@@ -32,7 +32,7 @@ import numpy as np
 import mxnet_tpu as mx
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(
         description="train an image-classification model",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter)
@@ -81,7 +81,7 @@ def parse_args():
       help="also report top-k accuracy when > 0")
     d("--monitor", type=int, default=0,
       help="install a Monitor with this stat period")
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
 def get_network(args):
@@ -163,10 +163,12 @@ def lr_schedule(args, kv):
     return lr, sched
 
 
-def main():
+def main(argv=None):
+    """Train as the command line says (``argv``: sys.argv[1:] when None);
+    returns the fitted Module."""
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)-15s %(message)s")
-    args = parse_args()
+    args = parse_args(argv)
     if not args.benchmark and not args.data_train:
         raise SystemExit("--data-train is required (or use --benchmark 1)")
 
@@ -203,12 +205,14 @@ def main():
     mon = mx.mon.Monitor(args.monitor, pattern=".*weight") \
         if args.monitor > 0 else None
 
-    # train on the accelerator when one exists (the reference's --gpus
-    # analog; mxnet's default context is cpu, which would silently run
-    # the model on the host)
-    ctx = mx.tpu() if mx.context.num_tpus() > 0 else \
-        mx.context.current_context()
-    logging.info("training on %s", ctx)
+    # train on the accelerator (the reference's --gpus analog; mxnet's
+    # default context is cpu, which would silently run the model on the
+    # host).  The host is used only where jax itself was told to:
+    # JAX_PLATFORMS=cpu.  Anywhere else a missing accelerator is an error,
+    # raised when mx.tpu() is resolved.
+    pinned_to_host = os.environ.get("JAX_PLATFORMS", "").lower() == "cpu"
+    ctx = mx.cpu() if pinned_to_host else mx.tpu()
+    logging.info("training on %s (%s)", ctx, ctx.jax_device)
     mod = mx.mod.Module(net, context=ctx)
     mod.fit(train, eval_data=val,
             eval_metric=mx.metric.CompositeEvalMetric(metrics),
@@ -222,6 +226,7 @@ def main():
             batch_end_callback=batch_cb, epoch_end_callback=epoch_cb,
             allow_missing=True, monitor=mon)
     print("train_imagenet OK")
+    return mod
 
 
 if __name__ == "__main__":

@@ -21,6 +21,16 @@ _jax.config.update("jax_enable_x64", True)
 # matmul precision truncates f32 to bf16 passes even on CPU.  bfloat16
 # workloads are unaffected — bf16 inputs hit the MXU natively either way.
 _jax.config.update("jax_default_matmul_precision", "highest")
+# jax's persistent compilation cache: where JAX_COMPILATION_CACHE_DIR says
+# (jax reads that itself), else one fixed directory inside the checkout —
+# see compile/paths.py.  Every program is kept, however fast it compiled:
+# a cold process on the chip pays seconds for each one it lacks.
+import os as _os
+from .compile import paths as _paths
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir", _paths.jax_cache_dir())
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+_jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 from .base import MXNetError
 from .attribute import AttrScope

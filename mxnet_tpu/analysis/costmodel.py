@@ -35,13 +35,13 @@ directions share.
 """
 from __future__ import annotations
 
-import os
 import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
 __all__ = ["HloInstr", "iter_instructions", "analytic_flops",
            "instruction_bytes", "bytes_by_dtype", "top_contributors",
-           "collective_compute_overlap", "chip_peaks", "roofline",
+           "collective_compute_overlap", "CHIP_PEAKS", "chip_peaks",
+           "roofline",
            "entry_io_bytes", "memory_breakdown", "predicted_peak_bytes"]
 
 _DTYPE_BYTES = {
@@ -52,6 +52,7 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(pred|bf16|f8e4m3fn|f8e5m2|[sufc]\d+)\[([\d,]*)\]")
+_OPERAND_REF_RE = re.compile(r"%([\w.\-]+)")
 
 # instruction line: `[ROOT ]%name = TYPE opcode(operands...), attrs...`
 # (same shape as parallel/audit.py's collective matcher, kept permissive:
@@ -125,11 +126,11 @@ class HloInstr:
     """One parsed HLO instruction."""
 
     __slots__ = ("name", "opcode", "result_type", "result_dtype",
-                 "result_bytes", "operands", "operand_shapes", "attrs",
-                 "computation")
+                 "result_bytes", "operands", "operand_shapes",
+                 "operand_bytes", "attrs", "computation")
 
     def __init__(self, name, opcode, result_type, operands, attrs,
-                 computation):
+                 computation, types=None):
         self.name = name
         self.opcode = opcode
         self.result_type = result_type
@@ -137,10 +138,18 @@ class HloInstr:
         self.result_dtype = toks[0][0] if toks else "?"
         self.result_bytes = _type_bytes(result_type)
         self.operands = operands
+        # the installed XLA prints operands as bare `%name` references;
+        # their types are those of the instructions that defined them
+        # (``types``: name -> result type, filled by iter_instructions)
+        typed = operands
+        if types and not _SHAPE_RE.search(operands):
+            typed = " ".join(types.get(ref, "")
+                             for ref in _OPERAND_REF_RE.findall(operands))
         # [(dtype, [dims...]), ...] in operand order
         self.operand_shapes = [
             (dt, [int(d) for d in dims.split(",") if d])
-            for dt, dims in _SHAPE_RE.findall(operands)]
+            for dt, dims in _SHAPE_RE.findall(typed)]
+        self.operand_bytes = _type_bytes(typed)
         self.attrs = attrs
         self.computation = computation
 
@@ -156,6 +165,7 @@ def iter_instructions(hlo_text: str) -> Iterator[HloInstr]:
     dot/convolution instructions are visible — which is exactly what the
     per-op-class accounting wants."""
     computation = ""
+    types: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         stripped = line.strip()
         if stripped.endswith("{") and ("->" in stripped
@@ -168,7 +178,9 @@ def iter_instructions(hlo_text: str) -> Iterator[HloInstr]:
             continue
         name, rtype, opcode = m.groups()
         operands, attrs = _balanced_operands(line, m.end() - 1)
-        yield HloInstr(name, opcode, rtype, operands, attrs, computation)
+        types[name] = rtype
+        yield HloInstr(name, opcode, rtype, operands, attrs, computation,
+                       types)
 
 
 # ---------------------------------------------------------------------------
@@ -460,7 +472,6 @@ _HEAVY_COMPUTE_OPS = frozenset({
     "cholesky", "triangular-solve",
 })
 
-_OPERAND_REF_RE = re.compile(r"%([\w.\-]+)")
 
 
 def _pipelined_sync_collectives(instrs: List[HloInstr]) -> Dict[str, bool]:
@@ -564,7 +575,7 @@ def collective_compute_overlap(hlo_text: str) -> Dict:
                         del open_starts[(comp, sname)]
                         break
                 continue
-            payload = _type_bytes(ins.operands)
+            payload = ins.operand_bytes
             total += payload
             slot = kind_slot(base)
             slot["bytes"] += payload
@@ -610,35 +621,42 @@ def collective_compute_overlap(hlo_text: str) -> Dict:
 # roofline
 # ---------------------------------------------------------------------------
 
-def chip_peaks() -> Dict[str, float]:
-    """Per-chip peak rates the roofline normalizes against.  Defaults are
-    TPU v5e (bf16): 197 TFLOP/s, 819 GB/s HBM, 2×45 GB/s ICI per link —
-    override with ``BENCH_PEAK_TFLOPS`` / ``MXNET_TPU_PEAK_HBM_GBS`` /
-    ``MXNET_TPU_PEAK_ICI_GBS`` (bench.py already owns the first knob; the
-    attribution plane reads the same one so MFU can never disagree)."""
-    def envf(name, default):
-        try:
-            return float(os.environ[name])
-        except (KeyError, ValueError):
-            return default
-    return {
-        "flops": envf("BENCH_PEAK_TFLOPS", 197.0) * 1e12,
-        "hbm_bytes_s": envf("MXNET_TPU_PEAK_HBM_GBS", 819.0) * 1e9,
-        "ici_bytes_s": envf("MXNET_TPU_PEAK_ICI_GBS", 90.0) * 1e9,
-    }
+# Published per-chip peaks, keyed by the ``device_kind`` jax reports.
+# "TPU v5 lite" is the v5e: 197 TFLOP/s bf16 and 819 GB/s of HBM (Google
+# Cloud documentation, "TPU v5e"); the ICI figure is the 2 x 45 GB/s ring
+# bandwidth the PR-6 collective model was built on (the published
+# aggregate is 1,600 Gbit/s per chip over four links).
+CHIP_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_s": 819e9,
+                    "ici_bytes_s": 90e9},
+}
+
+
+def chip_peaks(device_kind: str) -> Dict[str, float]:
+    """Per-chip peak rates the roofline and every MFU normalize against,
+    for the chip jax calls ``device_kind``.  A kind that is not in
+    :data:`CHIP_PEAKS` raises: a CPU, or a chip nobody has looked up, gets
+    no utilization figure rather than another chip's."""
+    try:
+        return dict(CHIP_PEAKS[device_kind])
+    except KeyError:
+        raise ValueError(
+            "no published peaks for device kind %r (known: %s); add it to "
+            "analysis.costmodel.CHIP_PEAKS with its source"
+            % (device_kind, sorted(CHIP_PEAKS))) from None
 
 
 def roofline(flops: float, hbm_bytes: float, collective_wire_bytes: float,
-             peaks: Optional[Dict[str, float]] = None,
+             peaks: Dict[str, float],
              measured_step_s: Optional[float] = None) -> Dict:
-    """Peak-normalized component times and the binding roof.
+    """Peak-normalized component times and the binding roof, against the
+    ``peaks`` of one chip (:func:`chip_peaks`).
 
     ``measured_step_s`` (when known) anchors the shares: each share is
     that component's lower-bound time over the measured step, and the
     residue the device math cannot explain is the host-bound share.
     Without a measurement the shares are relative to the slowest
     component (pure static mode)."""
-    peaks = peaks or chip_peaks()
     compute_s = flops / peaks["flops"] if peaks["flops"] else 0.0
     hbm_s = hbm_bytes / peaks["hbm_bytes_s"] if peaks["hbm_bytes_s"] \
         else 0.0

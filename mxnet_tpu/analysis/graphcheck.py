@@ -110,7 +110,7 @@ except ImportError:                     # older: the classic namespace
 __all__ = ["CollectiveEvent", "collect_collectives", "check_jaxpr",
            "check_fn", "check_collective_free", "check_symbol",
            "check_registry",
-           "check_replication", "check_capacity", "check_overlap",
+           "check_model_axis_replication", "check_capacity", "check_overlap",
            "check_embedding_grad", "check_decode_retrace",
            "is_decode_shaped", "check_trainer", "check_executor",
            "PER_STEP_ATTRS", "COLLECTIVE_PRIMS"]
@@ -120,6 +120,8 @@ __all__ = ["CollectiveEvent", "collect_collectives", "check_jaxpr",
 COLLECTIVE_PRIMS = frozenset({
     "psum", "pmax", "pmin", "ppermute", "pshuffle", "all_gather",
     "all_to_all", "reduce_scatter", "psum_scatter", "pgather",
+    # what psum / all_gather trace to inside a check_vma shard_map
+    "psum_invariant", "all_gather_invariant",
 })
 
 # attrs that change every optimizer step; static jit keys on these mean
@@ -569,7 +571,7 @@ def _replicated_threshold_bytes() -> int:
     return int(mb * (1 << 20))
 
 
-def check_replication(entries: Iterable[Tuple], mesh,
+def check_model_axis_replication(entries: Iterable[Tuple], mesh,
                       model_axes: Sequence[str] = (),
                       target: str = "") -> Report:
     """GC201: large arrays fully replicated while a model-parallel axis is
@@ -763,7 +765,6 @@ def check_embedding_grad(hlo_text: str, table_bytes=None, target: str = "",
     smallest table)`` so toy MLP grads in the same program never trip
     it.  Payload conventions match ``parallel.audit``: sync ops report
     result bytes, async ``-start`` their operand bytes."""
-    from ..parallel.audit import _shape_bytes
     from . import costmodel
     rep = Report("graphcheck", target)
     instrs = list(costmodel.iter_instructions(hlo_text))
@@ -786,7 +787,7 @@ def check_embedding_grad(hlo_text: str, table_bytes=None, target: str = "",
         if base not in ("all-reduce", "all-gather") or \
                 op.endswith("-done"):
             continue
-        payload = _shape_bytes(ins.operands) if op.endswith("-start") \
+        payload = ins.operand_bytes if op.endswith("-start") \
             else ins.result_bytes
         if payload < threshold:
             continue
@@ -950,7 +951,7 @@ def check_trainer(trainer, params, mom, aux, inputs, keys=None,
     entries = [(n, trainer._param_shapes.get(n, ()), 4, s)
                for n, s in zip(trainer.param_names, shardings)]
     model_axes = [a for a in (trainer.tp_axis,) if a]
-    rep.extend(check_replication(entries, trainer.spec.mesh, model_axes,
+    rep.extend(check_model_axis_replication(entries, trainer.spec.mesh, model_axes,
                                  target=target))
     rep.extend(check_donation(getattr(trainer, "_step_donated", True),
                               "ShardedTrainer jitted step", target=target))

@@ -15,7 +15,7 @@ achievable fraction.  This module closes that gap in three pieces:
   compute-bucket fraction).  Persisted under the PR-13 shared cache
   rule (:func:`~mxnet_tpu.compile.paths.cache_location`):
   ``MXNET_TPU_CALIBRATION_CACHE`` overrides, off-values disable, default
-  ``~/.cache/mxnet_tpu/calibration.json``.
+  ``<checkout>/.cache/calibration.json``.
 
 * **pre-flight budgets** — :func:`predict_budget` composes the cost
   model's FLOPs / HBM bytes / per-axis collective wire / memory
@@ -60,6 +60,11 @@ __all__ = ["DEFAULT_FRACTION", "achievable_fraction", "budget_table",
 
 STORE_VERSION = 1
 ENV_STORE = "MXNET_TPU_CALIBRATION_CACHE"
+
+# the chip every budget is a prediction FOR, whichever host computes it
+# (a pre-flight check runs before the job reaches its chip); its peaks
+# come from costmodel.CHIP_PEAKS and ride in the report's basis
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 # uncalibrated fallback: a real step typically lands near half its
 # device roof (host residue, launch gaps, un-overlapped collectives) —
@@ -203,20 +208,13 @@ def fit_from_attribution(store: Dict, data: Dict) -> Optional[Dict]:
                               device_roof / measured, source="telemetry")
 
 
-def _default_ledger_path() -> str:
-    root = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    return os.path.join(root, "PERF_LEDGER.jsonl")
-
-
-def fit_from_ledger(store: Optional[Dict] = None,
-                    ledger_path: Optional[str] = None,
+def fit_from_ledger(ledger_path: str, store: Optional[Dict] = None,
                     kind: Optional[str] = None) -> Dict:
-    """Fit the compute bucket from the committed ledger history: every
-    ``*_mfu`` metric IS an achievable-fraction sample (MFU = analytic
-    compute_s / measured step for a compute-bound program)."""
+    """Fit the compute bucket from a benchwatch trajectory ledger
+    (``BENCH_LEDGER``): every ``*_mfu`` metric IS an achievable-fraction
+    sample (MFU = analytic compute_s / measured step for a compute-bound
+    program)."""
     store = load_store() if store is None else store
-    ledger_path = ledger_path or _default_ledger_path()
     kind = kind or device_kind()
     samples: List[float] = []
     try:
@@ -337,7 +335,8 @@ def predict_budget(compiled=None, name: str = "program", *,
         wire += audit.collective_wire_bytes(kind_name, info["bytes"],
                                             ring_n)
 
-    roof = costmodel.roofline(fl["flops"], hbm_bytes, float(wire))
+    roof = costmodel.roofline(fl["flops"], hbm_bytes, float(wire),
+                              costmodel.chip_peaks(TARGET_DEVICE_KIND))
     kind = device_kind()
     store = load_store() if store is None else store
     cal = achievable_fraction(store, kind, roof["bound"])
@@ -401,7 +400,8 @@ def predict_decode_budget(num_layers: int, hidden: int, vocab: int,
     model = costmodel.decode_step_model(num_layers, hidden, vocab, slots,
                                         cached_tokens,
                                         quant_bits=quant_bits)
-    roof = costmodel.roofline(model["flops"], model["hbm_bytes"], 0.0)
+    roof = costmodel.roofline(model["flops"], model["hbm_bytes"], 0.0,
+                              costmodel.chip_peaks(TARGET_DEVICE_KIND))
     kind = device_kind()
     store = load_store() if store is None else store
     cal = achievable_fraction(store, kind, roof["bound"])

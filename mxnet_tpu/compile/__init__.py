@@ -13,7 +13,7 @@ recovery —
   world N, compiles the N−1 / grow-back generation step programs into
   the cache so an elastic resize resumes with zero in-drill
   compilation;
-* :mod:`.paths` — the shared ``~/.cache/mxnet_tpu`` / ``MXNET_TPU_*_
+* :mod:`.paths` — the shared ``<checkout>/.cache`` / ``MXNET_TPU_*_
   CACHE`` location convention (also used by ``ops/autotune.py``);
 * :mod:`.treedefs` — the pickle-free pytree codec cached entries use
   for their call signatures.
@@ -25,13 +25,13 @@ from . import paths
 from .treedefs import UnsupportedTreedef, obj_to_treedef, treedef_to_obj
 from .cache import (arm, cache_dir, cache_stats, cached_compile, clear,
                     device_signature, disarm, donation_safe, enabled,
-                    program_fingerprint)
+                    outside_jax_cache, program_fingerprint)
 from .standby import StandbyCompiler, trainer_standby_jobs
 
 __all__ = [
     "paths", "UnsupportedTreedef", "obj_to_treedef", "treedef_to_obj",
     "arm", "cache_dir", "cache_stats", "cached_compile", "clear",
     "device_signature", "disarm", "donation_safe", "enabled",
-    "program_fingerprint",
+    "outside_jax_cache", "program_fingerprint",
     "StandbyCompiler", "trainer_standby_jobs",
 ]

@@ -39,7 +39,7 @@ Knobs (docs/robustness.md):
 
 =====================================  ====================================
 ``MXNET_TPU_COMPILE_CACHE``            ``1`` enables at the default
-                                       location (``~/.cache/mxnet_tpu/
+                                       location (``<checkout>/.cache/
                                        compile-cache``); a path selects a
                                        directory; ``0``/unset disables
 ``MXNET_TPU_COMPILE_CACHE_MAX_MB``     best-effort size bound: oldest
@@ -49,6 +49,7 @@ Knobs (docs/robustness.md):
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import logging
 import os
@@ -59,7 +60,9 @@ from . import paths as _paths
 from .treedefs import UnsupportedTreedef, obj_to_treedef, treedef_to_obj
 
 __all__ = ["enabled", "arm", "disarm", "cache_dir", "entry_path",
-           "program_fingerprint", "device_signature", "cached_compile",
+           "outside_jax_cache",
+           "program_fingerprint", "program_devices", "device_signature",
+           "cached_compile",
            "donation_safe", "load", "store", "quarantine", "cache_stats",
            "clear", "CACHE_MAGIC"]
 
@@ -74,6 +77,30 @@ _UNCACHEABLE_MARKERS = ("callback", "infeed", "outfeed", "debug_print")
 
 # lowered-text markers of input→output aliasing (donated buffers)
 _ALIASING_MARKERS = ("tf.aliasing_output", "jax.buffer_donor")
+
+
+@contextlib.contextmanager
+def outside_jax_cache():
+    """Scope in which jax's persistent compilation cache is neither read
+    nor written — for programs that carry non-default layouts.
+
+    On the installed jax 0.9.0 / libtpu 0.0.34 an executable that comes
+    OUT of that cache has forgotten its result layouts: a ``device_put`` to
+    a ``Format`` returns the default layout on a cache hit (measured on
+    the v5e, PERF.md PR 21), so a warm process would hand the AUTO-layout
+    train step arrays it then refuses.  jax decides once per process
+    whether it uses the cache, hence the resets around the switch; another
+    thread that compiles meanwhile only misses the cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def donation_safe(backend: Optional[str] = None) -> bool:
@@ -145,13 +172,22 @@ def program_fingerprint(lowered_text: str) -> str:
     return hashlib.sha256(lowered_text.encode("utf-8")).hexdigest()
 
 
+def program_devices(mesh=None) -> list:
+    """The devices an executable binds to, in assignment order: the
+    mesh's for a program lowered under one, else the default device.  A
+    deserialized executable must be told — jax would otherwise bind it to
+    every local device and reject its arguments ("expected 8 shards, got
+    1") on any host with more than one."""
+    import jax
+    if mesh is not None:
+        return list(mesh.devices.flat)
+    return jax.devices()[:1]
+
+
 def device_signature(mesh=None) -> str:
     """platform / kind / exact device ids the executable will bind to."""
     import jax
-    if mesh is not None:
-        devices = list(getattr(mesh, "devices").flat)
-    else:
-        devices = jax.devices()
+    devices = program_devices(mesh)
     kinds = sorted({str(d.device_kind) for d in devices})
     ids = ",".join(str(d.id) for d in devices)
     return "%s|%s|%s" % (jax.default_backend(), "+".join(kinds), ids)
@@ -190,8 +226,9 @@ def quarantine(path: str, reason: str, what: str = "") -> None:
     _count("corrupt" if reason.startswith("corrupt") else reason, what)
 
 
-def load(key_digest: str, what: str = ""):
-    """Deserialize the entry for ``key_digest`` or return None (miss,
+def load(key_digest: str, what: str = "", mesh=None):
+    """Deserialize the entry for ``key_digest`` onto the devices of
+    ``mesh`` (:func:`program_devices`) or return None (miss,
     corrupt-quarantined, key-mismatch-quarantined, or deserializer
     refusal — every non-hit degrades to 'caller compiles fresh')."""
     path = entry_path(key_digest)
@@ -218,7 +255,8 @@ def load(key_digest: str, what: str = ""):
         in_tree = obj_to_treedef(meta["in_tree"])
         out_tree = obj_to_treedef(meta["out_tree"])
         compiled = serialize_executable.deserialize_and_load(
-            blobs["executable"], in_tree, out_tree)
+            blobs["executable"], in_tree, out_tree,
+            execution_devices=program_devices(mesh))
     except Exception as e:
         quarantine(path, "corrupt: deserialize failed: %r" % e, what)
         return None
@@ -379,7 +417,7 @@ def cached_compile(lowered, what: str, mesh=None, extra: Sequence = (),
         logging.exception("compile-cache: keying failed for %s "
                           "(compiling uncached)", what)
         return lowered.compile(), "off"
-    hit = load(key, what=what)
+    hit = load(key, what=what, mesh=mesh)
     if hit is not None:
         return hit, "hit"
     from .. import telemetry as _tel

@@ -6,6 +6,9 @@ kernel to that device's stream.  Here a Context names a JAX device; arrays are
 committed to it with jax.device_put and XLA owns streams/async.  ``tpu`` is
 the first-class device type; ``gpu(i)`` is accepted and mapped onto the i-th
 accelerator so reference scripts run unmodified; ``cpu()`` is the host.
+A context that names an accelerator this process does not have raises when
+it is resolved: ``tpu(0)`` is never quietly the CPU, ``tpu(3)`` on one chip
+is never chip 0.
 """
 from __future__ import annotations
 
@@ -14,16 +17,16 @@ from typing import List, Optional
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
-           "num_gpus", "num_tpus", "device_of"]
+           "num_gpus", "num_tpus", "device_of", "device_summary"]
 
 
 def _accelerators():
     # local_devices: in a multi-process run only this rank's devices are
     # addressable (jax.devices() lists the whole job's)
-    devs = jax.local_devices()
-    acc = [d for d in devs if d.platform != "cpu"]
-    return acc if acc else devs
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 class Context:
@@ -59,7 +62,13 @@ class Context:
                 # cpu platform absent under some runtimes: fall back to default
                 return jax.local_devices()[0]
         acc = _accelerators()
-        return acc[self.device_id % len(acc)]
+        if not 0 <= self.device_id < len(acc):
+            raise MXNetError(
+                "context %s names accelerator %d but this process has %d "
+                "(jax.local_devices(): %s)"
+                % (self, self.device_id, len(acc),
+                   [str(d) for d in jax.local_devices()]))
+        return acc[self.device_id]
 
     def __eq__(self, other):
         return (isinstance(other, Context)
@@ -128,6 +137,14 @@ def num_tpus() -> int:
     return num_gpus()
 
 
+def device_summary(devices) -> dict:
+    """``{"platform", "kind", "count"}`` of ``devices`` as jax reports them:
+    the block every benchmark result and the chip smoke name their device
+    with."""
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
 def current_context() -> Context:
     return Context.default_ctx()
 
@@ -140,8 +157,4 @@ def device_of(array) -> Context:
         return cpu()
     if dev.platform == "cpu":
         return cpu()
-    acc = _accelerators()
-    for i, d in enumerate(acc):
-        if d == dev:
-            return tpu(i)
-    return tpu(0)
+    return tpu(_accelerators().index(dev))

@@ -291,8 +291,11 @@ class ServedProgram:
         in_tree, out_tree = _arity_trees(
             len(meta["param_names"]), len(meta["input_names"]),
             int(meta["n_outputs"]))
+        # export_compiled builds a one-device program
+        from .compile.cache import program_devices
         self._compiled = serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree)
+            payload, in_tree, out_tree,
+            execution_devices=program_devices())
         self.input_names = meta["input_names"]
         self.input_shapes = {n: tuple(s)
                              for n, s in meta["input_shapes"].items()}

@@ -74,7 +74,12 @@ class ImageRecordIter(DataIter):
                     std=None if std is None else tuple(float(v) for v in std),
                     prefetch=prefetch_buffer, part_index=part_index,
                     num_parts=num_parts if have_idx else 1)
+                logging.info("ImageRecordIter(%s): native C++ decode "
+                             "pipeline", path_imgrec)
                 return
+            logging.info("ImageRecordIter(%s): native IO library not built "
+                         "(make -C native) — decoding in Python",
+                         path_imgrec)
 
         from ..resilience.retry import call_with_retry
         if have_idx:

@@ -591,7 +591,10 @@ def launch_server(kv_dir: str, world: int,
     from ..serving.fleet import ReplicaSupervisor
     repo_root = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
+    # the server is host code: pinned to the CPU, or importing the package
+    # would make it open the chip its workers train on
     base_env = {"MXNET_TPU_KV_DIR": os.fspath(kv_dir),
+                "JAX_PLATFORMS": "cpu",
                 "PYTHONPATH": os.pathsep.join(
                     [repo_root] + os.environ.get("PYTHONPATH", "").split(
                         os.pathsep)).rstrip(os.pathsep)}

@@ -18,6 +18,7 @@ are jax.custom_vjp primitives.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -706,6 +707,30 @@ _FLASH_MIN_SEQ = int(os.environ.get("MXNET_FLASH_MIN_SEQ", "1024"))
 # same jit-cache reason as the threshold.
 _FLASH_BWD = os.environ.get("MXNET_TPU_FLASH_BWD", "pallas")
 
+def _per_mesh_shard(fn):
+    """``fn`` over each device's batch shard, when the program being
+    traced spans a multi-device mesh (identity otherwise).  GSPMD cannot
+    partition a Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), so under a
+    multi-device trainer the flash calls run manually per device: every
+    operand — (B, T, H, D) tensors and the (B*H, T, 128) logsumexp alike —
+    splits along its batch-major leading dim over dp and is whole over
+    every other axis.  The trainer says so by tracing inside jax's mesh
+    context (ShardedTrainer._tracing_on_mesh); the MeshSpec it armed names
+    the dp axis."""
+    from ..parallel.mesh import current_mesh
+    spec = current_mesh()
+    traced_on = jax.sharding.get_abstract_mesh()
+    if spec is None or traced_on.size <= 1 or \
+            traced_on != spec.mesh.abstract_mesh:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    # check_vma off: off-TPU the Pallas interpreter's grid loop fails the
+    # varying-axes check (see sparse/embedding.py)
+    return jax.shard_map(fn, mesh=spec.mesh, in_specs=P(spec.dp_axis),
+                         out_specs=P(spec.dp_axis), check_vma=False)
+
+
 @register("_contrib_fused_attention", inputs=("query", "key", "value"),
           params=dict(causal=attr_bool(False), scale=attr_float(0.0),
                       block_q=attr_int(0), flash_min_seq=attr_int(0)),
@@ -745,26 +770,25 @@ def _contrib_fused_attention(attrs, q, k, v):
     if q.shape[1] < flash_min:
         return naive(q, k, v)
 
+    from . import pallas_kernels as pk
+    # the kernels fit block_q/block_k to T themselves
+    kw = dict(causal=causal, scale=scale, block_q=block_q)
+
     @jax.custom_vjp
     def attn(q, k, v):
-        from .pallas_kernels import fused_attention
-        # fused_attention clamps block_q/block_k to divisors of T itself
-        return fused_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=block_q)
+        return _per_mesh_shard(functools.partial(
+            pk.fused_attention, **kw))(q, k, v)
 
     def fwd(q, k, v):
-        from .pallas_kernels import fused_attention_fwd
-        out, lse = fused_attention_fwd(q, k, v, causal=causal,
-                                       scale=scale, block_q=block_q)
+        out, lse = _per_mesh_shard(functools.partial(
+            pk.fused_attention_fwd, **kw))(q, k, v)
         return out, (q, k, v, out, lse)
 
     def bwd(res, g):
-        q, k, v, out, lse = res
         if _FLASH_BWD == "pallas":
-            from .pallas_kernels import fused_attention_bwd
-            return fused_attention_bwd(q, k, v, out, lse, g,
-                                       causal=causal, scale=scale,
-                                       block_q=block_q)
+            return _per_mesh_shard(functools.partial(
+                pk.fused_attention_bwd, **kw))(*res, g)
+        q, k, v, _out, _lse = res
         # fallback: rematerialize through the einsum formulation
         _, vjp = jax.vjp(naive, q, k, v)
         return vjp(g)

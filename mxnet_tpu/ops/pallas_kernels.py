@@ -14,8 +14,11 @@ Kernels:
     form of parallel/ring.py's `_block_attn`; ring attention composes it
     across chips.
 
-Both kernels run through the Pallas interpreter when no TPU is present
-(pallas_call(interpret=True)), so the same code path is tested on CPU.
+Operands that live on a TPU get the Mosaic lowering; anywhere else the
+kernels run through the Pallas interpreter (pallas_call(interpret=True)),
+which is how the CPU tests reach the same code.  Every pallas_call carries
+a stable ``name=`` so a compiled program can be checked for it
+(chip_smoke.py) and a profile can find it.
 """
 from __future__ import annotations
 
@@ -28,14 +31,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax.enable_x64 graduated from jax.experimental after 0.4.37; accept both
-_enable_x64 = getattr(jax, "enable_x64", None)
-if _enable_x64 is None:   # pragma: no cover - version-dependent
-    from jax.experimental import enable_x64 as _enable_x64
-
 __all__ = ["two_bit_compress", "fused_attention", "fused_attention_fwd",
-           "fused_attention_bwd", "pallas_available", "decode_attention",
-           "quantize_weight", "quant_matmul"]
+           "fused_attention_bwd", "decode_attention", "quantize_weight",
+           "quant_matmul"]
 
 
 def _interpret(*arrays) -> bool:
@@ -51,8 +49,12 @@ def _interpret(*arrays) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def pallas_available() -> bool:
-    return True   # interpret mode keeps the path alive everywhere
+def _out_struct(shape, dtype, *operands):
+    """``out_shape`` entry for a pallas_call that may sit inside a
+    ``shard_map``: the output varies over the same manual mesh axes as the
+    operands, and ``check_vma`` wants that stated."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # ---------------------------------------------------------------------------
@@ -117,7 +119,7 @@ def _two_bit_jit(grad, residual, threshold, interpret):
     r2 = jnp.pad(residual.reshape(-1).astype(jnp.float32), (0, pad)) \
         .reshape(rows, _LANES)
     kern = functools.partial(_two_bit_kernel, t=float(threshold))
-    with _enable_x64(False):   # Mosaic cannot take i64 grid indices
+    with jax.enable_x64(False):   # Mosaic cannot take i64 grid indices
         q2, nr2 = pl.pallas_call(
             kern,
             grid=(rows // _BLOCK_ROWS,),
@@ -129,9 +131,9 @@ def _two_bit_jit(grad, residual, threshold, interpret):
                 pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0)),
                 pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0)),
             ),
-            out_shape=(jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
-                       jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)),
-            interpret=interpret,
+            out_shape=(_out_struct((rows, _LANES), jnp.float32, g2, r2),
+                       _out_struct((rows, _LANES), jnp.float32, g2, r2)),
+            interpret=interpret, name="two_bit_compress",
         )(g2, r2)
     q = q2.reshape(-1)[:n].reshape(shape).astype(dtype)
     nr = nr2.reshape(-1)[:n].reshape(shape).astype(dtype)
@@ -152,22 +154,28 @@ _NEG_BIG = -1e30      # -inf would make exp(m_prev - m_new) NaN on init
 _LSE_LANES = 128
 
 
+def _fit_block(block, T, dtype):
+    """Largest halving of ``block`` that divides ``T`` and stays on the
+    dtype's sublane tile (8 rows of 32 bits: 8 for f32, 16 for bf16);
+    a length no such block divides is taken whole, which Mosaic accepts
+    as "equal to the array dimension"."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    b = min(block, T)
+    while T % b and b > tile:
+        b //= 2
+    return b if T % b == 0 and b % tile == 0 else T
+
+
 def _pick_blocks(block_q, block_k, Tq, Tk, D, dtype, kind):
     """Resolve (block_q, block_k): explicit argument wins, then the
     autotune cache (ops/autotune.py), then the static default — and
-    either way clamp to divisors of the sequence lengths."""
+    either way fit them to the sequence lengths (:func:`_fit_block`)."""
     if block_q is None or block_k is None:
         from . import autotune as _autotune
         tq, tk = _autotune.flash_blocks(kind, Tq, Tk, D, dtype)
         block_q = block_q or tq
         block_k = block_k or tk
-    bq = min(block_q, Tq)
-    while Tq % bq:
-        bq //= 2
-    bk = min(block_k, Tk)
-    while Tk % bk:
-        bk //= 2
-    return bq, bk
+    return _fit_block(block_q, Tq, dtype), _fit_block(block_k, Tk, dtype)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
@@ -236,17 +244,17 @@ def _flash_call(qf, kf, vf, dtype, *, scale, causal, bq, bk, with_lse,
     kern = functools.partial(_flash_kernel, scale=scale, causal=causal,
                              block_q=bq, block_k=bk, nk=nk,
                              with_lse=with_lse)
-    out_shape = [jax.ShapeDtypeStruct((BH, Tq, D), dtype)]
+    out_shape = [_out_struct((BH, Tq, D), dtype, qf, kf, vf)]
     out_specs = [pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))]
     if with_lse:
         out_shape.append(
-            jax.ShapeDtypeStruct((BH, Tq, _LSE_LANES), jnp.float32))
+            _out_struct((BH, Tq, _LSE_LANES), jnp.float32, qf, kf, vf))
         out_specs.append(
             pl.BlockSpec((None, bq, _LSE_LANES), lambda b, i, j: (b, i, 0)))
     # this package runs with jax_enable_x64 on (mxnet int64 parity); grid
     # index maps would then trace their literals as i64, which Mosaic
     # cannot legalize — trace the kernel in an x64-off scope
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         res = pl.pallas_call(
             kern,
             grid=(BH, Tq // bq, nk),
@@ -262,7 +270,7 @@ def _flash_call(qf, kf, vf, dtype, *, scale, causal, bq, bk, with_lse,
                 pltpu.VMEM((bq, 128), jnp.float32),   # running max (lanes
                 pltpu.VMEM((bq, 128), jnp.float32),   # + sum, broadcast)
             ],
-            interpret=interpret,
+            interpret=interpret, name="flash_fwd",
         )(qf, kf, vf)
     return res if with_lse else (res, None)
 
@@ -444,7 +452,8 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
                     axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (B * H, Tq, _LSE_LANES))
     interpret = _interpret(q, k, v)
-    with _enable_x64(False):
+    operands = (qf, kf, vf, dof, lse, delta)
+    with jax.enable_x64(False):
         dq = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel, scale=scale,
                               causal=causal, block_q=bq, block_k=bk,
@@ -461,10 +470,10 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
                              lambda b, i, j: (b, i, 0)),
             ],
             out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
+            out_shape=_out_struct((B * H, Tq, D), q.dtype, *operands),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            interpret=interpret,
-        )(qf, kf, vf, dof, lse, delta)
+            interpret=interpret, name="flash_bwd_dq",
+        )(*operands)
         dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_dkv_kernel, scale=scale,
                               causal=causal, block_q=bq, block_k=bk,
@@ -484,12 +493,12 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
                 pl.BlockSpec((None, bk, D), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((None, bk, D), lambda b, i, j: (b, i, 0)),
             ),
-            out_shape=(jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-                       jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype)),
+            out_shape=(_out_struct((B * H, Tk, D), k.dtype, *operands),
+                       _out_struct((B * H, Tk, D), v.dtype, *operands)),
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)],
-            interpret=interpret,
-        )(qf, kf, vf, dof, lse, delta)
+            interpret=interpret, name="flash_bwd_dkv",
+        )(*operands)
 
     def unflat(x, T):
         return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
@@ -516,6 +525,13 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
 
 def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                         acc_ref, m_ref, l_ref, *, page, n_pages, scale):
+    """One (slot, logical page) cell.  Every value keeps the
+    (H, page|1, D|1) rank of the page block: a one-token query against a
+    page is a matrix-VECTOR product per head, which Mosaic's matmul does
+    not take (it refused the batched ``(H,page,D)·(H,D)`` dot_general:
+    "failed to parse TPU_DotDimensionNumbersAttr"), so the scores and the
+    weighted sum are broadcast-multiplies reduced over lanes / sublanes
+    on the VPU — decode attention is bandwidth-bound either way."""
     s = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -533,32 +549,25 @@ def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[0].astype(jnp.float32)            # (H, D)
+        q = q_ref[0].astype(jnp.float32)            # (H, 1, D)
         k = k_ref[0].astype(jnp.float32)            # (H, page, D)
         v = v_ref[0].astype(jnp.float32)
-        s_hp = jax.lax.dot_general(
-            k, q, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale      # (H, page)
+        s_hp = jnp.sum(k * q, axis=-1, keepdims=True) * scale  # (H, page, 1)
         pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s_hp.shape, 1)
         s_hp = jnp.where(pos < len_ref[s], s_hp, jnp.float32(_NEG_BIG))
-        m_prev = m_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_hp, axis=-1, keepdims=True))
+        m_prev = m_ref[:]                           # (H, 1, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s_hp, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_hp - m_new)                   # (H, page)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, 0:1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)     # (H, D)
-        acc_ref[:] = acc_ref[:] * corr + pv
+        p = jnp.exp(s_hp - m_new)                   # (H, page, 1)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:] = m_new
+        acc_ref[:] = acc_ref[:] * corr + jnp.sum(p * v, axis=1,
+                                                 keepdims=True)  # (H, 1, D)
 
     @pl.when(j == n_pages - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] /
-                    jnp.maximum(l_ref[:, 0:1],
-                                jnp.float32(1e-37))).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], jnp.float32(1e-37))
+                    ).astype(o_ref.dtype)
 
 
 def _decode_attn_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
@@ -568,30 +577,36 @@ def _decode_attn_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
     n_pages = page_table.shape[1]
     kern = functools.partial(_decode_attn_kernel, page=page,
                              n_pages=n_pages, scale=scale)
+    # q and the output ride as (S, H, 1, D): the block's trailing two dims
+    # then equal the array's, and the kernel never reshapes
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, n_pages),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda s, j, pt, ln: (s, 0, 0)),
+            pl.BlockSpec((1, H, 1, D), lambda s, j, pt, ln: (s, 0, 0, 0)),
             pl.BlockSpec((1, H, page, D),
                          lambda s, j, pt, ln: (pt[s, j], 0, 0, 0)),
             pl.BlockSpec((1, H, page, D),
                          lambda s, j, pt, ln: (pt[s, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda s, j, pt, ln: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, 1, D),
+                               lambda s, j, pt, ln: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),        # acc
-            pltpu.VMEM((H, 128), jnp.float32),      # running max
-            pltpu.VMEM((H, 128), jnp.float32),      # running sum
+            pltpu.VMEM((H, 1, D), jnp.float32),     # acc
+            pltpu.VMEM((H, 1, 1), jnp.float32),     # running max
+            pltpu.VMEM((H, 1, 1), jnp.float32),     # running sum
         ],
     )
-    with _enable_x64(False):
-        return pl.pallas_call(
+    q4 = q.reshape(S, H, 1, D)
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
             kern, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-            interpret=interpret,
+            out_shape=_out_struct((S, H, 1, D), q.dtype, q4, k_pages,
+                                  v_pages),
+            interpret=interpret, name="decode_attn",
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q, k_pages, v_pages)
+          q4, k_pages, v_pages)
+    return out.reshape(S, H, D)
 
 
 def _decode_attn_xla(q, k_pages, v_pages, page_table, seq_lens, scale):
@@ -692,41 +707,49 @@ def quantize_weight(w, bits: int = 8):
     return ((hi << 4) | lo).astype(np.uint8), scales
 
 
-def _unpack_int4(packed):
-    """(N, K//2) uint8 -> (N, K) f32 in [-7, 7] (sign-extended nibbles)."""
+def _nibbles(packed):
+    """(N, K//2) uint8 -> the (N, K//2) f32 low (even k) and high (odd k)
+    nibbles, sign-extended to [-7, 7]."""
     p = packed.astype(jnp.int32)
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    both = jnp.stack([lo, hi], axis=-1).reshape(p.shape[0], -1)
-    return jnp.where(both > 7, both - 16, both).astype(jnp.float32)
+
+    def signed(n):
+        return jnp.where(n > 7, n - 16, n).astype(jnp.float32)
+    return signed(p & 0xF), signed((p >> 4) & 0xF)
 
 
-def _quant_matmul_kernel(x_ref, qw_ref, sc_ref, o_ref, acc_ref, *,
-                         bits, nk):
+def _unpack_int4(packed):
+    """(N, K//2) uint8 -> (N, K) f32 in [-7, 7]."""
+    lo, hi = _nibbles(packed)
+    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], -1)
+
+
+def _quant_matmul_kernel(*refs, bits, nk):
     """One (M, bn) output tile: the k-axis is the sequential grid
-    dimension; each step dequantizes ONE (bn, bk) weight tile in VMEM
-    (int4: unpacked from (bn, bk//2) nibbles) and accumulates
-    x_tile @ w_tile.T in f32 scratch — the f32 weight tile exists only
-    on-chip, never in HBM."""
+    dimension; each step dequantizes ONE weight tile in VMEM and
+    accumulates x_tile @ w_tile.T in f32 scratch — the f32 weight tile
+    exists only on-chip, never in HBM.  int4 takes x pre-split into its
+    even and odd k columns and multiplies each against its own nibble
+    plane: interleaving the nibbles back into k order inside the kernel
+    is a lane shuffle Mosaic does not lower."""
+    *x_refs, qw_ref, sc_ref, o_ref, acc_ref = refs
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:].astype(jnp.float32)                 # (M, bk)
     if bits == 4:
-        w = _unpack_int4(qw_ref[:])                  # (bn, bk)
+        planes = _nibbles(qw_ref[:])                 # 2 x (bn, bk//2)
     else:
-        w = qw_ref[:].astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (M, bn)
+        planes = (qw_ref[:].astype(jnp.float32),)    # (bn, bk)
+    for x_ref, w in zip(x_refs, planes):
+        acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
+            x_ref[:].astype(jnp.float32), w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # (M, bn)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[:] = (acc_ref[:] * sc_ref[:].reshape(1, -1)
-                    ).astype(o_ref.dtype)
+        o_ref[:] = (acc_ref[:] * sc_ref[:]).astype(o_ref.dtype)
 
 
 def _quant_matmul_xla(x, qw, scales, bits):
@@ -765,25 +788,32 @@ def quant_matmul(x: jax.Array, qw: jax.Array, scales: jax.Array,
     bn = min(block_n, N)
     while N % bn:
         bn //= 2
-    bk = min(block_k, K)
-    while K % bk:
+    if bits == 4:
+        # two k per packed byte: x rides as its even / odd k columns
+        if K % 2:
+            x2 = jnp.pad(x2, ((0, 0), (0, 1)))
+        xs = (x2[:, 0::2], x2[:, 1::2])
+    else:
+        xs = (x2,)
+    kc = qw.shape[1]               # columns of each x operand and of qw
+    bk = min(block_k // len(xs), kc)
+    while kc % bk:
         bk //= 2
-    nk = K // bk
+    nk = kc // bk
     kern = functools.partial(_quant_matmul_kernel, bits=bits, nk=nk)
-    # int4 tiles address the PACKED byte axis (two k per byte)
-    kdiv = 2 if bits == 4 else 1
-    with _enable_x64(False):
+    sc2 = scales.reshape(1, N)     # 2-D: a (bn,) block has no (8,128) tile
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kern,
             grid=(N // bn, nk),
-            in_specs=[
-                pl.BlockSpec((M, bk), lambda n, k_: (0, k_)),
-                pl.BlockSpec((bn, bk // kdiv), lambda n, k_: (n, k_)),
-                pl.BlockSpec((bn,), lambda n, k_: (n,)),
+            in_specs=[pl.BlockSpec((M, bk), lambda n, k_: (0, k_))
+                      for _ in xs] + [
+                pl.BlockSpec((bn, bk), lambda n, k_: (n, k_)),
+                pl.BlockSpec((1, bn), lambda n, k_: (0, n)),
             ],
             out_specs=pl.BlockSpec((M, bn), lambda n, k_: (0, n)),
-            out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+            out_shape=_out_struct((M, N), x.dtype, x2, qw, sc2),
             scratch_shapes=[pltpu.VMEM((M, bn), jnp.float32)],
-            interpret=_interpret(x, qw),
-        )(x2, qw, scales)
+            interpret=_interpret(x, qw), name="quant_matmul",
+        )(*xs, qw, sc2)
     return out.reshape(lead + (N,))

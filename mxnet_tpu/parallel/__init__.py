@@ -55,12 +55,12 @@ def init_distributed(coordinator_address=None, num_processes=None,
     coordinator and rank, and a cpu backend with gloo collectives is
     configured so DCN logic runs without a pod."""
     import os
-    try:  # NOTE: jax.process_count() would itself initialise the backend
-        from jax._src import xla_bridge as _xb
-        if _xb.backends_are_initialized():
-            return  # too late to (re)initialise; runtime already decided
-    except Exception:
-        pass
+    # jax 0.9.0 has no public spelling of "is a backend up?", and
+    # jax.process_count() would itself initialise one
+    from jax._src import xla_bridge
+    if jax.distributed.is_initialized() or \
+            xla_bridge.backends_are_initialized():
+        return  # too late to (re)initialise; the runtime already decided
     coordinator_address = coordinator_address or \
         os.environ.get("MXNET_TPU_COORDINATOR")
     if num_processes is None and "DMLC_NUM_WORKER" in os.environ:
@@ -70,24 +70,21 @@ def init_distributed(coordinator_address=None, num_processes=None,
     if coordinator_address and (num_processes or 0) > 1:
         if os.environ.get("MXNET_TPU_DIST_DEVICE", "cpu") == "cpu":
             jax.config.update("jax_platforms", "cpu")
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes, process_id=process_id)
-        except RuntimeError:
-            pass  # repeat call: the service is already up
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        # a rank that cannot join its gang must not train on alone
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes, process_id=process_id)
         return
     try:
         jax.distributed.initialize(coordinator_address=coordinator_address,
                                    num_processes=num_processes,
                                    process_id=process_id)
-    except Exception:
-        pass  # single-process
+    except ValueError:
+        # no coordinator given and no cluster environment jax can detect:
+        # this is a single-process run.  Anything else — a coordinator
+        # that cannot be reached, a rank mismatch — propagates.
+        pass
 
 
 def barrier(name="kvstore_barrier"):

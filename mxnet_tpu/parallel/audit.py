@@ -15,17 +15,9 @@ import threading
 import time
 from collections import deque
 
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
-    "c64": 8, "c128": 16,
-}
-
 # every collective HLO op we account for
 _COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
                 "collective-permute")
-
-_SHAPE_RE = re.compile(r"(pred|[sufc]\d+|bf16)\[([\d,]*)\]")
 
 # ---------------------------------------------------------------------------
 # Runtime collective trail.  The HLO accounting above is static; this is the
@@ -89,19 +81,6 @@ def clear_collective_log():
         _RUNTIME_LOG.clear()
 
 
-def _shape_bytes(type_expr):
-    """Sum bytes over every dtype[dims] token in an HLO type expression
-    (handles tuple-shaped collective outputs)."""
-    total = 0
-    for dtype, dims in _SHAPE_RE.findall(type_expr):
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES.get(dtype, 4)
-    return total
-
-
 # ---------------------------------------------------------------------------
 # replica-group parsing + mesh-axis attribution
 # ---------------------------------------------------------------------------
@@ -112,8 +91,6 @@ _GROUPS_BRACE_RE = re.compile(
     r"replica_groups=\{(\{[\d, ]*\}(?:, *\{[\d, ]*\})*)\}")
 _PAIRS_RE = re.compile(
     r"source_target_pairs=\{(\{[\d, ]*\}(?:, *\{[\d, ]*\})*)\}")
-_NUM_PARTITIONS_RE = re.compile(r"num_partitions=(\d+)")
-_REF_RE = re.compile(r"%([\w.\-]+)")
 
 
 def parse_replica_groups(attrs_text):
@@ -219,67 +196,6 @@ class AxisLabeler:
         return "unmapped"
 
 
-def _group_size(ins, default):
-    groups = parse_replica_groups(ins.attrs)
-    if groups and groups[0]:
-        return len(groups[0])
-    return default
-
-
-def _fused_reduce_scatters(instrs_by_comp, num_partitions):
-    """The ReduceScatterCreator pattern, detected statically: an
-    ``all-reduce`` whose EVERY consumer takes a partition-id-derived
-    slice of the result (a ``dynamic-slice`` with partition-dependent
-    offsets, or a fusion consuming the full array plus ``partition-id``
-    and producing a 1/group shard).  Semantically that pair IS a
-    reduce-scatter — the TPU/GPU toolchains' ReduceScatterCreator pass
-    rewrites exactly this form into one; XLA:CPU (the dryrun backend)
-    lacks the pass and keeps it spelled out, the same way it never emits
-    async ``-start``/``-done`` pairs.  Classifying it here keeps the
-    audit describing the program's wire semantics rather than one
-    backend's pass list — the precedent set by the costmodel's
-    'pipelined' overlap classification.
-
-    Returns {(computation, name): shard_payload_bytes}."""
-    out = {}
-    for comp, instrs in instrs_by_comp.items():
-        pids = {i.name for i in instrs if i.opcode == "partition-id"}
-        if not pids:
-            continue
-        refs = {i.name: set(_REF_RE.findall(i.operands)) for i in instrs}
-        # scalar offset chains: partition-id flows through multiplies/
-        # bitcasts/lookup-table slices into the dynamic-slice offsets
-        derived = set(pids)
-        changed = True
-        while changed:
-            changed = False
-            for i in instrs:
-                if i.name in derived or i.result_bytes > 64:
-                    continue
-                if refs[i.name] & derived:
-                    derived.add(i.name)
-                    changed = True
-        users = {}
-        for i in instrs:
-            for r in refs[i.name]:
-                users.setdefault(r, []).append(i)
-        for i in instrs:
-            if i.opcode != "all-reduce":
-                continue
-            us = users.get(i.name, [])
-            if not us:
-                continue
-            g = _group_size(i, num_partitions)
-            if g <= 1:
-                continue
-            if all(u.opcode in ("dynamic-slice", "fusion")
-                   and 2 * u.result_bytes <= i.result_bytes
-                   and (refs[u.name] & derived)
-                   for u in us):
-                out[(comp, i.name)] = i.result_bytes // g
-    return out
-
-
 def collective_accounting(hlo_text, mesh=None):
     """Payload bytes + instruction count per collective kind.
 
@@ -287,52 +203,32 @@ def collective_accounting(hlo_text, mesh=None):
     non-async-duplicate instructions ('-start' variants counted once via
     their operand shapes, '-done' skipped).  Payload conventions: sync
     ops report their result bytes, async ``-start`` their operand bytes,
-    reduce-scatter therefore the (1/group) shard.
+    reduce-scatter therefore the (1/group) shard.  An instruction is
+    counted as the opcode the compiler emitted: an all-reduce whose only
+    consumer slices out this partition's shard moves a full all-reduce on
+    the wire and is reported as one.
 
-    Two refinements over raw opcode counting:
-
-    * an all-reduce in the fused all-reduce + partition-slice form (see
-      :func:`_fused_reduce_scatters`) is reported as ``reduce-scatter``
-      with shard payload, plus a ``fused_from_all_reduce`` count so the
-      reclassification is visible;
-    * with ``mesh`` given, every kind carries a ``by_axis`` breakdown
-      mapping the instruction's replica groups (or ppermute pairs) onto
-      the mesh axes — dp vs tp vs ep traffic becomes directly
-      attributable in dryrun output.
+    With ``mesh`` given, every kind carries a ``by_axis`` breakdown
+    mapping the instruction's replica groups (or ppermute pairs) onto
+    the mesh axes — dp vs tp vs ep traffic becomes directly
+    attributable in dryrun output.
     """
     from ..analysis.costmodel import iter_instructions
-    instrs = list(iter_instructions(hlo_text))
-    by_comp = {}
-    for ins in instrs:
-        by_comp.setdefault(ins.computation, []).append(ins)
-    m = _NUM_PARTITIONS_RE.search(hlo_text)
-    num_partitions = int(m.group(1)) if m else 1
-    fused = _fused_reduce_scatters(by_comp, num_partitions)
     labeler = AxisLabeler(mesh) if mesh is not None else None
     out = {}
-    for ins in instrs:
+    for ins in iter_instructions(hlo_text):
         op = ins.opcode
         is_start = op.endswith("-start")
-        base = op[:-len("-start")] if is_start else op
-        if base not in _COLLECTIVES or op.endswith("-done"):
+        kind = op[:-len("-start")] if is_start else op
+        if kind not in _COLLECTIVES or op.endswith("-done"):
             continue
-        key = (ins.computation, ins.name)
-        if key in fused:
-            kind, payload = "reduce-scatter", fused[key]
-        elif is_start:
-            # async -start result types bundle (operand, result[,
-            # scratch]) shapes; the operand shapes from the call args are
-            # what the collective is fed (asymmetric all-gather/
-            # reduce-scatter fix)
-            kind, payload = base, _shape_bytes(ins.operands)
-        else:
-            kind, payload = base, ins.result_bytes
+        # async -start result types bundle (operand, result[, scratch])
+        # shapes; the operand shapes are what the collective is fed
+        # (asymmetric all-gather/reduce-scatter fix)
+        payload = ins.operand_bytes if is_start else ins.result_bytes
         slot = out.setdefault(kind, {"count": 0, "bytes": 0})
         slot["count"] += 1
         slot["bytes"] += payload
-        if key in fused:
-            slot["fused_from_all_reduce"] = \
-                slot.get("fused_from_all_reduce", 0) + 1
         if labeler is not None:
             axis = labeler.label(ins)
             ba = slot.setdefault("by_axis", {}).setdefault(
@@ -362,15 +258,16 @@ def collective_wire_bytes(kind, payload_bytes, n_devices):
     return payload_bytes
 
 
-def zero_update_model_bytes(shardable_bytes, residual_bytes, dp):
+def zero_update_model_bytes(shardable_bytes, residual_bytes):
     """Analytic per-step collective PAYLOADS of the ZeRO sharded weight
-    update at dp degree ``dp`` (the audit-side model the dryrun holds
-    measurements against): the shardable grads reduce-scatter into 1/dp
-    shards, the updated weights all-gather back whole, and params with
-    no dp-divisible dim keep a plain all-reduce."""
-    return {"reduce-scatter": shardable_bytes // max(1, dp),
-            "all-gather": shardable_bytes,
-            "all-reduce": residual_bytes}
+    update (the audit-side model the dryrun holds measurements against),
+    as the installed compilers emit it: every gradient is all-reduced in
+    full and each device then slices out the shard it owns (neither
+    XLA:CPU nor libtpu 0.0.34 for v5e turns that pair into a
+    reduce-scatter — PERF.md, "ZeRO on the wire"), and the updated
+    weights of the shardable params all-gather back whole."""
+    return {"all-reduce": shardable_bytes + residual_bytes,
+            "all-gather": shardable_bytes}
 
 
 def hierarchical_allreduce_model_bytes(payload_bytes, islands, per_island,
@@ -432,7 +329,7 @@ def audit_report(tag, hlo_text, n_devices, params=None, ring_n=None,
     ``mesh`` adds the per-axis byte breakdown (dp/tp/sp/ep/pp traffic
     attributed from replica groups).  ``zero_model`` — the dict from
     :func:`zero_update_model_bytes` — swaps the plain grad-payload
-    comparison for the ZeRO reduce-scatter + all-gather model.
+    comparison for the ZeRO all-reduce + all-gather model.
     ``hier_model`` — from :func:`hierarchical_allreduce_model_bytes` —
     appends the two-tier comparison: per-kind measured/model payloads
     plus the slow-tier wire bytes against the flat-ring baseline.
@@ -443,11 +340,9 @@ def audit_report(tag, hlo_text, n_devices, params=None, ring_n=None,
     for kind in sorted(acct):
         info = acct[kind]
         wire = collective_wire_bytes(kind, info["bytes"], ring_n)
-        fused = info.get("fused_from_all_reduce")
-        parts.append("%s: %d ops%s, %.2f MB payload, %.2f MB/device on "
-                     "wire" % (kind, info["count"],
-                               " (%d fused ar+slice)" % fused if fused
-                               else "", info["bytes"] / 1e6, wire / 1e6))
+        parts.append("%s: %d ops, %.2f MB payload, %.2f MB/device on "
+                     "wire" % (kind, info["count"], info["bytes"] / 1e6,
+                               wire / 1e6))
     text = "collectives[%s, n=%d, ring=%d] " % (tag, n_devices, ring_n) + \
         ("; ".join(parts) if parts else "none")
     if mesh is not None:
@@ -463,11 +358,10 @@ def audit_report(tag, hlo_text, n_devices, params=None, ring_n=None,
         model = sum(zero_model.values())
         measured = sum(acct.get(k, {}).get("bytes", 0)
                        for k in zero_model)
-        text += (" | analytic ZeRO payload RS %.2f + AG %.2f + AR %.2f MB"
+        text += (" | analytic ZeRO payload AR %.2f + AG %.2f MB"
                  " (measured/model = %.2f)"
-                 % (zero_model.get("reduce-scatter", 0) / 1e6,
+                 % (zero_model.get("all-reduce", 0) / 1e6,
                     zero_model.get("all-gather", 0) / 1e6,
-                    zero_model.get("all-reduce", 0) / 1e6,
                     measured / model if model else float("nan")))
     if hier_model is not None:
         kinds = ("reduce-scatter", "all-reduce", "all-gather")

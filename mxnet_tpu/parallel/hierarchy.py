@@ -32,16 +32,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:   # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["two_tier_psum", "hierarchical_allreduce", "flat_allreduce"]
 
-# jax < 0.5 shard_map needs check_rep=False for programs whose
-# replication the checker can't prove; jax >= 0.5 dropped the kwarg
-_COMPAT = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
 
 
 def _count(shape) -> int:
@@ -92,7 +86,7 @@ def hierarchical_allreduce(stacked, mesh, slow_axis: str = "island",
         return out[None]
 
     mapped = shard_map(per_device, mesh=mesh, in_specs=spec,
-                       out_specs=spec, **_COMPAT)
+                       out_specs=spec)
     from ..resilience import watchdog as _wd
     from .audit import hierarchical_allreduce_model_bytes, \
         record_collective
@@ -123,7 +117,7 @@ def flat_allreduce(stacked, mesh, slow_axis: str = "island",
         return jax.lax.psum(block[0], (slow_axis, fast_axis))[None]
 
     mapped = shard_map(per_device, mesh=mesh, in_specs=spec,
-                       out_specs=spec, **_COMPAT)
+                       out_specs=spec)
     from ..resilience import watchdog as _wd
     from .audit import record_collective
     world = int(mesh.shape[slow_axis]) * int(mesh.shape[fast_axis])

@@ -26,10 +26,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:   # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:   # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["moe_ffn", "moe_ffn_dense", "top1_gating"]
@@ -128,12 +125,10 @@ def moe_ffn(x, wg, w1, w2, mesh: Mesh, axis: str = "ep",
     t_local = T // n_dev
     capacity = max(1, math.ceil(t_local * capacity_factor / E))
     fn = _moe_local_fn(axis, capacity, activation)
-    # pre-pvary jax (< 0.6) cannot prove the dispatch carry's replication
-    compat = {} if hasattr(lax, "pvary") else {"check_rep": False}
     sharded = shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis), P(), P(axis), P(axis)),
-        out_specs=(P(axis), P()), **compat)
+        out_specs=(P(axis), P()))
     from .. import telemetry as _tel
     from ..resilience import watchdog as _wd
     from .audit import record_collective

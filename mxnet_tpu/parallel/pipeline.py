@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:   # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:   # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_apply", "PipelineRunner"]
 
@@ -90,12 +87,14 @@ def pipeline_apply(stage_fn: Callable, num_stages: int, mesh: Mesh,
                 outputs)
             return (y, buf_next, outputs), None
 
-        # lax.pvary (varying-axis annotation for check_vma) only exists on
-        # jax >= 0.6; on older versions zeros are already unvarying-safe
-        pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-        y0 = pvary(jnp.zeros(mb_shape, x_all.dtype), (axis,))
-        buf0 = pvary(jnp.zeros(mb_shape, x_all.dtype), (axis,))
-        outs0 = pvary(jnp.zeros((M,) + mb_shape, x_all.dtype), (axis,))
+        # the scan carries become device-varying on the first tick, so
+        # their zero initial values must be marked varying too (check_vma)
+        def varying_zeros(shape):
+            return jax.lax.pcast(jnp.zeros(shape, x_all.dtype), (axis,),
+                                 to="varying")
+        y0 = varying_zeros(mb_shape)
+        buf0 = varying_zeros(mb_shape)
+        outs0 = varying_zeros((M,) + mb_shape)
         (_, _, outputs), _ = jax.lax.scan(tick, (y0, buf0, outs0),
                                           jnp.arange(T))
         # only the last stage holds real outputs; broadcast them ring-wide
@@ -106,11 +105,8 @@ def pipeline_apply(stage_fn: Callable, num_stages: int, mesh: Mesh,
 
     in_specs = (P(axis), P())       # params sharded by stage; x replicated
     out_specs = P()
-    # pre-pvary jax (< 0.6) cannot prove the scan carry's replication;
-    # its own error message prescribes check_rep=False as the workaround
-    compat = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
     mapped = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **compat)
+                       out_specs=out_specs)
     from .. import telemetry as _tel
     from ..resilience import watchdog as _wd
     from .audit import record_collective

@@ -26,10 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:   # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:   # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["ring_attention", "local_ring_attention_fn"]
 
@@ -138,10 +135,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         scale = float(1.0 / np.sqrt(q.shape[-1]))
     fn = local_ring_attention_fn(axis, causal, scale, n)
     spec = P(None, axis, None, None)
-    # pre-pvary jax (< 0.6) cannot prove the ring loop carry's replication
-    compat = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
     mapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, **compat)
+                       out_specs=spec)
     sharding = NamedSharding(mesh, spec)
     from .. import telemetry as _tel
     from ..resilience import watchdog as _wd

@@ -51,6 +51,19 @@ def zero_enabled(shard_optimizer_state: bool, zero=None) -> bool:
     return bool(shard_optimizer_state)
 
 
+def _relaid(x, fmt):
+    """``x`` in the layout (and sharding) ``fmt``, checked: a re-lay that
+    comes back in another layout would only surface steps later, as the
+    compiled step refusing its own state."""
+    y = jax.device_put(x, fmt)
+    if y.format.layout != fmt.layout:
+        raise RuntimeError(
+            "re-laying %s%s for the AUTO-layout step gave layout %s, not "
+            "the %s that was asked for" % (x.dtype, x.shape,
+                                           y.format.layout, fmt.layout))
+    return y
+
+
 def _tree_sgd(params, grads, mom, lr, momentum, wd, rescale):
     new_params = []
     new_mom = []
@@ -199,6 +212,16 @@ class ShardedTrainer:
         against it at trace time, and watchdog post-mortems report it."""
         from .mesh import set_current_mesh
         set_current_mesh(self.spec)
+
+    def _tracing_on_mesh(self):
+        """Scope for everything that may trace the step: inside it jax's
+        own mesh context names this trainer's mesh, which is how an op
+        that must place itself by hand (the Pallas attention kernels,
+        ops/nn.py ``_per_mesh_shard``) knows the program being traced
+        spans it.  The armed MeshSpec above outlives the trace; this does
+        not, so a later single-device call is not mistaken for ours."""
+        self._arm_mesh()
+        return jax.set_mesh(self.spec.mesh)
 
     # -- state ------------------------------------------------------------
     def init_state(self, shapes: Dict[str, tuple], initializer=None,
@@ -476,7 +499,10 @@ class ShardedTrainer:
         donate_argnums = ((0, 1, 2, 5)
                           if not (_cc.enabled() and not _cc.donation_safe())
                           else ())
-        with self.spec.mesh:
+        # everything here that carries a layout — the step and the re-lay
+        # programs below — stays out of jax's persistent cache
+        # (compile/cache.outside_jax_cache says why)
+        with self._tracing_on_mesh(), _cc.outside_jax_cache():
             jitted = jax.jit(step_fn, in_shardings=in_shardings,
                              out_shardings=out_shardings,
                              donate_argnums=donate_argnums)
@@ -489,15 +515,12 @@ class ShardedTrainer:
                     (sds(guard[0]), sds(guard[1])))
                 compiled, cc_result = _cc.cached_compile(
                     lowered, "auto_layout", mesh=self.spec.mesh)
-                if cc_result == "hit":
-                    try:        # the re-lay below needs the layouts; a
-                        # deserialized executable that cannot expose
-                        # them degrades to a fresh compile
-                        _ = (getattr(compiled, "input_formats", None)
-                             or compiled.input_layouts)
-                    except Exception:
-                        compiled, cc_result = lowered.compile(), "miss"
                 _cs.attrs["result"] = cc_result
+            p_fmt, m_fmt, a_fmt = compiled.input_formats[0][:3]
+            params, mom, aux = (
+                tuple(_relaid(x, f) for x, f in zip(state, fmt))
+                for state, fmt in ((params, p_fmt), (mom, m_fmt),
+                                   (aux, a_fmt)))
         _tel.tracing.note_compile("train_step_auto_layout", _cs.duration,
                                   symbol=self.symbol.name or "symbol",
                                   result=cc_result)
@@ -508,12 +531,6 @@ class ShardedTrainer:
                                                 or "symbol"),
             n_devices=self.spec.mesh.size, ring_n=self.spec.dp_size,
             mesh=self.spec.mesh)
-        fmts = getattr(compiled, "input_formats",
-                       None) or compiled.input_layouts
-        p_fmt, m_fmt, a_fmt = fmts[0][:3]
-        params = tuple(jax.device_put(p, f) for p, f in zip(params, p_fmt))
-        mom = tuple(jax.device_put(m, f) for m, f in zip(mom, m_fmt))
-        aux = tuple(jax.device_put(a, f) for a, f in zip(aux, a_fmt))
         from ..telemetry import memory as _memory
         if _memory.enabled():
             # re-laid state carries fresh buffers; re-tag them and record
@@ -542,7 +559,7 @@ class ShardedTrainer:
             structs = jax.tree_util.tree_map(
                 sds, (params, mom, aux, inputs, keys,
                       self._guard_arrays()))
-            with self.spec.mesh:
+            with self._tracing_on_mesh():
                 lowered = self._step.lower(*structs)
             compiled, result = _cc.cached_compile(
                 lowered, "train_step", mesh=self.spec.mesh)
@@ -615,7 +632,7 @@ class ShardedTrainer:
                      jax.ShapeDtypeStruct((), jnp.int32))
         jitted = shadow._build_step()
         try:
-            with spec.mesh:
+            with shadow._tracing_on_mesh():
                 lowered = jitted.lower(
                     tuple(sds(p) for p in params),
                     tuple(sds(m) for m in mom),
@@ -765,9 +782,12 @@ class ShardedTrainer:
                             self._step_exec(params, mom, aux, inputs,
                                             keys, self._guard_arrays())
                     else:
-                        params, mom, aux, loss, ok, guard = self._step(
-                            params, mom, aux, inputs, keys,
-                            self._guard_arrays())
+                        # the first call traces, and so does any
+                        # later one that brings a new batch shape
+                        with self._tracing_on_mesh():
+                            params, mom, aux, loss, ok, guard = self._step(
+                                params, mom, aux, inputs, keys,
+                                self._guard_arrays())
                 if fresh_program:
                     from ..telemetry import tracing as _tracing
                     _cspan.attrs["result"] = cc_result
@@ -841,7 +861,8 @@ class ShardedTrainer:
             structs = jax.tree_util.tree_map(
                 sds, (params, mom, aux, inputs, keys,
                       self._guard_arrays()))
-            compiled = self._step.lower(*structs).compile()
+            with self._tracing_on_mesh():
+                compiled = self._step.lower(*structs).compile()
         except Exception:
             import logging
             logging.exception("attribution: step lowering failed "

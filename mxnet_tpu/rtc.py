@@ -28,11 +28,6 @@ import numpy as np
 from .base import MXNetError, dtype_np
 from .ndarray.ndarray import NDArray
 
-# jax.enable_x64 graduated from jax.experimental after 0.4.37; accept both
-_enable_x64_ctx = getattr(jax, "enable_x64", None)
-if _enable_x64_ctx is None:   # pragma: no cover - version-dependent
-    from jax.experimental import enable_x64 as _enable_x64_ctx
-
 __all__ = ["TPUModule", "TPUKernel", "CudaModule"]
 
 
@@ -70,7 +65,7 @@ class TPUKernel:
             kwargs["in_specs"] = self._in_specs
         if self._out_specs is not None:
             kwargs["out_specs"] = self._out_specs
-        with _enable_x64_ctx(False):   # grid index maps must stay i32
+        with jax.enable_x64(False):   # grid index maps must stay i32
             outs = pl.pallas_call(
                 self._fn, out_shape=out_shape,
                 interpret=_interpret(*arrays), **kwargs)(*arrays)

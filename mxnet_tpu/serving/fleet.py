@@ -189,7 +189,14 @@ class ServingFleet:
     drills).  ``replica_env`` maps slot -> extra env for that replica's
     process (chaos arming in drills: ``{1: {"MXNET_TPU_CHAOS":
     "hedge_lagx100000"}}``).  All ``FleetRouter`` keyword knobs pass
-    through ``router_kw``."""
+    through ``router_kw``.
+
+    Chips: a chip belongs to one process, and nothing here assigns one —
+    replicas that serve from a TPU must each be given their own through
+    ``replica_env`` (``{i: {"TPU_VISIBLE_CHIPS": str(i),
+    "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1", "TPU_PROCESS_BOUNDS":
+    "1,1,1"}}`` gives slot ``i`` chip ``i`` of a v5e host; PERF.md, PR 21),
+    and the process that builds the fleet must not have touched jax."""
 
     def __init__(self, n_replicas: int, *, artifact=None, synthetic=None,
                  fleet_dir=None, quotas=None, replica_env=None,

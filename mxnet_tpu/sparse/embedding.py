@@ -55,10 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:   # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:   # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import kernels as _kernels
 
@@ -135,15 +132,15 @@ def _plan(ids, S: int, rows_per: int, C: int, vpad: int):
     return uniq, inv, owner, pos, ok, dropped
 
 
+# Every shard_map below passes check_vma=False: the shard-local kernels may
+# be Pallas, and off-TPU the Pallas interpreter's own grid loop fails the
+# varying-axes check (jax 0.9.0: "dynamic_slice requires varying manual
+# axes to match").
+
 def _a2a(x, axis: str, S: int):
     if S == 1:
         return x
     return lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
-
-
-def _shard_compat():
-    # pre-pvary jax (< 0.6) cannot prove replication of routed carries
-    return {} if hasattr(lax, "pvary") else {"check_rep": False}
 
 
 class ShardedEmbedding:
@@ -304,7 +301,7 @@ class ShardedEmbedding:
             mapped = jax.jit(shard_map(fn, mesh=self.mesh,
                                        in_specs=(P(axis), P(axis)),
                                        out_specs=out_specs,
-                                       **_shard_compat()))
+                                       check_vma=False))
             self._programs[key] = mapped
         from .. import telemetry as _tel
         from ..parallel.audit import record_collective
@@ -480,7 +477,7 @@ class ShardedEmbedding:
                 mapped = jax.jit(shard_map(
                     fn, mesh=self.mesh,
                     in_specs=(P(axis), P(axis), P(axis)),
-                    out_specs=P(axis), **_shard_compat()))
+                    out_specs=P(axis), check_vma=False))
                 self._programs[key] = mapped
             with _tel.span("collective/embedding_update",
                            cat="collective",
@@ -495,7 +492,7 @@ class ShardedEmbedding:
                 mapped = jax.jit(shard_map(
                     fn, mesh=self.mesh,
                     in_specs=(P(axis), P(axis), P(axis), P(axis)),
-                    out_specs=(P(axis), P(axis)), **_shard_compat()))
+                    out_specs=(P(axis), P(axis)), check_vma=False))
                 self._programs[key] = mapped
             with _tel.span("collective/embedding_update",
                            cat="collective",
@@ -528,7 +525,7 @@ class ShardedEmbedding:
             mapped = jax.jit(shard_map(
                 fn, mesh=self.mesh,
                 in_specs=(P(axis),) * 5,
-                out_specs=(P(axis), P(axis), P(axis)), **_shard_compat()))
+                out_specs=(P(axis), P(axis), P(axis)), check_vma=False))
             self._programs[key] = mapped
         with _tel.span("collective/embedding_update", cat="collective",
                        metric="parallel.collective_seconds",

@@ -47,10 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax.enable_x64 graduated from jax.experimental after 0.4.37; accept both
-_enable_x64 = getattr(jax, "enable_x64", None)
-if _enable_x64 is None:   # pragma: no cover - version-dependent
-    from jax.experimental import enable_x64 as _enable_x64
+from ..ops.pallas_kernels import _interpret, _out_struct
 
 __all__ = ["embedding_gather", "embedding_scatter", "embed_backend",
            "tune_embedding", "gather_sig", "scatter_sig"]
@@ -113,11 +110,12 @@ def _gather_pallas(table, ids, interpret):
         # row DMA per grid step, straight from the table's HBM row
         in_specs=[pl.BlockSpec((1, dim), lambda i, ids_ref: (ids_ref[i], 0))],
         out_specs=pl.BlockSpec((1, dim), lambda i, ids_ref: (i, 0)))
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             _gather_kernel, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n, dim), table.dtype),
-            interpret=interpret)(ids.astype(jnp.int32), table)
+            out_shape=_out_struct((n, dim), table.dtype, ids, table),
+            interpret=interpret, name="embedding_gather",
+        )(ids.astype(jnp.int32), table)
 
 
 def embedding_gather(table, ids, backend=None):
@@ -129,7 +127,6 @@ def embedding_gather(table, ids, backend=None):
     if backend is None:
         backend = embed_backend("gather", rows, dim, n, table.dtype)
     if backend == "pallas":
-        from ..ops.pallas_kernels import _interpret
         return _gather_pallas(table, ids, _interpret(table))
     return jnp.take(table, ids.astype(jnp.int32), axis=0)
 
@@ -171,15 +168,17 @@ def _scatter_pallas(table, ids, rows, add, interpret):
             pl.BlockSpec((1, dim), lambda i, ids_ref: (ids_ref[i], 0)),
         ],
         out_specs=pl.BlockSpec((1, dim), lambda i, ids_ref: (ids_ref[i], 0)))
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             kern, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((nrows, dim), table.dtype),
+            out_shape=_out_struct((nrows, dim), table.dtype, ids, rows,
+                                  table),
             # the table IS the output: untouched rows never DMA, touched
             # rows read-modify-write in place (operand index counts the
             # scalar-prefetch arg: ids=0, rows=1, table=2)
             input_output_aliases={2: 0},
-            interpret=interpret)(ids32, rows.astype(table.dtype), table)
+            interpret=interpret, name="embedding_scatter",
+        )(ids32, rows.astype(table.dtype), table)
 
 
 def embedding_scatter(table, ids, rows, mode: str = "add", backend=None):
@@ -197,7 +196,6 @@ def embedding_scatter(table, ids, rows, mode: str = "add", backend=None):
     if backend is None:
         backend = embed_backend("scatter", nrows, dim, n, table.dtype)
     if backend == "pallas":
-        from ..ops.pallas_kernels import _interpret
         return _scatter_pallas(table, ids, rows, mode == "add",
                                _interpret(table))
     ids32 = ids.astype(jnp.int32)

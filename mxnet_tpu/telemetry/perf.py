@@ -181,11 +181,8 @@ class AttributionReport:
         coll = a.get("collectives") or {}
         for kind in sorted(coll):
             info = coll[kind]
-            fused = info.get("fused_from_all_reduce")
-            lines.append("collective %-20s %3d ops  %.2f MB payload%s"
-                         % (kind, info["count"], info["bytes"] / 1e6,
-                            "  (%d fused ar+slice)" % fused if fused
-                            else ""))
+            lines.append("collective %-20s %3d ops  %.2f MB payload"
+                         % (kind, info["count"], info["bytes"] / 1e6))
         by_axis = a.get("collectives_by_axis") or {}
         if by_axis:
             lines.append("collective bytes by axis: " + ", ".join(
@@ -346,8 +343,15 @@ def attribute_compiled(compiled, name: str, n_devices: int = 1,
                        device_s: Optional[float] = None,
                        hlo_text: Optional[str] = None,
                        mesh=None,
-                       extra: Optional[Dict] = None) -> AttributionReport:
+                       extra: Optional[Dict] = None,
+                       peaks_of: Optional[str] = None
+                       ) -> AttributionReport:
     """Build the attribution report for one compiled program.
+
+    The roofline and the MFU are taken against the published peaks of the
+    device kind ``peaks_of`` — by default the kind of the device the
+    process runs on.  A kind with no entry in ``costmodel.CHIP_PEAKS`` (a
+    CPU, say) gets the counts and no roofline, shares or MFU.
 
     ``measured_step_s`` anchors the roofline shares and MFU; when None
     the telemetry ``train.step_seconds`` histogram is consulted (armed
@@ -416,24 +420,33 @@ def attribute_compiled(compiled, name: str, n_devices: int = 1,
     if measured_step_s is None and host_s is None and device_s is None:
         measured_step_s, host_s, device_s = _measured_from_telemetry()
 
-    peaks = costmodel.chip_peaks()
+    import jax
+    devs = jax.devices()
+    peaks_of = peaks_of or devs[0].device_kind
     # HBM roofline prefers XLA's deduplicated traffic number; the
     # instruction-byte table is the per-class breakdown, not the roof
     instr_total = sum(b for dts in per_class.values()
                       for b in dts.values())
     hbm_bytes = float(bytes_accessed) if bytes_accessed else \
         float(instr_total)
-    roof = costmodel.roofline(fl["flops"], hbm_bytes, float(wire),
-                              peaks=peaks,
-                              measured_step_s=measured_step_s)
+    try:
+        peaks = costmodel.chip_peaks(peaks_of)
+    except ValueError as e:
+        peaks = None
+        roof = {"bound": "unknown", "no_peaks": str(e)}
+    else:
+        roof = costmodel.roofline(fl["flops"], hbm_bytes, float(wire),
+                                  peaks, measured_step_s=measured_step_s)
+        roof["peaks_of"] = peaks_of
 
     step: Dict = {}
     if measured_step_s:
         # ns precision: toy programs step in the sub-microsecond range
         # and a 6-digit round would zero them out (killing conformance)
         step["measured_s"] = round(float(measured_step_s), 9)
-        step["mfu"] = round(fl["flops"] / measured_step_s
-                            / peaks["flops"], 6)
+        if peaks:
+            step["mfu"] = round(fl["flops"] / measured_step_s
+                                / peaks["flops"], 6)
     if host_s is not None:
         step["host_enqueue_s"] = round(float(host_s), 9)
     if device_s is not None:
@@ -454,14 +467,9 @@ def attribute_compiled(compiled, name: str, n_devices: int = 1,
         if iv["bound_input"]:
             roof["bound"] = "input"
 
-    topo = {"n_devices": int(n_devices), "ring_n": int(ring_n)}
-    try:
-        import jax
-        devs = jax.devices()
-        topo["platform"] = jax.default_backend()
-        topo["device_kind"] = devs[0].device_kind
-    except Exception:
-        pass
+    topo = {"n_devices": int(n_devices), "ring_n": int(ring_n),
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind}
 
     data = {
         "kind": "attribution_report",

@@ -2,7 +2,7 @@
 point that executes collectives with no watchdog arming.  Parsed with
 ``in_library=True`` by tests/test_analysis.py; never imported."""
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

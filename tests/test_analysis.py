@@ -14,10 +14,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import mxnet_tpu as mx
 from mxnet_tpu.analysis import (Finding, PreflightError, Report, graphcheck,
@@ -27,17 +24,15 @@ from mxnet_tpu.parallel.mesh import MeshSpec, make_mesh
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURES = os.path.join(REPO, "tests", "fixtures")
 
-# pre-pvary jax cannot prove replication of some carries
-_COMPAT = {} if hasattr(lax, "pvary") else {"check_rep": False}
-
-
 def _mesh(n=2, axis="dp"):
     return make_mesh((n,), (axis,))
 
 
 def _smap(fn, mesh, in_specs, out_specs):
+    # check_vma off: these programs are seeded with divergent collectives
+    # on purpose, and graphcheck — not the tracer — must be what flags them
     return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **_COMPAT)
+                     check_vma=False)
 
 
 def _rules(report):
@@ -229,7 +224,7 @@ def test_reshard_chain_flagged():
     assert any(f.rule == "GC203" for f in rep.warnings())
 
 
-def test_check_replication_flags_large_replicated_on_model_axis():
+def test_check_model_axis_replication_flags_large_replicated_on_model_axis():
     mesh = make_mesh((2, 2), ("dp", "tp")) if jax.device_count() >= 4 \
         else make_mesh((1, 2), ("dp", "tp"))
     big = (2048, 2048)          # 16 MB f32 > default 8 MB threshold
@@ -238,10 +233,10 @@ def test_check_replication_flags_large_replicated_on_model_axis():
         ("big_sharded", big, 4, NamedSharding(mesh, P("tp", None))),
         ("small_replicated", (8, 8), 4, NamedSharding(mesh, P())),
     ]
-    rep = graphcheck.check_replication(entries, mesh, model_axes=("tp",))
+    rep = graphcheck.check_model_axis_replication(entries, mesh, model_axes=("tp",))
     assert [f.location for f in rep.warnings()] == ["big_replicated"]
     # pure-dp mesh: replication is the design, nothing fires
-    rep2 = graphcheck.check_replication(entries, _mesh(), model_axes=())
+    rep2 = graphcheck.check_model_axis_replication(entries, _mesh(), model_axes=())
     assert len(rep2) == 0
 
 
@@ -616,6 +611,8 @@ def test_tpulint_predict_self_run(tmp_path, capsys, monkeypatch):
     monkeypatch.setenv("MXNET_TPU_CALIBRATION_CACHE",
                        str(tmp_path / "calibration.json"))
     monkeypatch.setenv("MXNET_TPU_ATTRIBUTION_DIR", str(tmp_path / "rep"))
+    monkeypatch.setenv("BENCH_LEDGER", os.path.join(
+        REPO, "tests", "fixtures", "bench_ledger.jsonl"))
     clean = tmp_path / "clean.py"
     clean.write_text("X = 1\n")
     rc = tpulint.main(["--predict", str(clean), "--format", "json"])
@@ -630,14 +627,14 @@ def test_tpulint_predict_self_run(tmp_path, capsys, monkeypatch):
         assert r["budget"]["peak_hbm_bytes"] > 0
         assert r["basis"]["achievable_fraction"] > 0
         assert not r["over_budget"]
-    # the calibration store was fitted from the committed ledger
+    # the calibration store was fitted from the ledger BENCH_LEDGER names
     assert os.path.isfile(str(tmp_path / "calibration.json"))
     written = [f for f in os.listdir(str(tmp_path / "rep"))
                if f.startswith("predict-")]
     assert len(written) >= 6
 
 
-def test_hlo_diff_from_graphcheck_report(tmp_path, capsys, monkeypatch):
+def test_hlo_diff_from_saved_graphcheck_findings(tmp_path, capsys, monkeypatch):
     hlo_a = tmp_path / "a.hlo.txt"
     hlo_a.write_text(
         "  %x = f32[4]{0} add(f32[4]{0} %a, f32[4]{0} %b)\n"

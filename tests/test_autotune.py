@@ -107,13 +107,17 @@ def test_autotune_skips_failing_candidates():
     assert got == (2,)
 
 
-def test_autotune_all_fail_returns_default():
+def test_autotune_all_fail_raises():
+    """One failing candidate is data for the search; when every candidate
+    fails the default cannot run either, and that is an error carrying
+    the last failure — never a quiet fallback."""
     def measure(c):
         raise RuntimeError("no")
 
-    got = autotune.autotune("op", ("s3",), [(1,), (2,)], measure,
-                            default=(7,), force=True)
-    assert got == (7,)
+    with pytest.raises(RuntimeError, match="every candidate failed") as ei:
+        autotune.autotune("op", ("s3",), [(1,), (2,)], measure,
+                          default=(7,), force=True)
+    assert str(ei.value.__cause__) == "no"
     assert autotune.lookup("op", ("s3",)) is None
 
 

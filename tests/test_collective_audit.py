@@ -67,33 +67,14 @@ ENTRY %main (p0: f32[64,32]) -> f32[8,32] {
 }
 """
 
-# same shape but the slice offset is a constant — NOT partition-derived,
-# so the all-reduce really is a replica all-reduce and must stay one
-_PLAIN_AR_HLO = """
-ENTRY %main (p0: f32[64,32]) -> f32[8,32] {
-  %p0 = f32[64,32]{1,0} parameter(0)
-  %all-reduce = f32[64,32]{1,0} all-reduce(f32[64,32]{1,0} %p0), replica_groups=[1,8]<=[8], use_global_device_ids=true, to_apply=%add.clone
-  ROOT %dynamic-slice = f32[8,32]{1,0} dynamic-slice(f32[64,32]{1,0} %all-reduce, s32[] %c8, s32[] %c0), dynamic_slice_sizes={8,32}
-}
-"""
-
-
-def test_fused_allreduce_slice_classified_reduce_scatter():
-    """The ReduceScatterCreator pattern — an all-reduce whose every
-    consumer takes a partition-id-derived slice — is accounted as the
-    reduce-scatter it is on the wire (shard payload), with the
-    reclassification visible via fused_from_all_reduce."""
+def test_allreduce_then_partition_slice_is_counted_as_allreduce():
+    """An all-reduce whose only consumer slices out this partition's shard
+    still moves the full all-reduce on the wire: neither installed
+    compiler folds the pair into a reduce-scatter, so the accounting
+    reports the opcode that was emitted."""
     acct = collective_accounting(_FUSED_RS_HLO)
-    assert "all-reduce" not in acct
-    rs = acct["reduce-scatter"]
-    assert rs["count"] == 1 and rs["fused_from_all_reduce"] == 1
-    assert rs["bytes"] == 64 * 32 * 4 // 8      # the 1/8 shard
-
-
-def test_constant_slice_of_allreduce_stays_allreduce():
-    acct = collective_accounting(_PLAIN_AR_HLO)
     assert "reduce-scatter" not in acct
-    assert acct["all-reduce"]["bytes"] == 64 * 32 * 4
+    assert acct["all-reduce"] == {"count": 1, "bytes": 64 * 32 * 4}
 
 
 def test_replica_groups_parsing_both_syntaxes():
@@ -145,9 +126,8 @@ def test_collective_wire_models():
     # all-gather payload is the gathered result: (n-1)/n of it on wire
     assert collective_wire_bytes("all-gather", 1000, 8) == 7 * 1000 // 8
     assert collective_wire_bytes("collective-permute", 42, 8) == 42
-    m = zero_update_model_bytes(8000, 30, 8)
-    assert m == {"reduce-scatter": 1000, "all-gather": 8000,
-                 "all-reduce": 30}
+    m = zero_update_model_bytes(8000, 30)
+    assert m == {"all-reduce": 8030, "all-gather": 8000}
 
 
 def test_async_start_counts_operand_shapes_only():

@@ -118,7 +118,7 @@ def test_treedef_codec_rejects_custom_nodes():
 
 
 def test_cache_location_convention(monkeypatch):
-    # default: under ~/.cache/mxnet_tpu
+    # default: under <checkout>/.cache
     monkeypatch.delenv("MXNET_TPU_TESTX_CACHE", raising=False)
     loc = paths_mod.cache_location("MXNET_TPU_TESTX_CACHE", "x.json")
     assert loc == os.path.join(paths_mod.cache_root(), "x.json")
@@ -467,10 +467,10 @@ def test_benchwatch_single_excursion_uses_floor_band():
                                       "regression": False, "n": 1}
 
 
-def test_committed_ledger_still_green():
+def test_recorded_ledger_still_green():
     bw = _benchwatch()
     ok, results = bw.check_ledger(bw.read_ledger(
-        os.path.join(REPO, "PERF_LEDGER.jsonl")))
+        os.path.join(REPO, "tests", "fixtures", "bench_ledger.jsonl")))
     assert ok, results
 
 

@@ -12,14 +12,19 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # heavyweight scripts (tier-1 runs `-m 'not slow'` under a time budget;
 # the PR-16 re-profile on the 1-core rig added the 8-20 s scripts below —
 # their model families keep symbol/module coverage in test_model_symbols
-# and ~19 faster example scripts stay in the default selection)
+# and ~19 faster example scripts stay in the default selection; PR 21
+# brought 42 tests back to passing and added tests/test_chip_smoke.py, and
+# paid for their seconds with the four slowest scripts that were left,
+# 65 s together)
 _SLOW = {"detection/train_ssd_toy.py", "captcha/ocr_ctc.py",
          "capsnet/capsnet_digits.py",
          "deep_embedded_clustering/dec_digits.py",
          "fcn_xs/fcn_segmentation.py",
          "detection/train_frcnn_toy.py",
          "gan/dcgan.py",
-         "reinforcement_learning/dqn_gridworld.py"}
+         "reinforcement_learning/dqn_gridworld.py",
+         "nce_loss/nce_lm.py", "stochastic_depth/sd_digits.py",
+         "vae/vae_digits.py", "time_series/lstm_forecast.py"}
 
 EXAMPLES = [
     ("image_classification/train_mlp.py", "train_mlp example OK"),

@@ -203,18 +203,14 @@ def test_trainer_step_memory_predicted_vs_compiled_within_20pct():
 def test_ring_memory_predicted_vs_compiled_within_20pct():
     from mxnet_tpu.parallel.mesh import make_mesh
     from mxnet_tpu.parallel.ring import local_ring_attention_fn
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     n = min(2, jax.device_count())
     mesh = make_mesh((n,), ("sp",))
     fn = local_ring_attention_fn("sp", causal=True, scale=1.0,
                                  num_devices=n)
-    compat = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
     mapped = shard_map(fn, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
-                       out_specs=P(None, "sp"), **compat)
+                       out_specs=P(None, "sp"))
     blk = jnp.ones((1, 2 * n, 2, 4), jnp.float32)
     compiled = jax.jit(mapped).lower(blk, blk, blk).compile()
     mem = _memory_section_of(compiled, "ring_attention")

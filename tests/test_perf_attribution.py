@@ -33,6 +33,10 @@ from mxnet_tpu.telemetry import perf
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# these tests run on the CPU; where one checks the roofline or the MFU
+# arithmetic it says which chip's published peaks to take them against
+V5E = "TPU v5 lite"
+
 
 def _load_tool(name):
     spec = importlib.util.spec_from_file_location(
@@ -89,14 +93,9 @@ def test_analytic_flops_conv_backward_dilated():
 
 def _psum_compiled():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-        smap = lambda f, mesh: shard_map(  # noqa: E731
-            f, mesh=mesh, in_specs=P("dp"), out_specs=P())
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        smap = lambda f, mesh: shard_map(  # noqa: E731
-            f, mesh=mesh, in_specs=P("dp"), out_specs=P())
+    from jax import shard_map
+    smap = lambda f, mesh: shard_map(  # noqa: E731
+        f, mesh=mesh, in_specs=P("dp"), out_specs=P())
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("dp",))
 
     def f(x):
@@ -217,18 +216,13 @@ def test_overlap_ring_and_pipeline_schedules():
     import mxnet_tpu  # noqa: F401
     from mxnet_tpu.parallel.mesh import make_mesh
     from mxnet_tpu.parallel.ring import local_ring_attention_fn
-    try:
-        from jax import shard_map as smap2
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as smap2
+    from jax import shard_map as smap2
     from jax.sharding import PartitionSpec as PS
     n = 2
     mesh = make_mesh((n,), ("sp",))
-    compat = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
     fn = local_ring_attention_fn("sp", False, 0.25, n)
     spec = PS(None, "sp", None, None)
-    mapped = smap2(fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-                   **compat)
+    mapped = smap2(fn, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
     x = jnp.ones((1, 4 * n, 2, 8), jnp.float32)
     txt = jax.jit(mapped).lower(x, x, x).compile().as_text()
     ov = costmodel.collective_compute_overlap(txt)
@@ -268,7 +262,8 @@ def test_attribute_compiled_report_schema(tmp_path):
     c = jax.jit(lambda a, b: a @ b).lower(
         jnp.ones((128, 128), jnp.float32),
         jnp.ones((128, 128), jnp.float32)).compile()
-    rep = perf.attribute_compiled(c, "matmul", measured_step_s=1e-5)
+    rep = perf.attribute_compiled(c, "matmul", measured_step_s=1e-5,
+                                  peaks_of=V5E)
     d = rep.to_dict()
     assert d["kind"] == "attribution_report"
     assert d["hlo_cost"]["flops_ratio_analytic_vs_hlo"] == pytest.approx(
@@ -296,7 +291,8 @@ def test_phases_block_shape():
     c = jax.jit(lambda a, b: a @ b).lower(
         jnp.ones((64, 64), jnp.float32),
         jnp.ones((64, 64), jnp.float32)).compile()
-    rep = perf.attribute_compiled(c, "bench.toy", measured_step_s=0.002)
+    rep = perf.attribute_compiled(c, "bench.toy", measured_step_s=0.002,
+                                  peaks_of=V5E)
     block = perf.phases_block(rep, "/tmp/r.json")
     assert {"bound", "compute_share", "hbm_share", "collective_share",
             "host_share", "mfu", "overlap_pct", "report"} <= set(block)
@@ -378,7 +374,7 @@ def test_transformer_attribution_matches_bench_formula():
     step, params, mom, aux = tr.build_step_auto_layout(
         params, mom, aux, shapes)
     rep = perf.attribute_compiled(step, "transformer",
-                                  measured_step_s=0.1)
+                                  measured_step_s=0.1, peaks_of=V5E)
     d = rep.to_dict()
     bi = _load_tool("bench_ideal")
     formula = bi.transformer_flops_per_step(batch, seq, layers, hidden,
@@ -395,42 +391,6 @@ def test_transformer_attribution_matches_bench_formula():
     # named top contributors
     assert d["analytic"]["bytes_by_dtype"]
     assert len(d["analytic"]["top_contributors"]) >= 3
-
-
-@pytest.mark.slow
-def test_bench_py_emits_phases_and_feeds_ledger(tmp_path):
-    """Bench-backed e2e: `python bench.py` (transformer, toy geometry)
-    emits the self-describing phases block — bench MFU == attribution
-    MFU — and appends to the BENCH_LEDGER trajectory."""
-    import subprocess
-    import sys
-    ledger = str(tmp_path / "ledger.jsonl")
-    attr = str(tmp_path / "attr.json")
-    env = dict(os.environ, BENCH_MODEL="transformer", BENCH_LAYERS="2",
-               BENCH_HIDDEN="128", BENCH_HEADS="4", BENCH_SEQ="128",
-               BENCH_VOCAB="512", BENCH_BATCH="2", BENCH_ITERS="3",
-               BENCH_WARMUP="1", BENCH_LEDGER=ledger,
-               BENCH_ATTRIBUTION_PATH=attr, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                       env=env, capture_output=True, text=True,
-                       timeout=900)
-    assert r.returncode == 0, r.stderr[-1500:]
-    doc = json.loads(r.stdout.strip().splitlines()[-1])
-    phases = doc["phases"]
-    assert phases["bound"] in ("compute", "hbm", "collective", "host")
-    assert phases["report"] == attr
-    full = json.load(open(attr))
-    assert full["hlo_cost"]["flops_ratio_analytic_vs_hlo"] \
-        == pytest.approx(1.0, abs=0.05)
-    # bench MFU and attribution MFU must agree (acceptance: within 0.02
-    # at the real operating point; here both are computed from the same
-    # measured time, so agreement is a flops-model statement)
-    assert phases["mfu"] == pytest.approx(doc["mfu"], abs=0.02)
-    bw = _load_tool("benchwatch")
-    entries = bw.read_ledger(ledger)
-    assert len(entries) == 1
-    assert "transformer_train_tokens_per_sec_per_chip" \
-        in entries[0]["metrics"]
 
 
 # ---------------------------------------------------------------------------
@@ -463,11 +423,12 @@ def test_benchwatch_short_series_not_gated():
                                       "regression": False, "n": 1}
 
 
-def test_benchwatch_committed_ledger_green():
-    """--check on the committed r01→r05 trajectory must pass (the 0.2%
-    r02→r03 dip is inside the noise floor)."""
+def test_benchwatch_recorded_ledger_green():
+    """--check on the recorded r01→r05 trajectory (a fixture: those rounds
+    ran on a set-up that no longer exists) must pass — the 0.2% r02→r03
+    dip is inside the noise floor."""
     bw = _load_tool("benchwatch")
-    ledger = os.path.join(REPO, "PERF_LEDGER.jsonl")
+    ledger = os.path.join(REPO, "tests", "fixtures", "bench_ledger.jsonl")
     entries = bw.read_ledger(ledger)
     assert len(entries) >= 5
     ok, results = bw.check_ledger(entries)

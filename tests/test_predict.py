@@ -18,10 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
@@ -35,13 +32,10 @@ from mxnet_tpu.telemetry import perf
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_COMPAT = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
-
-
 @pytest.fixture(autouse=True)
 def _isolated_plane(tmp_path, monkeypatch):
     """Every test gets its own calibration store + clean noted budgets;
-    nothing leaks into (or reads) the developer's ~/.cache store."""
+    nothing leaks into (or reads) the checkout's .cache store."""
     monkeypatch.setenv("MXNET_TPU_CALIBRATION_CACHE",
                        str(tmp_path / "calibration.json"))
     for var in ("MXNET_TPU_STEP_BUDGET_MS", "MXNET_TPU_WIRE_BUDGET_MB",
@@ -107,10 +101,11 @@ def test_achievable_fraction_fallback_ladder():
     assert miss["source"] == "default" and miss["n"] == 0
 
 
-def test_fit_from_ledger_committed_and_synthetic(tmp_path):
-    # the committed ledger must yield a usable compute fraction
+def test_fit_from_ledger_fixture_and_synthetic(tmp_path):
+    # the recorded trajectory must yield a usable compute fraction
     store = predict.fit_from_ledger(
-        ledger_path=os.path.join(REPO, "PERF_LEDGER.jsonl"), kind="cpu")
+        os.path.join(REPO, "tests", "fixtures", "bench_ledger.jsonl"),
+        kind="cpu")
     e = store["entries"]["cpu|compute"]
     assert 0.0 < e["achievable_fraction"] <= 1.0
     assert e["source"] == "ledger" and e["n"] >= 1
@@ -123,7 +118,7 @@ def test_fit_from_ledger_committed_and_synthetic(tmp_path):
             {"metrics": {"tokens_per_sec": 9e9}},  # not an mfu
             {"not": "json-with-metrics"}]
     path.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    store2 = predict.fit_from_ledger(ledger_path=str(path), kind="x")
+    store2 = predict.fit_from_ledger(str(path), kind="x")
     e2 = store2["entries"]["x|compute"]
     assert e2["achievable_fraction"] == pytest.approx(0.40)
     assert e2["n"] == 3
@@ -238,7 +233,8 @@ def _agreement(compiled, name, measured_s, tmp_path):
     store: the budget must land within the conformance floor (20%) of
     what was measured."""
     data = perf.attribute_compiled(
-        compiled, name, measured_step_s=measured_s).to_dict()
+        compiled, name, measured_step_s=measured_s,
+        peaks_of=predict.TARGET_DEVICE_KIND).to_dict()
     store = predict.load_store(str(tmp_path / "agree.json"))
     assert predict.fit_from_attribution(store, data) is not None
     rep = predict.predict_budget(compiled, name, store=store)
@@ -284,7 +280,7 @@ def test_ring_prediction_agreement(tmp_path):
     fn = local_ring_attention_fn("sp", causal=True, scale=1.0,
                                  num_devices=n)
     mapped = shard_map(fn, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
-                       out_specs=P(None, "sp"), **_COMPAT)
+                       out_specs=P(None, "sp"))
     jitted = jax.jit(mapped)
     blk = jnp.ones((1, 128 * n, 8, 32), jnp.float32)
     out = jitted(blk, blk, blk)                          # compile + warm
@@ -403,7 +399,8 @@ def test_attribution_report_carries_conformance(tmp_path, monkeypatch):
     budget = predict.predict_budget(c, "matmul64")
     slow = budget["budget"]["step_time_s"] / 0.4          # 2.5x budget
     telemetry.arm()
-    rep = perf.attribute_compiled(c, "matmul64", measured_step_s=slow)
+    rep = perf.attribute_compiled(c, "matmul64", measured_step_s=slow,
+                                  peaks_of=predict.TARGET_DEVICE_KIND)
     d = rep.to_dict()
     conf = d["conformance"]
     assert conf["verdict"] == "VIOLATED"

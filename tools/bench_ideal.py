@@ -3,7 +3,7 @@
 yardstick for bench.py (PERF.md).  No framework code: raw jax.numpy +
 lax convs in NHWC, bf16 params/activations with fp32 BN stats, fused
 fwd+bwd+SGD(momentum+wd) step with full buffer donation.  Methodology
-matches bench.py exactly: warmup, 100-iter chain, float(loss) sync.
+matches bench.py exactly: warmup, 100-iter chain, block_until_ready.
 
 BENCH_ARCH=v2 (default) mirrors the framework bench's architecture
 EXACTLY (models/resnet.py: pre-activation v2, data-BN stem, eps=2e-5)
@@ -275,6 +275,19 @@ def _t_forward(p, ids, layers, heads):
     return dense("head", x).astype(jnp.float32)
 
 
+def _chip_peaks(device_kind):
+    """The one peaks table (analysis/costmodel.CHIP_PEAKS), loaded by path:
+    importing the mxnet_tpu package would switch this process's jax to x64
+    and 'highest' matmul precision, and the yardstick must stay plain jax."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "mxnet_tpu", "analysis", "costmodel.py")
+    spec = importlib.util.spec_from_file_location("_costmodel_peaks", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.chip_peaks(device_kind)
+
+
 def _transformer_main():
     batch = int(os.environ.get("BENCH_BATCH", "8"))
     seq = int(os.environ.get("BENCH_SEQ", "1024"))
@@ -284,7 +297,7 @@ def _transformer_main():
     vocab = int(os.environ.get("BENCH_VOCAB", "32768"))
     warmup = int(os.environ.get("BENCH_WARMUP", "5"))
     iters = int(os.environ.get("BENCH_ITERS", "30"))
-    peak = float(os.environ.get("BENCH_PEAK_TFLOPS", "197")) * 1e12
+    peak = _chip_peaks(jax.devices()[0].device_kind)["flops"]
 
     key = jax.random.PRNGKey(0)
     params = _t_init(key, vocab, seq, layers, hidden)
@@ -316,11 +329,11 @@ def _transformer_main():
 
     for _ in range(warmup):
         params, mom, loss = step(params, mom, ids, labels)
-    float(loss)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(iters):
         params, mom, loss = step(params, mom, ids, labels)
-    float(loss)
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
     tok_s = batch * seq * iters / dt
     mfu = transformer_flops_per_step(batch, seq, layers, hidden,
@@ -356,11 +369,11 @@ def main():
 
     for _ in range(warmup):
         params, mom, stats, loss = train_step(params, mom, stats, x, labels)
-    float(loss)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(iters):
         params, mom, stats, loss = train_step(params, mom, stats, x, labels)
-    float(loss)
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
     print(json.dumps({
         "metric": "resnet50_ideal_img_per_sec",

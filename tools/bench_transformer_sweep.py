@@ -5,12 +5,14 @@ For each sequence length, runs the framework train step (bench.py's
 exact program) and the hand-written pure-JAX ideal
 (tools/bench_ideal.py geometry: 12L/768H/12 heads) with one warmup
 then WINDOWS timed chains of ITERS fused steps, reporting
-mean +/- sigma tokens/sec and MFU (BENCH_PEAK_TFLOPS, default 197 =
-TPU v5e bf16 peak).  Tokens per batch are held at 8192 across T so
-memory stays flat (bs = 8192 / T).
+mean +/- sigma tokens/sec and MFU against the published bf16 peak of the
+device it ran on (analysis.costmodel.chip_peaks; a device with no entry
+there, the CPU included, is an error).  Tokens per batch are held at 8192
+across T so memory stays flat (bs = 8192 / T).
 
 Usage: python tools/bench_transformer_sweep.py [T ...]   (default 1024 2048 4096)
-Emits one JSON line per (program, T).
+Emits one JSON line per (program, T), each naming its device; exits
+non-zero if any (program, T) failed.
 """
 import functools
 import json
@@ -27,7 +29,6 @@ LAYERS, HIDDEN, HEADS, VOCAB = 12, 768, 12, 32768
 TOKENS = int(os.environ.get("BENCH_TOKENS", "8192"))
 ITERS = int(os.environ.get("BENCH_ITERS", "20"))
 WINDOWS = int(os.environ.get("BENCH_WINDOWS", "5"))
-PEAK = float(os.environ.get("BENCH_PEAK_TFLOPS", "197")) * 1e12
 
 
 def timed_windows(step_once):
@@ -44,10 +45,16 @@ def timed_windows(step_once):
 
 
 def report(tag, seq, batch, spans, flops_per_step, phases=None):
+    import jax
+    from mxnet_tpu.analysis.costmodel import chip_peaks
+    from mxnet_tpu.context import device_summary
+    dev = jax.devices()[0]
+    peak = chip_peaks(dev.device_kind)["flops"]
     toks = [batch * seq * ITERS / s for s in spans]
-    mfus = [flops_per_step * ITERS / s / PEAK for s in spans]
+    mfus = [flops_per_step * ITERS / s / peak for s in spans]
     doc = {
         "program": tag, "seq": seq, "batch": batch,
+        "device": device_summary([dev]),
         "tokens_per_sec_mean": round(statistics.mean(toks), 1),
         "tokens_per_sec_std": round(statistics.stdev(toks), 1),
         "mfu_mean": round(statistics.mean(mfus), 4),
@@ -63,15 +70,12 @@ def attribution_phases(step, measured_step_s):
     """bench.py's phases block, reused here (satellite: every sweep line
     is self-describing).  ``step`` must be an AOT Compiled (the
     framework path); returns None for plain jitted callables."""
-    try:
-        if not hasattr(step, "as_text"):
-            return None
-        from mxnet_tpu.telemetry import perf as _perf
-        rep = _perf.attribute_compiled(step, "sweep.framework",
-                                       measured_step_s=measured_step_s)
-        return _perf.phases_block(rep)
-    except Exception as e:
-        return {"error": str(e)[:200]}
+    if not hasattr(step, "as_text"):
+        return None
+    from mxnet_tpu.telemetry import perf as _perf
+    rep = _perf.attribute_compiled(step, "sweep.framework",
+                                   measured_step_s=measured_step_s)
+    return _perf.phases_block(rep)
 
 
 def run_framework(seq, batch):
@@ -105,7 +109,7 @@ def run_framework(seq, batch):
     def step_once():
         state[0], state[1], state[2], state[3], _ok, state[4] = step(
             state[0], state[1], state[2], feed, keys, state[4])
-    step_once.sync = lambda: float(state[3])
+    step_once.sync = lambda: jax.block_until_ready(state[3])
     spans = timed_windows(step_once)
     phases = attribution_phases(
         step, statistics.mean(spans) / ITERS)
@@ -148,7 +152,7 @@ def run_ideal(seq, batch):
 
     def step_once():
         state[0], state[1], state[2] = step(state[0], state[1], ids, labels)
-    step_once.sync = lambda: float(state[2])
+    step_once.sync = lambda: jax.block_until_ready(state[2])
     return timed_windows(step_once)
 
 
@@ -176,18 +180,22 @@ def main():
         return
     seqs = [int(a) for a in sys.argv[1:]] or [1024, 2048, 4096]
     me = os.path.abspath(__file__)
+    failed = []
     for seq in seqs:
         for program in ("framework", "ideal"):
+            # the child's errors go straight to this process's stderr
             r = subprocess.run([sys.executable, me, "--one", program,
-                                str(seq)], text=True, capture_output=True)
+                                str(seq)], text=True,
+                               stdout=subprocess.PIPE)
             sys.stdout.write(r.stdout)
-            if r.returncode != 0:
-                sys.stdout.write(json.dumps(
-                    {"program": program, "seq": seq, "error":
-                     r.stderr.strip().splitlines()[-1][:200]
-                     if r.stderr.strip() else "rc=%d" % r.returncode})
-                    + "\n")
             sys.stdout.flush()
+            if r.returncode != 0:
+                failed.append((program, seq, r.returncode))
+    if failed:
+        # the sweep goes on past a failure (T=32k not fitting is a
+        # result), but it does not end in success
+        sys.exit("bench_transformer_sweep: failed (program, T, exit code): "
+                 "%s" % failed)
 
 
 if __name__ == "__main__":

@@ -60,11 +60,11 @@ def main():
             return outs[0]
 
         out = score(args, aux, keys)
-        float(out.sum())                       # compile + sync
+        jax.block_until_ready(out)             # compile + sync
         t0 = time.perf_counter()
         for _ in range(iters):
             out = score(args, aux, keys)
-        float(out.sum())
+        jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         print(json.dumps({
             "metric": "resnet50_score_img_per_sec",

@@ -10,13 +10,13 @@ the same run it lands instead of being noticed three rounds later
 (exactly how the r01→r05 plateau went unflagged).
 
 Usage:
-    python tools/benchwatch.py append --from-bench BENCH_r05.json
-    python tools/benchwatch.py append --metric transformer_mfu=0.41
-    python tools/benchwatch.py check [--json]       # or: --check
-    python tools/benchwatch.py show
+    python tools/benchwatch.py append --ledger L --from-bench bench.json
+    python tools/benchwatch.py append --ledger L --metric transformer_mfu=0.41
+    python tools/benchwatch.py check --ledger L [--json]    # or: --check
+    python tools/benchwatch.py show --ledger L
 
-    --ledger PATH   ledger file (default: PERF_LEDGER.jsonl next to the
-                    repo root)
+    --ledger PATH   ledger file, required (PERF_LEDGER.jsonl at the repo
+                    root is the PR driver's record, not this tool's)
     --sigma N       regression threshold in noise sigmas (default 4)
     --floor F       minimum relative drop to flag regardless of sigma
                     (default 0.05 = 5%: sub-noise-floor trajectories
@@ -51,9 +51,6 @@ import os
 import statistics
 import sys
 import time
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_LEDGER = os.path.join(_REPO, "PERF_LEDGER.jsonl")
 
 SIGMA_MULT = 4.0
 FLOOR = 0.05
@@ -358,7 +355,10 @@ def main(argv=None):
         argv[0] = "check"
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("command", choices=["append", "check", "show"])
-    ap.add_argument("--ledger", default=DEFAULT_LEDGER)
+    ap.add_argument("--ledger", required=True,
+                    help="the trajectory file to append to or gate; no "
+                         "default (PERF_LEDGER.jsonl at the repo root is "
+                         "the PR driver's record, not this tool's)")
     ap.add_argument("--from-bench", action="append", default=[],
                     metavar="JSON")
     ap.add_argument("--metric", action="append", default=[],

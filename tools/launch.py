@@ -200,8 +200,9 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--num-workers", type=int, required=True)
     ap.add_argument("--dist-device", default="cpu",
-                    help="device backend for workers (cpu uses gloo "
-                         "collectives; tpu expects a pod runtime)")
+                    help="device backend for workers: cpu (gloo "
+                         "collectives) is the only one this launcher "
+                         "can serve")
     ap.add_argument("--env", action="append", default=[],
                     help="extra KEY=VALUE env for workers")
     ap.add_argument("--max-restarts", type=int, default=0,
@@ -224,6 +225,13 @@ def main():
     args = ap.parse_args()
     if not args.command:
         ap.error("no command given")
+    if args.dist_device != "cpu":
+        ap.error("--dist-device %s: a chip belongs to one process, and this "
+                 "launcher starts %d on one host without giving each its "
+                 "own, so every rank would open every chip.  On one host a "
+                 "single process drives all of its chips (ShardedTrainer "
+                 "over a dp mesh); across hosts the pod runtime starts one "
+                 "process per host." % (args.dist_device, args.num_workers))
     if args.max_restarts < 0:
         ap.error("--max-restarts must be >= 0")
     if args.elastic:

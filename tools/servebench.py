@@ -224,8 +224,10 @@ def _main_decode(args):
     prog = DecodeProgram(init_decode_params(cfg, seed=0), cfg,
                          name="servebench-decode")
     prog.ensure_compiled()
-    n_dev = len([d for d in jax.devices()
-                 if d.platform != "cpu"]) or 1
+    # an un-meshed program serves from the default device alone; the
+    # report names it, and the rates below are that one device's
+    from mxnet_tpu.context import device_summary
+    dev = jax.devices()[0]
     rs = np.random.RandomState(0)
     plens = [int(x) for x in args.decode_prompts.split(",")]
     nnews = [int(x) for x in args.decode_new.split(",")]
@@ -300,6 +302,7 @@ def _main_decode(args):
         "mode": "decode",
         "requests": len(jobs),
         "slots": S,
+        "device": device_summary([dev]),
         "geometry": "L%d H%d heads%d V%d T%d page%d%s" % (
             cfg.num_layers, cfg.hidden, cfg.heads, cfg.vocab_size,
             cfg.max_seq_len, cfg.page_size,
@@ -307,8 +310,7 @@ def _main_decode(args):
         "continuous": {
             "wall_s": round(cont_wall, 3),
             "tokens": cont_tokens,
-            "tokens_per_sec_per_chip": round(
-                cont_tokens / cont_wall / n_dev, 1),
+            "tokens_per_sec": round(cont_tokens / cont_wall, 1),
             "occupancy_mean": d["occupancy_mean"],
             "latency": _percentiles(lat_hist),
             "errors": errors,
@@ -316,8 +318,7 @@ def _main_decode(args):
         "static": {
             "wall_s": round(static_wall, 3),
             "tokens": static_tokens,
-            "tokens_per_sec_per_chip": round(
-                static_tokens / static_wall / n_dev, 1),
+            "tokens_per_sec": round(static_tokens / static_wall, 1),
             "occupancy_mean": round(static_occ, 4),
             "latency": {"p50_ms": round(
                 1e3 * statistics.median(static_lat), 3),
@@ -329,8 +330,8 @@ def _main_decode(args):
         "decode_stats": d,
     }
     report["continuous_vs_static"] = round(
-        report["continuous"]["tokens_per_sec_per_chip"] /
-        max(report["static"]["tokens_per_sec_per_chip"], 1e-9), 3)
+        report["continuous"]["tokens_per_sec"] /
+        max(report["static"]["tokens_per_sec"], 1e-9), 3)
     # prediction-conformance mirror: measured decode tokens/s vs the
     # analytic decode budget (analysis/predict.py), plus the input-bound
     # verdict when an input pipeline fed this process — same sections
@@ -344,7 +345,7 @@ def _main_decode(args):
             quant_bits={"int8": 8, "int4": 4}.get(cfg.quantize, 32))
         conf = _predict.conformance(budget, {
             "decode_tokens_per_s":
-                report["continuous"]["tokens_per_sec_per_chip"]})
+                report["continuous"]["tokens_per_sec"]})
         if conf:
             report["conformance"] = conf
         iv = _perf.input_verdict(
@@ -358,15 +359,16 @@ def _main_decode(args):
         print()
         return 0
     print("servebench --decode: %d mixed-length requests over %d slots "
-          "(%s)" % (len(jobs), S, report["geometry"]))
+          "(%s) on one %s %s" % (len(jobs), S, report["geometry"],
+                                 dev.platform, dev.device_kind))
     print("  %-12s %10s %14s %10s %10s %10s" %
-          ("batching", "wall s", "tokens/s/chip", "occupancy",
+          ("batching", "wall s", "tokens/s", "occupancy",
            "p50 ms", "p99 ms"))
     for name in ("continuous", "static"):
         r = report[name]
         lat = r["latency"]
         print("  %-12s %10.3f %14.1f %10.3f %10s %10s"
-              % (name, r["wall_s"], r["tokens_per_sec_per_chip"],
+              % (name, r["wall_s"], r["tokens_per_sec"],
                  r["occupancy_mean"], lat.get("p50_ms", "-"),
                  lat.get("p99_ms", "-")))
     print("  continuous / static throughput: %.2fx  (compiles: %d)"

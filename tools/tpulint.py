@@ -47,10 +47,7 @@ def _graphcheck_builtin(report):
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import mxnet_tpu as mx
     from mxnet_tpu.analysis import graphcheck
@@ -61,7 +58,6 @@ def _graphcheck_builtin(report):
 
     n = min(2, jax.device_count())
     mesh = make_mesh((n,), ("dp",))
-    compat = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
 
     # ShardedTrainer toy step
     data = mx.sym.Variable("data")
@@ -81,7 +77,7 @@ def _graphcheck_builtin(report):
                                  num_devices=n)
     mapped = shard_map(fn, mesh=ring_mesh,
                        in_specs=(P(None, "sp"),) * 3,
-                       out_specs=P(None, "sp"), **compat)
+                       out_specs=P(None, "sp"))
     blk = jax.ShapeDtypeStruct((1, 2 * n, 2, 4), jnp.float32)
     report.extend(graphcheck.check_fn(mapped, blk, blk, blk,
                                       mesh=ring_mesh,
@@ -104,7 +100,7 @@ def _graphcheck_builtin(report):
                                   activation=jax.nn.relu)
     mapped = shard_map(local, mesh=ep_mesh,
                        in_specs=(P("ep"), P(), P("ep"), P("ep")),
-                       out_specs=(P("ep"), P()), **compat)
+                       out_specs=(P("ep"), P()))
     report.extend(graphcheck.check_fn(
         mapped,
         jax.ShapeDtypeStruct((4 * n, 8), jnp.float32),
@@ -216,10 +212,7 @@ def _predict_builtin():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import mxnet_tpu as mx
     from mxnet_tpu.analysis import predict
@@ -230,11 +223,13 @@ def _predict_builtin():
 
     n = min(2, jax.device_count())
     mesh = make_mesh((n,), ("dp",))
-    compat = {} if hasattr(jax.lax, "pvary") else {"check_rep": False}
-    # one calibration pass against the committed ledger so the budgets
-    # carry a fitted fraction even on a box that never ran telemetry
-    store = predict.fit_from_ledger()
-    predict.save_store(store)
+    # with BENCH_LEDGER naming a benchwatch trajectory, one calibration
+    # pass against it gives the budgets a fitted fraction even on a box
+    # that never ran telemetry
+    store = predict.load_store()
+    if os.environ.get("BENCH_LEDGER"):
+        store = predict.fit_from_ledger(os.environ["BENCH_LEDGER"], store)
+        predict.save_store(store)
     reports = []
 
     def run(tag, fn):
@@ -269,7 +264,7 @@ def _predict_builtin():
                                      num_devices=n)
         mapped = shard_map(fn, mesh=ring_mesh,
                            in_specs=(P(None, "sp"),) * 3,
-                           out_specs=P(None, "sp"), **compat)
+                           out_specs=P(None, "sp"))
         blk = jax.ShapeDtypeStruct((1, 2 * n, 2, 4), jnp.float32)
         compiled = jax.jit(mapped).lower(blk, blk, blk).compile()
         rep = predict.predict_budget(compiled, "ring", n_devices=n,
@@ -283,7 +278,7 @@ def _predict_builtin():
                                       activation=jax.nn.relu)
         mapped = shard_map(local, mesh=ep_mesh,
                            in_specs=(P("ep"), P(), P("ep"), P("ep")),
-                           out_specs=(P("ep"), P()), **compat)
+                           out_specs=(P("ep"), P()))
         compiled = jax.jit(mapped).lower(
             jax.ShapeDtypeStruct((4 * n, 8), jnp.float32),
             jax.ShapeDtypeStruct((8, n * 2), jnp.float32),
