@@ -1,0 +1,3 @@
+from benchmark.lib import readers
+
+read = readers.idle_pct
