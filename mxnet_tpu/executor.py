@@ -237,11 +237,15 @@ class GraphProgram:
             if node.op.needs_rng:
                 ins = [keys[key_idx]] + ins
                 key_idx += 1
-            out = node.op.fn(attrs, *ins)
-            out = out if isinstance(out, tuple) else (out,)
-            ann = node.attrs.get("__shard__") if node.attrs else None
-            if ann is not None:
-                out = _shard_constrain_outputs(out, ann, node.name)
+            # a stable device-side name per node (metadata only): the
+            # profiler's op_name path reads mx.<OpType>.<node>, and the
+            # backward ops inherit it through jvp/transpose
+            with jax.named_scope("mx.%s.%s" % (node.op.name, node.name)):
+                out = node.op.fn(attrs, *ins)
+                out = out if isinstance(out, tuple) else (out,)
+                ann = node.attrs.get("__shard__") if node.attrs else None
+                if ann is not None:
+                    out = _shard_constrain_outputs(out, ann, node.name)
             raw[id(node)] = out
             if tap:
                 taps.extend(out[:node.op.num_visible_outputs(attrs)])

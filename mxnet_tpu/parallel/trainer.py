@@ -337,8 +337,9 @@ class ShardedTrainer:
         def shard_grads(grads):
             if not zero:
                 return grads
-            return tuple(_placement.constrain(g, s)
-                         for g, s in zip(grads, zspecs))
+            with jax.named_scope("mx.zero_scatter"):
+                return tuple(_placement.constrain(g, s)
+                             for g, s in zip(grads, zspecs))
 
         def step_fn(params, mom, aux, inputs, keys, guard):
             scale, good = guard
@@ -378,22 +379,31 @@ class ShardedTrainer:
                         aux, jnp.float32(0.0), jnp.int32(0))
                 (grads, new_aux, loss, _), _ = jax.lax.scan(
                     micro_step, init, inputs)
-            new_params, new_mom = _tree_sgd(
-                params, grads, mom, lr, momentum, wd, 1.0 / scale)
-            ok = _guards.all_finite(loss, grads)
-            new_params = tuple(jnp.where(ok, np_, p)
-                               for np_, p in zip(new_params, params))
+            # stable device-side names (jax.named_scope: metadata only)
+            # so a trace can put device time down to the update, the
+            # guard, the loss-scale automaton and the ZeRO gather
+            with jax.named_scope("mx.update"):
+                new_params, new_mom = _tree_sgd(
+                    params, grads, mom, lr, momentum, wd, 1.0 / scale)
+            with jax.named_scope("mx.guard"):
+                ok = _guards.all_finite(loss, grads)
+                new_params = tuple(jnp.where(ok, np_, p)
+                                   for np_, p in zip(new_params, params))
             if zero:
                 # the weight all-gather: shard-updated params return to
                 # their parameter sharding (replicated over dp)
-                new_params = tuple(_placement.constrain(np_, s)
-                                   for np_, s in zip(new_params, pspecs))
-            new_mom = tuple(jnp.where(ok, nm, m)
-                            for nm, m in zip(new_mom, mom))
-            new_aux = tuple(jnp.where(ok, na, a)
-                            for na, a in zip(new_aux, aux))
-            new_scale, new_good = _guards.scale_update(
-                scale, good, ok, growth_interval, dynamic=dynamic)
+                with jax.named_scope("mx.zero_gather"):
+                    new_params = tuple(
+                        _placement.constrain(np_, s)
+                        for np_, s in zip(new_params, pspecs))
+            with jax.named_scope("mx.guard"):
+                new_mom = tuple(jnp.where(ok, nm, m)
+                                for nm, m in zip(new_mom, mom))
+                new_aux = tuple(jnp.where(ok, na, a)
+                                for na, a in zip(new_aux, aux))
+            with jax.named_scope("mx.loss_scale"):
+                new_scale, new_good = _guards.scale_update(
+                    scale, good, ok, growth_interval, dynamic=dynamic)
             return (new_params, new_mom, new_aux, loss, ok,
                     (new_scale, new_good))
 

@@ -153,8 +153,3 @@ class Scope:
     def __exit__(self, *a):
         t1 = time.perf_counter() * 1e6
         record_event(self.name, self._t0, t1 - self._t0, self.cat)
-
-
-def trace_annotate(name):
-    """jax-level named region (shows in XPlane)."""
-    return jax.profiler.TraceAnnotation(name)

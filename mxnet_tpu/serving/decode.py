@@ -438,43 +438,59 @@ class DecodeProgram:
             if count:
                 self.trace_count += 1
             S = c.max_seqs
-            x = params["tok_embed_weight"][tokens] \
-                + params["pos_embed"][positions]          # (S, hidden)
+            # stable device-side names (jax.named_scope: metadata only);
+            # no layer index in them, so the layers group in a trace
+            scope = jax.named_scope
+            with scope("mx.decode.embed"):
+                x = params["tok_embed_weight"][tokens] \
+                    + params["pos_embed"][positions]      # (S, hidden)
             for i in range(c.num_layers):
                 pfx = "l%d_" % i
-                a = ln(params, x, pfx + "ln1")
-                q = lin(params, a, pfx + "q").reshape(S, H, Dh)
-                k = lin(params, a, pfx + "k").reshape(S, H, Dh)
-                v = lin(params, a, pfx + "v").reshape(S, H, Dh)
+                with scope("mx.decode.ln"):
+                    a = ln(params, x, pfx + "ln1")
+                with scope("mx.decode.qkv"):
+                    q = lin(params, a, pfx + "q").reshape(S, H, Dh)
+                    k = lin(params, a, pfx + "k").reshape(S, H, Dh)
+                    v = lin(params, a, pfx + "v").reshape(S, H, Dh)
                 # in-place paged write: scatter this token's K/V into
                 # (physical page, offset) per slot — donated pool, so
                 # XLA updates in place and shapes never change
-                kv = kv.at[i, 0, phys, :, off, :].set(
-                    k.astype(kv.dtype))
-                kv = kv.at[i, 1, phys, :, off, :].set(
-                    v.astype(kv.dtype))
-                att = pk.decode_attention(
-                    q, kv[i, 0], kv[i, 1], page_table, seq_lens,
-                    use_pallas=False if sharded else None)
-                att = lin(params, att.reshape(S, c.hidden), pfx + "proj")
-                x = x + att
-                f = ln(params, x, pfx + "ln2")
-                f = lin(params, f, pfx + "ff1")
-                f = jax.nn.gelu(f, approximate=False)
-                f = lin(params, f, pfx + "ff2")
-                x = x + f
-            x = ln(params, x, "ln_f")
-            logits = lin(params, x, "head")               # (S, vocab)
-            if sharded:
-                # the row-sharded vocab head leaves logits tp-sharded;
-                # gather them INSIDE the program (this is the one
-                # all-gather the analytic model budgets) so sampling and
-                # the host fetch see replicated values
-                from jax.sharding import NamedSharding, PartitionSpec
-                logits = jax.lax.with_sharding_constraint(
-                    logits, NamedSharding(self.spec.mesh,
-                                          PartitionSpec()))
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with scope("mx.decode.kv_write"):
+                    kv = kv.at[i, 0, phys, :, off, :].set(
+                        k.astype(kv.dtype))
+                    kv = kv.at[i, 1, phys, :, off, :].set(
+                        v.astype(kv.dtype))
+                with scope("mx.decode.attn"):
+                    att = pk.decode_attention(
+                        q, kv[i, 0], kv[i, 1], page_table, seq_lens,
+                        use_pallas=False if sharded else None)
+                with scope("mx.decode.proj"):
+                    att = lin(params, att.reshape(S, c.hidden),
+                              pfx + "proj")
+                    x = x + att
+                with scope("mx.decode.ln"):
+                    f = ln(params, x, pfx + "ln2")
+                with scope("mx.decode.mlp"):
+                    f = lin(params, f, pfx + "ff1")
+                    f = jax.nn.gelu(f, approximate=False)
+                    f = lin(params, f, pfx + "ff2")
+                    x = x + f
+            with scope("mx.decode.ln"):
+                x = ln(params, x, "ln_f")
+            with scope("mx.decode.head"):
+                logits = lin(params, x, "head")           # (S, vocab)
+                if sharded:
+                    # the row-sharded vocab head leaves logits
+                    # tp-sharded; gather them INSIDE the program (this
+                    # is the one all-gather the analytic model budgets)
+                    # so sampling and the host fetch see replicated
+                    # values
+                    from jax.sharding import NamedSharding, PartitionSpec
+                    logits = jax.lax.with_sharding_constraint(
+                        logits, NamedSharding(self.spec.mesh,
+                                              PartitionSpec()))
+            with scope("mx.decode.sample"):
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return next_tok, logits, kv
 
         return step
@@ -625,7 +641,7 @@ class DecodeRequest(Request):
     deadline/priority semantics, and a one-shot future delivering the
     generated ids."""
 
-    __slots__ = ("prompt", "max_new", "generated", "tenant")
+    __slots__ = ("prompt", "max_new", "generated", "token_times", "tenant")
 
     def __init__(self, prompt, max_new, priority=0, deadline=None,
                  seq=-1):
@@ -637,6 +653,11 @@ class DecodeRequest(Request):
         self.prompt = prompt
         self.max_new = int(max_new)
         self.generated: List[int] = []
+        # one time.monotonic() per generated token, stamped by the engine
+        # as it takes the token in (``serve/retire``): with
+        # ``enqueued_at`` / ``t_dispatched`` an operator has queue wait,
+        # time to first token and inter-token gaps per request
+        self.token_times: List[float] = []
         self.tenant = None
 
     @property
@@ -685,6 +706,10 @@ class DecodeEngine(ServingRuntime):
             else _env_int("MXNET_TPU_DECODE_MAX_NEW", 128))
         self._occ_hist = telemetry.Histogram(
             "decode.occupancy", registered=False, always=True)
+        self._ttft_hist = telemetry.Histogram(
+            "decode.ttft_seconds", registered=False, always=True)
+        self._itl_hist = telemetry.Histogram(
+            "decode.itl_seconds", registered=False, always=True)
         kw.setdefault("name", "decode")
         super().__init__(prog, **kw)
         # compile BEFORE serving (one visible compile/decode_step span;
@@ -839,6 +864,7 @@ class DecodeEngine(ServingRuntime):
         self._table[idx, :] = 0
         self._table[idx, :len(pages)] = pages
         req.t_dispatched = time.monotonic()
+        self._qwait_hist.observe(req.t_dispatched - req.enqueued_at)
         with self._lock:
             self._counters["admitted_slots"] += 1
         return True
@@ -860,8 +886,18 @@ class DecodeEngine(ServingRuntime):
     def _run(self):
         while not self._stop:
             try:
-                self._sweep_slots()
-                self._admit_from_queue()
+                # the host loop's spans (serve/admit, serve/build,
+                # serve/decode_step > serve/dispatch + serve/fetch,
+                # serve/retire) are what a profiler trace attributes the
+                # device's idle time between steps to; an iteration that
+                # finds no work emits serve/admit alone
+                with telemetry.span("serve/admit", cat="serve",
+                                    queued=len(self._queue)) as sp:
+                    before = self._counters["admitted_slots"]
+                    self._sweep_slots()
+                    self._admit_from_queue()
+                    sp.annotate(admitted=self._counters["admitted_slots"]
+                                - before)
                 active = self._active()
                 if not active:
                     req = self._queue.pop_live(timeout=0.05)
@@ -880,20 +916,25 @@ class DecodeEngine(ServingRuntime):
     def _engine_step(self, active: List[int]):
         c = self._program.config
         S = c.max_seqs
-        tokens = np.zeros(S, np.int32)
-        positions = np.zeros(S, np.int32)
-        seq_lens = np.zeros(S, np.int32)
-        phys = np.zeros(S, np.int32)      # inactive -> trash page 0
-        off = np.zeros(S, np.int32)
-        for i in active:
-            slot = self._slots[i]
-            req = slot.req
-            tokens[i] = (req.prompt[slot.pos] if slot.pos < req.n_prompt
-                         else req.generated[-1])
-            positions[i] = slot.pos
-            seq_lens[i] = slot.pos + 1
-            phys[i] = slot.pages[slot.pos // c.page_size]
-            off[i] = slot.pos % c.page_size
+        with telemetry.span("serve/build", cat="serve", slots=len(active)):
+            tokens = np.zeros(S, np.int32)
+            positions = np.zeros(S, np.int32)
+            seq_lens = np.zeros(S, np.int32)
+            phys = np.zeros(S, np.int32)      # inactive -> trash page 0
+            off = np.zeros(S, np.int32)
+            feeding = 0                       # slots still taking prompt
+            for i in active:
+                slot = self._slots[i]
+                req = slot.req
+                tokens[i] = (req.prompt[slot.pos]
+                             if slot.pos < req.n_prompt
+                             else req.generated[-1])
+                positions[i] = slot.pos
+                seq_lens[i] = slot.pos + 1
+                phys[i] = slot.pages[slot.pos // c.page_size]
+                off[i] = slot.pos % c.page_size
+                feeding += slot.pos + 1 < req.n_prompt
+            attended = int(seq_lens.sum())
         with self._lock:
             self._batch_seq += 1
             seq = self._batch_seq
@@ -904,18 +945,25 @@ class DecodeEngine(ServingRuntime):
                      "%s.step" % self._name, kind="step", step=seq,
                      timeout=self._exec_timeout))
         try:
-            with armed, telemetry.memory.oom_guard(
-                    "%s.step" % self._name, step=seq), telemetry.span(
+            # the span is the outermost of the three, so that arming the
+            # watchdog and the OOM guard are host time a trace can name
+            with telemetry.span(
                     "serve/decode_step", cat="serve", timed=True,
-                    batch=seq, slots=len(active)) as sp:
+                    batch=seq, slots=len(active), n_prefill=feeding,
+                    n_decode=len(active) - feeding,
+                    attended=attended) as sp, armed, \
+                    telemetry.memory.oom_guard(
+                        "%s.step" % self._name, step=seq):
                 chaos.maybe_exec_error(seq)
                 chaos.maybe_slow_exec(seq)
                 chaos.maybe_replica_crash(seq)
                 chaos.maybe_hedge_lag(seq)
-                next_tok, _logits, kv = prog.step(
-                    self._kv, tokens, positions, seq_lens, phys, off,
-                    self._table)
-                next_np = np.asarray(next_tok)
+                with telemetry.span("serve/dispatch", cat="serve"):
+                    next_tok, _logits, kv = prog.step(
+                        self._kv, tokens, positions, seq_lens, phys, off,
+                        self._table)
+                with telemetry.span("serve/fetch", cat="serve"):
+                    next_np = np.asarray(next_tok)
         except Exception as e:
             # the pool was DONATED into a step that died: state is
             # unknown, so fail every running sequence (typed) and start
@@ -937,38 +985,48 @@ class DecodeEngine(ServingRuntime):
         self._kv = kv
         self._breaker.record_success()
         step_time = sp.duration
-        n_prefill = n_decode = 0
-        for i in active:
-            slot = self._slots[i]
-            if slot is None:
-                continue
-            req = slot.req
-            slot.pos += 1
-            if slot.pos < req.n_prompt:
-                n_prefill += 1
-                continue
-            n_decode += 1
-            tok = int(next_np[i])
-            req.generated.append(tok)
-            done = (len(req.generated) >= req.max_new
-                    or (c.eos_id is not None and tok == c.eos_id)
-                    or slot.pos >= c.max_seq_len)
-            if done:
-                self._retire(i)
-        with self._lock:
-            self._exec_ewma = (step_time if self._exec_ewma == 0.0 else
-                               0.8 * self._exec_ewma + 0.2 * step_time)
-            self._counters["steps"] += 1
-            self._counters["tokens_prefilled"] += n_prefill
-            self._counters["tokens_decoded"] += n_decode
-        self._exec_hist.observe(step_time)
-        self._occ_hist.observe(len(active) / float(S))
-        telemetry.count("decode.tokens", float(n_decode), kind="decode")
-        if n_prefill:
-            telemetry.count("decode.tokens", float(n_prefill),
-                            kind="prefill")
-        telemetry.window_tick()
-        telemetry.memory.note_step(seq)
+        with telemetry.span("serve/retire", cat="serve") as rsp:
+            n_prefill = n_decode = n_retired = 0
+            now = time.monotonic()
+            for i in active:
+                slot = self._slots[i]
+                if slot is None:
+                    continue
+                req = slot.req
+                slot.pos += 1
+                if slot.pos < req.n_prompt:
+                    n_prefill += 1
+                    continue
+                n_decode += 1
+                tok = int(next_np[i])
+                req.generated.append(tok)
+                if req.token_times:
+                    self._itl_hist.observe(now - req.token_times[-1])
+                else:
+                    self._ttft_hist.observe(now - req.enqueued_at)
+                req.token_times.append(now)
+                done = (len(req.generated) >= req.max_new
+                        or (c.eos_id is not None and tok == c.eos_id)
+                        or slot.pos >= c.max_seq_len)
+                if done:
+                    self._retire(i)
+                    n_retired += 1
+            with self._lock:
+                self._exec_ewma = (step_time if self._exec_ewma == 0.0 else
+                                   0.8 * self._exec_ewma + 0.2 * step_time)
+                self._counters["steps"] += 1
+                self._counters["tokens_prefilled"] += n_prefill
+                self._counters["tokens_decoded"] += n_decode
+                self._counters["contexts_attended"] += attended
+            self._exec_hist.observe(step_time)
+            self._occ_hist.observe(len(active) / float(S))
+            telemetry.count("decode.tokens", float(n_decode), kind="decode")
+            if n_prefill:
+                telemetry.count("decode.tokens", float(n_prefill),
+                                kind="prefill")
+            telemetry.window_tick()
+            telemetry.memory.note_step(seq)
+            rsp.annotate(retired=n_retired)
 
     # -- swap / stats --------------------------------------------------------
     def _validate_swap(self, source, canary_inputs=None):
@@ -1037,14 +1095,23 @@ class DecodeEngine(ServingRuntime):
             "tokens_prefilled": counters.get("tokens_prefilled", 0),
             "tokens_per_step": round(
                 counters.get("tokens_decoded", 0) / steps, 3),
+            # running sum of the steps' seq_lens: the contexts the
+            # attention read, for bytes-per-step and pool-residency maths
+            "contexts_attended": counters.get("contexts_attended", 0),
             "compiles": self._program.trace_count,
             "quantize": c.quantize,
         }
-        step_s = self._exec_hist.summary()
-        if step_s["count"]:
-            ps = self._exec_hist.percentiles((0.50, 0.99))
-            out["decode"]["token_step_s"] = {
-                "p50": round(ps[0.50], 6), "p99": round(ps[0.99], 6)}
+        # per-step time and the request stamps' distributions (queue wait
+        # enqueued_at -> t_dispatched, time to first token, inter-token
+        # gap), each from its engine histogram
+        for key, hist in (("token_step_s", self._exec_hist),
+                          ("queue_wait_s", self._qwait_hist),
+                          ("ttft_s", self._ttft_hist),
+                          ("itl_s", self._itl_hist)):
+            ps = hist.percentiles((0.50, 0.99))
+            if ps:
+                out["decode"][key] = {"p50": round(ps[0.50], 6),
+                                      "p99": round(ps[0.99], 6)}
         return out
 
     def close(self):

@@ -1,7 +1,15 @@
 """Structured spans: thread-aware nested timing regions.
 
-``span("train/step", step=n)`` times a region and, depending on what is
-armed, feeds two consumers from the ONE measurement:
+``span("train/step", step=n)`` is first of all a
+``jax.profiler.TraceAnnotation`` of the same name and attrs, entered for
+the span's lifetime whether or not anything here is armed: any
+``jax.profiler`` trace of the process therefore holds the program's spans
+on the host plane and the device's operations on ONE clock
+(``benchmark/lib/program_trace.py`` reads both).  While no trace is being
+taken the annotation is one flag check in C++ and encodes nothing.
+
+Beyond that it times the region and, depending on what is armed, feeds
+further consumers from the ONE measurement:
 
 * **trace** — while the profiler runs (``profiler.set_state('run')``)
   every completed span becomes a Chrome-trace ``X`` event in the
@@ -19,16 +27,21 @@ Open spans are tracked per thread in a process-global table, so a
 watchdog post-mortem can report what every thread was *inside* when it
 hung — not just its stack.
 
-Cost when nothing is armed: one module-bool check on enter and one on
-exit; no clock read, no lock (``timed=True`` forces the two clock reads
-for callers that need ``.duration`` regardless, e.g. the serving
-EWMA).
+Cost when nothing is armed: the idle annotation plus one module-bool
+check on enter and one on exit; no clock read, no lock (``timed=True``
+forces the two clock reads for callers that need ``.duration``
+regardless, e.g. the serving EWMA).  A bare ``jax.profiler`` trace arms
+nothing here: ``spans_active()`` stays False under it, so a span that
+changes what it measures when armed (``train/device_wait`` blocks on the
+device) does not start doing so because somebody is looking.
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import Dict, List, Optional
+
+import jax.profiler
 
 from . import registry as _registry
 
@@ -48,6 +61,16 @@ def _stack() -> List[dict]:
             _OPEN[threading.get_ident()] = (
                 threading.current_thread().name, st)
     return st
+
+
+def _plain(attrs: dict) -> dict:
+    """Attrs as the profiler takes them: TraceMe encodes ``str``, ``int``
+    and ``float`` values; anything else goes in as its ``repr``."""
+    for v in attrs.values():
+        if not isinstance(v, (str, int, float)):
+            return {k: v if isinstance(v, (str, int, float)) else repr(v)
+                    for k, v in attrs.items()}
+    return attrs
 
 
 def spans_active() -> bool:
@@ -72,7 +95,7 @@ class span:
     """
 
     __slots__ = ("name", "cat", "metric", "attrs", "timed", "active",
-                 "duration", "_t0", "_entry")
+                 "duration", "_t0", "_entry", "_ann")
 
     def __init__(self, name: str, cat: str = "span",
                  metric: Optional[str] = None, timed: bool = False,
@@ -86,8 +109,12 @@ class span:
         self.duration = None
         self._t0 = None
         self._entry = None
+        self._ann = None
 
     def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self.name,
+                                                 **_plain(self.attrs))
+        self._ann.__enter__()
         self.active = spans_active()
         if self.active:
             self._entry = {"name": self.name, "cat": self.cat,
@@ -98,9 +125,16 @@ class span:
             self._t0 = time.perf_counter()
         return self
 
+    def annotate(self, **attrs):
+        """Attach attrs known only once the work is done (how many were
+        admitted, retired); every consumer sees them with the rest."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**_plain(attrs))
+
     def __exit__(self, *exc):
         if self._t0 is not None:
             self.duration = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
         if not self.active:
             return False
         st = _stack()

@@ -1,0 +1,297 @@
+"""One clock (ISSUE 26): every ``telemetry.span`` is a
+``jax.profiler.TraceAnnotation``, the decode engine's host loop names its
+parts, the jitted steps carry ``mx.*`` scopes that do not move the compile
+cache's keys, and requests carry per-token stamps."""
+import contextlib
+import glob
+import os
+import re
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import telemetry
+from mxnet_tpu.compile import program_fingerprint
+from mxnet_tpu.parallel.mesh import MeshSpec, make_mesh
+from mxnet_tpu.parallel.trainer import ShardedTrainer
+from mxnet_tpu.serving.decode import (DecodeConfig, DecodeEngine,
+                                      DecodeProgram, init_decode_params)
+from mxnet_tpu.telemetry import spans as spans_mod
+
+@pytest.fixture(autouse=True)
+def _clean():
+    yield
+    telemetry.reset()
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    """Stands in for ``jax.profiler.TraceAnnotation``: a log of
+    ``(thread, what, name, attrs)`` in the order things happened."""
+    log = []
+
+    class Recorder:
+        def __init__(self, name, **attrs):
+            self.name = name
+            self.attrs = dict(attrs)
+
+        def _note(self, what, attrs=None):
+            log.append((threading.current_thread().name, what, self.name,
+                        dict(self.attrs if attrs is None else attrs)))
+
+        def __enter__(self):
+            self._note("enter")
+            return self
+
+        def set_metadata(self, **attrs):
+            self._note("meta", attrs)
+
+        def __exit__(self, *exc):
+            self._note("exit")
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return log
+
+
+# -- part 1: a span is a TraceAnnotation ----------------------------------
+
+@pytest.mark.parametrize("armed", [False, True], ids=["off", "armed"])
+def test_span_enters_and_exits_one_annotation(recorder, armed):
+    if armed:
+        telemetry.arm()
+    assert spans_mod.spans_active() is armed
+    with telemetry.span("train/step", cat="train", step=7, lr=0.5,
+                        who="me", shape=(2, 3), none=None) as outer:
+        with telemetry.span("train/host_enqueue"):
+            pass
+        outer.annotate(skipped=1, why=[1])
+    me = threading.current_thread().name
+    plain = {"step": 7, "lr": 0.5, "who": "me", "shape": "(2, 3)",
+             "none": "None"}
+    assert recorder == [
+        (me, "enter", "train/step", plain),
+        (me, "enter", "train/host_enqueue", {}),
+        (me, "exit", "train/host_enqueue", {}),
+        (me, "meta", "train/step", {"skipped": 1, "why": "[1]"}),
+        (me, "exit", "train/step", plain),
+    ]
+    # the span's own consumers see the late attrs beside the rest
+    assert outer.attrs["skipped"] == 1 and outer.attrs["step"] == 7
+    assert outer.active is armed
+
+
+def test_span_annotation_exits_on_error(recorder):
+    with pytest.raises(ValueError):
+        with telemetry.span("serve/build", slots=1):
+            raise ValueError("x")
+    assert [r[1] for r in recorder] == ["enter", "exit"]
+
+
+def test_profiler_trace_holds_spans_and_arms_nothing(tmp_path):
+    """A real ``jax.profiler`` trace holds the spans with their attrs on
+    the host plane, and does not make ``spans_active()`` true: a span that
+    changes what it measures when armed (``train/device_wait`` blocks on
+    the device) must not start to because somebody is looking."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert not spans_mod.spans_active()
+        with telemetry.span("serve/build", cat="serve", slots=3) as sp:
+            with telemetry.span("serve/dispatch", cat="serve"):
+                pass
+            sp.annotate(retired=2)
+        assert not sp.active
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1]
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve/"):
+                    found[ev.name] = (ev.start_ns, ev.end_ns,
+                                      dict(ev.stats))
+    assert found["serve/build"][2] == {"slots": 3, "retired": 2}
+    b, d = found["serve/build"], found["serve/dispatch"]
+    assert b[0] <= d[0] and d[1] <= b[1]
+
+
+# -- part 2: the engine's host loop ----------------------------------------
+
+VOCAB, T, L, H, HEADS = 29, 16, 2, 24, 2
+
+
+@pytest.fixture(scope="module")
+def toy():
+    cfg = DecodeConfig(VOCAB, L, H, HEADS, T, page_size=4, max_seqs=3)
+    prog = DecodeProgram(init_decode_params(cfg, seed=3), cfg, name="toy26")
+    prog.ensure_compiled()
+    return cfg, prog
+
+
+def _engine_log(recorder):
+    return [r for r in recorder if r[0] == "mxt-serving"
+            and r[2].startswith("serve/")]
+
+
+def test_engine_emits_its_six_spans_once_a_step(toy, recorder):
+    cfg, prog = toy
+    requests = [(np.arange(5) % VOCAB, 4), (np.arange(2) % VOCAB, 6),
+                (np.arange(7) % VOCAB, 3), (np.arange(3) % VOCAB, 2)]
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        futures = [eng.submit(p, max_new_tokens=m) for p, m in requests]
+        outs = [f.result(timeout=60.0)[0] for f in futures]
+        stats = eng.stats()
+    assert [len(o) for o in outs] == [m for _p, m in requests]
+
+    # a request of n prompt and m new tokens is fed n + m - 1 tokens, at
+    # contexts 1, 2, ..., n + m - 1
+    fed = [len(p) + m - 1 for p, m in requests]
+    attended = sum(f * (f + 1) // 2 for f in fed)
+    assert stats["decode"]["contexts_attended"] == attended
+    assert stats["counters"]["contexts_attended"] == attended
+    steps = stats["counters"]["steps"]
+    assert steps >= max(fed)
+
+    log = _engine_log(recorder)
+    # an iteration that finds no work emits serve/admit alone: drop those
+    order = [(what, name) for _t, what, name, _a in log if what != "meta"]
+    kept = []
+    for i, item in enumerate(order):
+        lone = (item == ("exit", "serve/admit")
+                and order[i + 1:i + 2] != [("enter", "serve/build")])
+        if lone:
+            assert kept.pop() == ("enter", "serve/admit")
+            continue
+        kept.append(item)
+    one_step = [("enter", "serve/admit"), ("exit", "serve/admit"),
+                ("enter", "serve/build"), ("exit", "serve/build"),
+                ("enter", "serve/decode_step"),
+                ("enter", "serve/dispatch"), ("exit", "serve/dispatch"),
+                ("enter", "serve/fetch"), ("exit", "serve/fetch"),
+                ("exit", "serve/decode_step"),
+                ("enter", "serve/retire"), ("exit", "serve/retire")]
+    assert kept == one_step * steps
+
+    entered = [(name, attrs) for _t, what, name, attrs in log
+               if what == "enter"]
+    step_attrs = [a for n, a in entered if n == "serve/decode_step"]
+    assert sum(a["attended"] for a in step_attrs) == attended
+    assert all(a["n_prefill"] + a["n_decode"] == a["slots"]
+               for a in step_attrs)
+    assert sum(a["n_prefill"] for a in step_attrs) \
+        == stats["decode"]["tokens_prefilled"]
+    assert sum(a["n_decode"] for a in step_attrs) \
+        == stats["decode"]["tokens_decoded"]
+    assert [a["batch"] for a in step_attrs] == list(range(1, steps + 1))
+    assert all(set(a) == {"slots"} for n, a in entered
+               if n == "serve/build")
+    metas = {}
+    for _t, what, name, attrs in log:
+        if what == "meta":
+            for k, v in attrs.items():
+                metas[name, k] = metas.get((name, k), 0) + v
+    assert metas["serve/admit", "admitted"] == len(requests)
+    assert metas["serve/retire", "retired"] == len(requests)
+    assert all("queued" in a for n, a in entered if n == "serve/admit")
+
+
+def test_request_stamps_and_their_percentiles(toy):
+    cfg, prog = toy
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        reqs = [eng.submit(np.arange(n) % VOCAB, max_new_tokens=m)
+                for n, m in ((4, 5), (2, 3), (6, 1))]
+        for r in reqs:
+            r.result(timeout=60.0)
+        decode = eng.stats()["decode"]
+    for r in reqs:
+        assert len(r.token_times) == len(r.generated) == r.max_new
+        assert list(r.token_times) == sorted(r.token_times)
+        assert r.enqueued_at <= r.t_dispatched <= r.token_times[0]
+        assert r.token_times[-1] <= r.done_at
+    for key in ("token_step_s", "queue_wait_s", "ttft_s", "itl_s"):
+        assert 0.0 <= decode[key]["p50"] <= decode[key]["p99"], key
+    # first tokens: one per request; gaps: the rest
+    assert eng._ttft_hist.summary()["count"] == 3
+    assert eng._itl_hist.summary()["count"] == (5 - 1) + (3 - 1)
+    assert eng._qwait_hist.summary()["count"] == 3
+
+
+# -- part 3: stable names on the device ------------------------------------
+
+def _net():
+    d = mx.sym.Variable("data")
+    f1 = mx.sym.FullyConnected(d, num_hidden=8, name="fc1")
+    b = mx.sym.BatchNorm(f1, name="bn1")
+    a = mx.sym.Activation(b, act_type="relu", name="relu1")
+    f2 = mx.sym.FullyConnected(a, num_hidden=2, name="fc2")
+    return mx.sym.SoftmaxOutput(f2, name="softmax")
+
+
+def _lower_trainer_step(zero=False, n_dev=1):
+    spec = MeshSpec(make_mesh((n_dev,), ("dp",),
+                              devices=jax.devices()[:n_dev]))
+    tr = ShardedTrainer(_net(), spec, lr=0.01, momentum=0.9, wd=0.0,
+                        zero=zero)
+    p, m, a = tr.init_state({"data": (12, 4), "softmax_label": (12,)},
+                            seed=3)
+    inputs = {"data": jax.ShapeDtypeStruct((12, 4), np.float32),
+              "softmax_label": jax.ShapeDtypeStruct((12,), np.float32)}
+    with tr._tracing_on_mesh():
+        return tr._build_step(donate=False).lower(
+            p, m, a, inputs, tr._keys(), tr._guard_arrays())
+
+
+def _lower_decode_step():
+    cfg = DecodeConfig(VOCAB, L, H, HEADS, T, page_size=4, max_seqs=3)
+    prog = DecodeProgram(init_decode_params(cfg, seed=3), cfg, name="low26")
+    return jax.jit(prog._make_step_fn(count=False)).lower(
+        prog._params, prog.fresh_cache(), *prog._zero_step_args())
+
+
+TRAINER_SCOPES = {"mx.update", "mx.guard", "mx.loss_scale",
+                  "mx.FullyConnected.fc1", "mx.BatchNorm.bn1",
+                  "mx.Activation.relu1", "mx.FullyConnected.fc2",
+                  "mx.SoftmaxOutput.softmax"}
+DECODE_SCOPES = {"mx.decode." + s for s in
+                 ("embed", "ln", "qkv", "kv_write", "attn", "proj", "mlp",
+                  "head", "sample")}
+
+
+@pytest.mark.parametrize("lower, scopes", [
+    (_lower_trainer_step, TRAINER_SCOPES),
+    (_lower_decode_step, DECODE_SCOPES),
+], ids=["trainer", "decode"])
+def test_lowered_step_holds_scopes_and_keeps_its_fingerprint(
+        monkeypatch, lower, scopes):
+    lowered = lower()
+    named = set(re.findall(r"mx\.[A-Za-z0-9_.]+",
+                           lowered.as_text(debug_info=True)))
+    assert named == scopes
+    # what compile/cache.program_fingerprint hashes carries no debug info
+    text = lowered.as_text()
+    assert "mx." not in text
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lower()
+    assert "mx." not in bare.as_text(debug_info=True)
+    assert program_fingerprint(bare.as_text()) == program_fingerprint(text)
+
+
+def test_backward_ops_inherit_the_node_scope():
+    dbg = _lower_trainer_step().as_text(debug_info=True)
+    assert re.search(r"transpose\(jvp\(mx\.BatchNorm\.bn1\)\)", dbg)
+    assert re.search(r"jvp\(mx\.FullyConnected\.fc1\)", dbg)
+
+
+def test_zero_step_names_its_scatter_and_gather():
+    dbg = _lower_trainer_step(zero=True, n_dev=2).as_text(debug_info=True)
+    assert {"mx.zero_scatter", "mx.zero_gather"} <= set(
+        re.findall(r"mx\.[A-Za-z0-9_.]+", dbg))
