@@ -51,10 +51,12 @@ __all__ = ["flash_blocks", "autotune", "tune_flash", "lookup", "record",
            "cache_path", "invalidate", "device_kind",
            "DEFAULT_FLASH_BLOCKS", "decode_backend", "tune_decode"]
 
-# static fallbacks when the cache has no entry: the hand-picked r4
-# forward blocks, and symmetric 128s for the backward (two operand tiles
-# + two accumulators per cell leave less VMEM headroom than the forward)
-DEFAULT_FLASH_BLOCKS = {"fwd": (128, 512), "bwd": (128, 128)}
+# static fallbacks when the cache has no entry, which is how a fresh
+# checkout runs: the winners of a v5e sweep at the benchmark's training
+# shape (192 heads x 1,024 x 64, bfloat16, causal; PERF.md section 6,
+# PR 27), each kernel within 10% of them from (512, 512) up.
+# ``pallas_kernels._fit_block`` fits them to other lengths.
+DEFAULT_FLASH_BLOCKS = {"fwd": (512, 1024), "bwd": (512, 1024)}
 
 _LOCK = threading.RLock()
 _CACHE: Optional[Dict[str, dict]] = None
@@ -254,24 +256,34 @@ def flash_blocks(kind: str, Tq: int, Tk: int, D: int = 0,
 
 def _flash_candidates(kind: str, Tq: int, Tk: int, D: int,
                       itemsize: int = 2):
-    """Block-size grid, pre-filtered by a VMEM budget: per cell the live
-    set is the q/k/v(/do) tiles + the (bq, bk) score tile + f32
-    accumulators; candidates past ~12 MB can only fail to compile."""
+    """Block-size grid, pre-filtered by a VMEM budget.  A cell holds its
+    blocks in the operands' own dtype (``itemsize`` bytes; the kernels no
+    longer widen them), each twice because the grid pipeline double-
+    buffers every block it copies, a (rows, D) block with D padded to
+    whole 128-lane registers; float32 accumulators; and a handful of
+    float32 (sub, block_q) score tiles, ``sub`` being the kernels' inner
+    key sub-tile.  Candidates past ~12 MB of the 16 MB a kernel gets can
+    only fail to compile."""
+    from .pallas_kernels import _FLASH_SUB_K
     budget = 12 * (1 << 20)
-    nacc = 1 if kind == "fwd" else 2
-    ntile = 3 if kind == "fwd" else 4
+    lanes = -(-D // 128) * 128
     out = []
-    for bq in (128, 256, 512):
-        for bk in (128, 256, 512, 1024):
+    for bq in (128, 256, 512, 1024):
+        for bk in (128, 256, 512, 1024, 2048):
             if bq > Tq or bk > Tk:
                 continue
-            # operand tiles ×2: the pallas grid pipeline double-buffers
-            # input blocks (fetch i+1 while computing i)
-            vmem = (2 * ntile * (bq + bk) * D * 4  # operand tiles (f32 up)
-                    + bq * bk * 4                  # score tile
-                    + nacc * max(bq, bk) * D * 4   # accumulators
-                    + 2 * bq * 128 * 4)            # m/l or lse/delta lanes
-            if vmem <= budget:
+            q_rows = 2 * bq * lanes * itemsize      # q, do: (bq, D)
+            k_rows = 2 * bk * lanes * itemsize      # k, v, dk, dv: (bk, D)
+            q_cols = 2 * D * bq * itemsize          # out, dq: (D, bq)
+            k_cols = 2 * D * bk * itemsize          # v or k transposed
+            if kind == "fwd":       # q, k, v^T -> out^T; acc
+                held = q_rows + k_rows + k_cols + q_cols + D * bq * 4
+            else:                   # the larger of flash_bwd_dq and _dkv
+                held = max(2 * q_rows + 2 * k_rows + k_cols + q_cols
+                           + D * bq * 4,
+                           2 * q_rows + 4 * k_rows + 2 * bk * lanes * 4)
+            scores = 4 * bq * min(bk, _FLASH_SUB_K) * 4
+            if held + scores <= budget:
                 out.append((bq, bk))
     return out or [DEFAULT_FLASH_BLOCKS[kind]]
 
@@ -311,7 +323,7 @@ def tune_flash(q, k, v, causal: bool = True, kinds=("fwd", "bwd"),
                                           block_q=bq, block_k=bk)
         results["fwd"] = autotune(
             "flash_fwd", _flash_sig("fwd", Tq, Tk, D, q.dtype),
-            _flash_candidates("fwd", Tq, Tk, D),
+            _flash_candidates("fwd", Tq, Tk, D, q.dtype.itemsize),
             timed(fwd), default=DEFAULT_FLASH_BLOCKS["fwd"], force=force)
     if "bwd" in kinds:
         out, lse = pk.fused_attention_fwd(q, k, v, causal=causal)
@@ -323,7 +335,7 @@ def tune_flash(q, k, v, causal: bool = True, kinds=("fwd", "bwd"),
                                           block_k=bk)
         results["bwd"] = autotune(
             "flash_bwd", _flash_sig("bwd", Tq, Tk, D, q.dtype),
-            _flash_candidates("bwd", Tq, Tk, D),
+            _flash_candidates("bwd", Tq, Tk, D, q.dtype.itemsize),
             timed(bwd), default=DEFAULT_FLASH_BLOCKS["bwd"], force=force)
     return results
 

@@ -692,13 +692,15 @@ def _identity_attach_kl_sparse_reg(attrs, x):
 # that explicit.  Per-call control stays available via the op's
 # flash_min_seq attr (which IS part of the jit cache key).
 #
-# Default moved 8192 -> 1024 in round 6: the old crossover was measured
-# against the REMATERIALIZING backward (the vjp re-ran the whole einsum
-# forward); with the fused Pallas backward (pallas_kernels.
-# fused_attention_bwd, recompute-free from the saved logsumexp) the
-# flash path stops paying the O(T²) probability/score HBM traffic in
-# BOTH directions, which is exactly the transformer bench's missing MFU
-# (PERF.md r6).  MXNET_FLASH_MIN_SEQ=8192 restores the old dispatch.
+# Why 1024 (v5e, PERF.md section 6, PR 27): at the benchmark's training
+# shape, 16 x 12 heads x 1,024 x 64 in bfloat16, causal, forward and
+# backward of one layer take 2.8 ms through the flash kernels and 5.8 ms
+# through the einsum formulation, whose compiled program plans 0.96 GB
+# where the kernels' plans 0.35 GB (tools/bench_pallas.py --mode=fwdbwd
+# --seqs 1024 --batch 16 --heads 12).  Until PR 27 the kernels took
+# 21 ms there (float32 products, 128-row tiles) and the einsum path would
+# have won; nothing shorter than 1,024 has been timed on the chip, so the
+# threshold stays where the measurement is.
 _FLASH_MIN_SEQ = int(os.environ.get("MXNET_FLASH_MIN_SEQ", "1024"))
 
 # Backward implementation above the threshold: the fused Pallas kernels
@@ -713,7 +715,7 @@ def _per_mesh_shard(fn):
     partition a Mosaic kernel ("Mosaic kernels cannot be automatically
     partitioned. Please wrap the call in a shard_map"), so under a
     multi-device trainer the flash calls run manually per device: every
-    operand — (B, T, H, D) tensors and the (B*H, T, 128) logsumexp alike —
+    operand — (B, T, H, D) tensors and the (B*H, 1, T) logsumexp alike —
     splits along its batch-major leading dim over dp and is whole over
     every other axis.  The trainer says so by tracing inside jax's mesh
     context (ShardedTrainer._tracing_on_mesh); the MeshSpec it armed names
@@ -743,12 +745,15 @@ def _contrib_fused_attention(attrs, q, k, v):
     XLA fuses it well and residuals fit in HBM at tiny T.  At and above
     the threshold both directions run the Pallas flash kernels —
     K/V-blocked online-softmax forward saving the row logsumexp, and a
-    recompute-free dQ/dK/dV backward from that residual — so HBM never
-    holds a (T, T) tensor in either direction (reach T=32k+ single
-    chip; tools/bench_pallas.py --mode=fwdbwd for the table).
-    ``block_q``: 0 = autotuned (ops/autotune.py cache, then 128);
-    explicit values win.  MXNET_TPU_FLASH_BWD=remat restores the pre-r6
-    rematerializing einsum backward."""
+    dQ/dK/dV backward that rebuilds its score tiles from that residual —
+    so HBM never holds a (T, T) tensor in either direction (reach T=32k+
+    single chip; tools/bench_pallas.py --mode=fwdbwd for the table).
+    The kernels' products run in the dtype of q/k/v, accumulated in
+    float32.  ``block_q``: 0 = autotuned (ops/autotune.py cache, then
+    ``autotune.DEFAULT_FLASH_BLOCKS``); explicit values win and are fitted
+    to T (on the chip: a multiple of 128, or all of T).
+    MXNET_TPU_FLASH_BWD=remat restores the pre-r6 rematerializing einsum
+    backward."""
     scale = attrs.scale if attrs.scale > 0 else 1.0 / float(q.shape[-1]) ** 0.5
     causal = attrs.causal
     block_q = attrs.block_q

@@ -146,45 +146,172 @@ def _two_bit_jit(grad, residual, threshold, interpret):
 
 _NEG_BIG = -1e30      # -inf would make exp(m_prev - m_new) NaN on init
 
-# lse/delta residuals carry a broadcast 128-lane trailing dim — the same
-# layout jax's own TPU flash kernel uses (MIN_BLOCK_SIZE lanes): Mosaic
-# wants the last dim on the 128-lane register file, and the ×128 HBM
-# cost is O(T·128) — noise next to the O(T²) scores the kernel exists to
-# avoid materializing.
-_LSE_LANES = 128
+# All three flash kernels hold a score tile TRANSPOSED: keys on sublanes,
+# queries on lanes, ``K·Qᵀ``.  What follows from that, on a chip whose
+# vector unit works on (8 sublanes, 128 lanes) registers and reduces
+# across lanes only through the slow cross-lane unit:
+#   * the softmax's row maximum and row sum run down the sublanes, i.e.
+#     are elementwise across registers but for one last step a 128 queries;
+#   * the row statistics (running max and sum, logsumexp, delta) are (1, bq)
+#     rows that broadcast down the sublanes as they are, and travel through
+#     HBM lane-major: (B*H, 1, T) float32, 4 bytes a query, where the
+#     lane-broadcast (B*H, T, 128) form the kernels used until PR 27 took
+#     512 and ``flash_bwd_dkv`` read it once per k block;
+#   * what accumulates per query, the forward's output and dQ, is a
+#     (D, bq) tile with every lane in use at D = 64, fed by ``Vᵀ·Pᵀ`` and
+#     ``Kᵀ·dSᵀ``; the wrappers hand V and K over transposed, (B*H, D, T),
+#     and take the result back the same way, inside the transposes that
+#     the (B, T, H, D) layout costs them anyway;
+#   * every product contracts the way the MXU takes it: no tile is
+#     transposed inside a cell.
+# The products take their operands in the dtype they arrived in (bfloat16 x
+# bfloat16 for a bfloat16 model, float32 products for float32 inputs) and
+# accumulate in float32; ``p`` and ``ds`` are rounded to that dtype only as
+# operands.  Scores, statistics, ``exp`` and accumulators are float32.
+
+# The key rows of one inner step: a grid cell holds a (block_q, D) and a
+# (block_k, D) block and walks the key block in sub-tiles of this many
+# rows.  The blocks the grid copies can then be large (a grid step costs
+# a fixed 0.15-0.35 us) while dead sub-tiles are skipped and only those
+# on the diagonal are masked at the finer grain.  Fitted to the block
+# like the blocks to T.  Chip timings: PERF.md section 6, PR 27.
+_FLASH_SUB_K = 512
+
+_Q_LANES = 128                     # what lies along the lanes: multiples
+
+_NT = (((1,), (1,)), ((), ()))     # a (m, d) x (n, d) -> (m, n) product
+_NN = (((1,), (0,)), ((), ()))     # a (m, n) x (n, d) -> (m, d) product
 
 
-def _fit_block(block, T, dtype):
-    """Largest halving of ``block`` that divides ``T`` and stays on the
-    dtype's sublane tile (8 rows of 32 bits: 8 for f32, 16 for bf16);
-    a length no such block divides is taken whole, which Mosaic accepts
-    as "equal to the array dimension"."""
-    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+def _mxu_dot(a, b, dims):
+    """Matrix product in the operands' own dtype, accumulated in float32.
+    Float32 operands get the package-wide "highest" precision (float32
+    products, mxnet_tpu/__init__.py); narrower ones go to the MXU as they
+    are, which Mosaic has to be told: it refuses bfloat16 operands under
+    a float32 contract precision."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _sublane_tile(dtype):
+    """Rows of one register tile: 8 of 32 bits (8 for f32, 16 for bf16)."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _fit_block(block, T, tile):
+    """Largest halving of ``block`` that divides ``T`` and is a multiple
+    of ``tile`` (the dtype's sublane tile for a block of rows, 128 for one
+    along the lanes); a length no such block divides is taken whole, which
+    Mosaic accepts as "equal to the array dimension"."""
     b = min(block, T)
     while T % b and b > tile:
         b //= 2
     return b if T % b == 0 and b % tile == 0 else T
 
 
-def _pick_blocks(block_q, block_k, Tq, Tk, D, dtype, kind):
-    """Resolve (block_q, block_k): explicit argument wins, then the
+def _pick_blocks(block_q, block_k, Tq, Tk, D, dtype, kind, interpret):
+    """Resolve (block_q, block_k, sub_k): explicit argument wins, then the
     autotune cache (ops/autotune.py), then the static default — and
-    either way fit them to the sequence lengths (:func:`_fit_block`)."""
+    either way fit them to the sequence lengths (:func:`_fit_block`), and
+    the inner sub-tile to the key block.  Query blocks lie along the
+    lanes, and so do the sub-tiles of Kᵀ and Vᵀ: Mosaic wants both in
+    multiples of 128 (the interpreter takes any)."""
     if block_q is None or block_k is None:
         from . import autotune as _autotune
         tq, tk = _autotune.flash_blocks(kind, Tq, Tk, D, dtype)
         block_q = block_q or tq
         block_k = block_k or tk
-    return _fit_block(block_q, Tq, dtype), _fit_block(block_k, Tk, dtype)
+    rows = _sublane_tile(dtype)
+    lanes = rows if interpret else _Q_LANES
+    bq = _fit_block(block_q, Tq, lanes)
+    bk = _fit_block(block_k, Tk, rows)
+    return bq, bk, _fit_block(_FLASH_SUB_K, bk, lanes)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
-                  block_q, block_k, nk, with_lse):
+def _live_sub_tiles(qi, ki, *, causal, block_q, block_k, sub_k):
+    """``(n_unmasked, n_live)`` of the cell's ``block_k // sub_k`` key
+    sub-tiles, counted from the block's first: how many lie at or below
+    the diagonal for every query of the block (no mask to apply), and how
+    many hold a live score at all.  The sub-tiles between the two
+    straddle the diagonal; those past ``n_live`` are never touched."""
+    n = block_k // sub_k
+    if not causal:
+        return n, n
+    first_q = qi * block_q - ki * block_k       # relative to the key block
+    n_unmasked = jnp.minimum(
+        jax.lax.div(jnp.maximum(first_q + 1, 0), sub_k), n)
+    n_live = jnp.minimum(
+        jax.lax.div(jnp.maximum(first_q + block_q - 1 + sub_k, 0), sub_k),
+        n)
+    return n_unmasked, n_live
+
+
+def _walk_sub_tiles(tile, qi, ki, **geometry):
+    """Run ``tile(t, masked)`` over the cell's live key sub-tiles: the
+    unmasked ones first, then those on the diagonal."""
+    n_unmasked, n_live = _live_sub_tiles(qi, ki, **geometry)
+
+    def run(masked):
+        def body(t, carry):
+            tile(t, masked)
+            return carry
+        return body
+
+    jax.lax.fori_loop(0, n_unmasked, run(False), None)
+    if geometry["causal"]:
+        jax.lax.fori_loop(n_unmasked, n_live, run(True), None)
+
+
+def _sub_tile_start(t, sub_k, block_k):
+    """First key of sub-tile ``t`` within its block: static where the
+    block is one sub-tile (a length that is no multiple of 128 is taken
+    whole, and Mosaic slices along lanes only at provable multiples)."""
+    return 0 if sub_k == block_k else pl.multiple_of(t * sub_k, sub_k)
+
+
+def _scores_t(k, q, scale, diagonal):
+    """The (sub_k, bq) transposed score tile ``K·Qᵀ·scale``; ``diagonal``
+    is None, or the tile's first (key, query) position for the causal
+    mask, which only a tile that straddles the diagonal needs."""
+    st = _mxu_dot(k, q, _NT) * scale
+    if diagonal is not None:
+        k_first, q_first = diagonal
+        k_idx = k_first + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+        q_idx = q_first + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+        st = jnp.where(q_idx >= k_idx, st, jnp.float32(_NEG_BIG))
+    return st
+
+
+def _skip_dead_copy(live, index, held):
+    """Block index for a grid cell's copy: its own where the cell is
+    live, and where it is dead the one that is (or is about to be) in
+    VMEM anyway, so that the pipeline issues no copy for a block nobody
+    reads."""
+    return jnp.where(live, index, held)
+
+
+def _live_k_block(causal, block_q, block_k):
+    """Key block a cell of the grids whose inner axis runs over key blocks
+    (``flash_fwd``, ``flash_bwd_dq``) copies.  A block wholly above the
+    diagonal names block 0: the copy starts under the row's last live cell
+    and is what the next row of cells reads first."""
+    def block(i, j):
+        if causal:
+            j = _skip_dead_copy(j * block_k <= i * block_q + block_q - 1,
+                                j, 0)
+        return j
+    return block
+
+
+def _flash_kernel(q_ref, k_ref, vt_ref, ot_ref, *rest, scale, causal,
+                  block_q, block_k, sub_k, nk, with_lse):
     """Flash attention cell: one (block_q, D) query block against one
-    (block_k, D) K/V block, with the running (max, sum, acc) online-
-    softmax state in VMEM scratch.  The k-axis is the innermost grid
-    dimension, which TPU executes sequentially — the scratch carries
-    across k steps and the output is finalized on the last one."""
+    (block_k, D) K block and (D, block_k) Vᵀ block, walked in sub_k keys,
+    with the running (max, sum, acc) online-softmax state in VMEM
+    scratch.  The k-axis is the innermost grid dimension, which TPU
+    executes sequentially — the scratch carries across k steps and the
+    (D, block_q) output is finalized on the last one."""
     if with_lse:
         lse_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -199,80 +326,114 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
         m_ref[:] = jnp.full_like(m_ref, jnp.float32(_NEG_BIG))
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: skip k blocks entirely above this q block's last row
-    live = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[:].astype(jnp.float32)           # (bq, D)
-        k = k_ref[:].astype(jnp.float32)           # (bk, D)
-        v = v_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # (bq, bk)
-        if causal:
-            q_idx = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_idx = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(q_idx >= k_idx, s, jnp.float32(_NEG_BIG))
-        m_prev = m_ref[:, 0:1]                     # (bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    def _tile(t, masked):
+        start = _sub_tile_start(t, sub_k, block_k)
+        keys = pl.ds(start, sub_k)
+        st = _scores_t(k_ref[keys, :], q_ref[:], scale,
+                       (ki * block_k + start, qi * block_q)
+                       if masked else None)             # (sub_k, bq)
+        m_prev = m_ref[:]                               # (1, bq)
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, 0:1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[:] = acc_ref[:] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        pt = jnp.exp(st - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(pt, axis=0, keepdims=True)
+        m_ref[:] = m_new
+        vt = vt_ref[:, keys]                            # (D, sub_k)
+        acc_ref[:] = acc_ref[:] * corr + _mxu_dot(vt, pt.astype(vt.dtype),
+                                                  _NN)
+
+    # causal: sub-tiles wholly above this q block's last row are skipped
+    _walk_sub_tiles(_tile, qi, ki, causal=causal, block_q=block_q,
+                    block_k=block_k, sub_k=sub_k)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[:] = (acc_ref[:] / l_ref[:, 0:1]).astype(o_ref.dtype)
+        ot_ref[:] = (acc_ref[:] / l_ref[:]).astype(ot_ref.dtype)
         if lse_ref is not None:
             # logsumexp of the SCALED logits: the backward's whole
-            # softmax state in one (bq,) row vector (lane-broadcast)
-            lse_ref[:] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], jnp.float32(1e-37)))
+            # softmax state, one float32 a query
+            lse_ref[:] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:],
+                                                        jnp.float32(1e-37)))
 
 
-def _flash_call(qf, kf, vf, dtype, *, scale, causal, bq, bk, with_lse,
-                interpret):
+# batch·head and the outer block axis are independent; the inner axis
+# carries the scratch
+_GRID_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _flash_call(operands, *, with_lse, interpret, **geometry):
+    """``flash_fwd`` over (B*H, T, D) q, k and (B*H, D, T) vᵀ: the output
+    transposed, (B*H, D, Tq), and the (B*H, 1, Tq) logsumexp or None."""
+    qf, kf, _ = operands
     BH, Tq, D = qf.shape
-    Tk = kf.shape[1]
-    nk = Tk // bk
-    kern = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                             block_q=bq, block_k=bk, nk=nk,
-                             with_lse=with_lse)
-    out_shape = [_out_struct((BH, Tq, D), dtype, qf, kf, vf)]
-    out_specs = [pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))]
+    bq, bk = geometry["block_q"], geometry["block_k"]
+    nk = kf.shape[1] // bk
+    live_k = _live_k_block(geometry["causal"], bq, bk)
+    out_shape = [_out_struct((BH, D, Tq), qf.dtype, *operands)]
+    out_specs = [pl.BlockSpec((None, D, bq), lambda b, i, j: (b, 0, i))]
     if with_lse:
-        out_shape.append(
-            _out_struct((BH, Tq, _LSE_LANES), jnp.float32, qf, kf, vf))
+        out_shape.append(_out_struct((BH, 1, Tq), jnp.float32, *operands))
         out_specs.append(
-            pl.BlockSpec((None, bq, _LSE_LANES), lambda b, i, j: (b, i, 0)))
+            pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i)))
     # this package runs with jax_enable_x64 on (mxnet int64 parity); grid
     # index maps would then trace their literals as i64, which Mosaic
     # cannot legalize — trace the kernel in an x64-off scope
     with jax.enable_x64(False):
         res = pl.pallas_call(
-            kern,
+            functools.partial(_flash_kernel, nk=nk, with_lse=with_lse,
+                              **geometry),
             grid=(BH, Tq // bq, nk),
             in_specs=[
                 pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((None, bk, D),
+                             lambda b, i, j: (b, live_k(i, j), 0)),
+                pl.BlockSpec((None, D, bk),
+                             lambda b, i, j: (b, 0, live_k(i, j))),
             ],
             out_specs=tuple(out_specs) if with_lse else out_specs[0],
             out_shape=tuple(out_shape) if with_lse else out_shape[0],
             scratch_shapes=[
-                pltpu.VMEM((bq, D), jnp.float32),     # acc
-                pltpu.VMEM((bq, 128), jnp.float32),   # running max (lanes
-                pltpu.VMEM((bq, 128), jnp.float32),   # + sum, broadcast)
+                pltpu.VMEM((D, bq), jnp.float32),     # acc
+                pltpu.VMEM((1, bq), jnp.float32),     # running max
+                pltpu.VMEM((1, bq), jnp.float32),     # running sum
             ],
+            compiler_params=_GRID_PARAMS,
             interpret=interpret, name="flash_fwd",
-        )(qf, kf, vf)
+        )(*operands)
     return res if with_lse else (res, None)
+
+
+def _heads_major(x):
+    """(B, T, H, D) -> (B*H, T, D)."""
+    B, T, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+
+
+def _heads_major_t(x):
+    """(B, T, H, D) -> (B*H, D, T): positions along the lanes."""
+    B, T, H, D = x.shape
+    return x.transpose(0, 2, 3, 1).reshape(B * H, D, T)
+
+
+def _from_heads_major_t(xt, B):
+    """(B*H, D, T) -> (B, T, H, D)."""
+    BH, D, T = xt.shape
+    return xt.reshape(B, BH // B, D, T).transpose(0, 3, 1, 2)
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
+    D = q.shape[-1]
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(D))
+    interpret = _interpret(q, k, v)
+    bq, bk, sub_k = _pick_blocks(block_q, block_k, q.shape[1], k.shape[1],
+                                 D, q.dtype, "fwd", interpret)
+    out_t, lse = _flash_call(
+        (_heads_major(q), _heads_major(k), _heads_major_t(v)), scale=scale,
+        causal=causal, block_q=bq, block_k=bk, sub_k=sub_k,
+        with_lse=with_lse, interpret=interpret)
+    return _from_heads_major_t(out_t, q.shape[0]), lse
 
 
 def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -282,58 +443,49 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     q/k/v: (B, T, H, D) (the parallel/ring.py layout).  Returns
     (B, T, H, D).  Per grid cell only (block_q + 2*block_k, D) tiles and
-    a (block_q, block_k) score tile live in VMEM — HBM traffic is
+    a (sub_k, block_q) score tile live in VMEM — HBM traffic is
     O(T*D) and the sequence length is bounded by HBM, not VMEM (the
     round-3 kernel held ALL of K/V in VMEM and topped out near T=8k;
-    this one runs T=32k+ single-chip, tools/bench_pallas.py).
+    this one runs T=32k+ single-chip, tools/bench_pallas.py).  The matrix
+    products run in the dtype of q/k/v with float32 accumulation.
 
     ``block_q``/``block_k`` default to the autotune cache
-    (ops/autotune.py; MXNET_TPU_AUTOTUNE knobs) falling back to 128/512.
+    (ops/autotune.py; MXNET_TPU_AUTOTUNE knobs), then to
+    ``autotune.DEFAULT_FLASH_BLOCKS["fwd"]`` (from v5e timings), fitted
+    to T; on the chip a query block is a multiple of 128 or all of T.
     """
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    if scale is None:
-        scale = 1.0 / float(np.sqrt(D))
-    bq, bk = _pick_blocks(block_q, block_k, Tq, Tk, D, q.dtype, "fwd")
-    # (B*H, T, D) lanes-last layout for the MXU
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    out, _ = _flash_call(qf, kf, vf, q.dtype, scale=scale, causal=causal,
-                         bq=bq, bk=bk, with_lse=False,
-                         interpret=_interpret(q, k, v))
-    return out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, False)[0]
 
 
 def fused_attention_fwd(q, k, v, causal=False, scale=None,
                         block_q=None, block_k=None):
     """Forward for the custom vjp: returns ``(out, lse)`` where ``lse``
     is the per-row logsumexp of the scaled logits, shape
-    ``(B*H, Tq, 128)`` f32 (lane-broadcast — see ``_LSE_LANES``).  With
-    this residual the backward never rematerializes the softmax
-    normalizer: one extra O(T) output instead of re-running the O(T²)
-    forward."""
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    if scale is None:
-        scale = 1.0 / float(np.sqrt(D))
-    bq, bk = _pick_blocks(block_q, block_k, Tq, Tk, D, q.dtype, "fwd")
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    out, lse = _flash_call(qf, kf, vf, q.dtype, scale=scale, causal=causal,
-                           bq=bq, bk=bk, with_lse=True,
-                           interpret=_interpret(q, k, v))
-    return out.reshape(B, H, Tq, D).transpose(0, 2, 1, 3), lse
+    ``(B*H, 1, Tq)`` f32 (lane-major, 4 bytes a query).  With this
+    residual the backward never rematerializes the softmax normalizer:
+    one extra O(T) output instead of re-running the online softmax."""
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, True)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                         dq_ref, acc_ref, *, scale, causal, block_q,
-                         block_k, nk):
-    """dQ cell: one (bq, D) query block against the sequential k-axis.
-    Recompute-free online-softmax backward: p rebuilds from the saved
-    row logsumexp (one exp per score — never the O(T²) softmax), and
-    ``delta = rowsum(dO·O)`` folds the dV-normalizer term."""
+def _p_and_ds_t(start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref, *,
+                scale, diagonal, sub_k):
+    """What both backward kernels rebuild of the sub-tile whose first key
+    is ``start``, transposed, (sub_k, bq): ``p`` from the saved row logsumexp (the scores are
+    recomputed, one exp each; the online softmax is not), and
+    ``ds = p·(dp - delta)·scale`` with ``delta = rowsum(dO·O)`` folding
+    the normalizer's term."""
+    keys = pl.ds(start, sub_k)
+    st = _scores_t(k_ref[keys, :], q_ref[:], scale, diagonal)
+    pt = jnp.exp(st - lse_ref[:])               # masked scores -> 0
+    dpt = _mxu_dot(v_ref[keys, :], do_ref[:], _NT)
+    return keys, pt, pt * (dpt - dl_ref[:]) * scale
+
+
+def _flash_bwd_dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref,
+                         dl_ref, dqt_ref, acc_ref, *, scale, causal,
+                         block_q, block_k, sub_k, nk):
+    """dQ cell: one (bq, D) query block against the sequential k-axis,
+    ``dQᵀ += Kᵀ·dSᵀ`` into a (D, bq) accumulator."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -341,41 +493,29 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    live = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    def _tile(t, masked):
+        start = _sub_tile_start(t, sub_k, block_k)
+        keys, _, dst = _p_and_ds_t(
+            start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref,
+            scale=scale, sub_k=sub_k,
+            diagonal=(ki * block_k + start, qi * block_q) if masked
+            else None)
+        kt = kt_ref[:, keys]                        # (D, sub_k)
+        acc_ref[:] = acc_ref[:] + _mxu_dot(kt, dst.astype(kt.dtype), _NN)
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[:].astype(jnp.float32)            # (bq, D)
-        k = k_ref[:].astype(jnp.float32)            # (bk, D)
-        v = v_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)          # (bq, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # (bq, bk)
-        if causal:
-            q_idx = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_idx = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(q_idx >= k_idx, s, jnp.float32(_NEG_BIG))
-        p = jnp.exp(s - lse_ref[:, 0:1])            # masked rows -> 0
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - dl_ref[:, 0:1]) * scale
-        acc_ref[:] = acc_ref[:] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+    _walk_sub_tiles(_tile, qi, ki, causal=causal, block_q=block_q,
+                    block_k=block_k, sub_k=sub_k)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
+        dqt_ref[:] = acc_ref[:].astype(dqt_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                          causal, block_q, block_k, nq):
+                          causal, block_q, block_k, sub_k, nq):
     """dK/dV cell: one (bk, D) key/value block against the sequential
-    q-axis, accumulating both grads in VMEM scratch."""
+    q-axis, ``dV += Pᵀ·dO`` and ``dK += dSᵀ·Q`` into VMEM scratch."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -384,37 +524,23 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # causal: q blocks entirely ABOVE this k block see none of it
-    live = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    def _tile(t, masked):
+        start = _sub_tile_start(t, sub_k, block_k)
+        keys, pt, dst = _p_and_ds_t(
+            start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref,
+            scale=scale, sub_k=sub_k,
+            diagonal=(ki * block_k + start, qi * block_q) if masked
+            else None)
+        do = do_ref[:]
+        q = q_ref[:]
+        dv_acc[keys, :] = dv_acc[keys, :] + _mxu_dot(pt.astype(do.dtype),
+                                                     do, _NN)
+        dk_acc[keys, :] = dk_acc[keys, :] + _mxu_dot(dst.astype(q.dtype),
+                                                     q, _NN)
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[:].astype(jnp.float32)            # (bq, D)
-        k = k_ref[:].astype(jnp.float32)            # (bk, D)
-        v = v_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)          # (bq, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # (bq, bk)
-        if causal:
-            q_idx = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_idx = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(q_idx >= k_idx, s, jnp.float32(_NEG_BIG))
-        p = jnp.exp(s - lse_ref[:, 0:1])            # (bq, bk)
-        # dV += P^T dO
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - dl_ref[:, 0:1]) * scale
-        # dK += dS^T Q
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # causal: q blocks entirely ABOVE this k block see none of it
+    _walk_sub_tiles(_tile, qi, ki, causal=causal, block_q=block_q,
+                    block_k=block_k, sub_k=sub_k)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -422,88 +548,108 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _flash_dq_call(operands, *, interpret, **geometry):
+    """dQᵀ, (B*H, D, Tq): grid (B*H, q blocks, k blocks), k innermost.
+    ``operands``: q, k, kᵀ, v, do, lse, delta."""
+    qf, kf = operands[:2]
+    BH, Tq, D = qf.shape
+    bq, bk = geometry["block_q"], geometry["block_k"]
+    nk = kf.shape[1] // bk
+    live_k = _live_k_block(geometry["causal"], bq, bk)
+    q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))
+    k_spec = pl.BlockSpec((None, bk, D),
+                          lambda b, i, j: (b, live_k(i, j), 0))
+    kt_spec = pl.BlockSpec((None, D, bk),
+                           lambda b, i, j: (b, 0, live_k(i, j)))
+    row_spec = pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i))
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, nk=nk, **geometry),
+            grid=(BH, Tq // bq, nk),
+            in_specs=[q_spec, k_spec, kt_spec, k_spec, q_spec, row_spec,
+                      row_spec],
+            out_specs=pl.BlockSpec((None, D, bq), lambda b, i, j: (b, 0, i)),
+            out_shape=_out_struct((BH, D, Tq), qf.dtype, *operands),
+            scratch_shapes=[pltpu.VMEM((D, bq), jnp.float32)],
+            compiler_params=_GRID_PARAMS,
+            interpret=interpret, name="flash_bwd_dq",
+        )(*operands)
+
+
+def _flash_dkv_call(operands, *, interpret, **geometry):
+    """dK, dV, (B*H, Tk, D): grid (B*H, k blocks, q blocks), q innermost.
+    ``operands``: q, k, v, do, lse, delta."""
+    qf, kf, vf = operands[:3]
+    BH, Tq, D = qf.shape
+    Tk = kf.shape[1]
+    bq, bk = geometry["block_q"], geometry["block_k"]
+    nq = Tq // bq
+
+    def live_q(j, i):
+        # the q blocks above k block j see none of it and come first in
+        # its row of cells; they name the first live one
+        if geometry["causal"]:
+            i = _skip_dead_copy(i * bq + bq - 1 >= j * bk, i,
+                                jnp.minimum(jax.lax.div(j * bk, bq), nq - 1))
+        return i
+
+    q_spec = pl.BlockSpec((None, bq, D),
+                          lambda b, j, i: (b, live_q(j, i), 0))
+    row_spec = pl.BlockSpec((None, 1, bq),
+                            lambda b, j, i: (b, 0, live_q(j, i)))
+    kv_spec = pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0))
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, nq=nq, **geometry),
+            grid=(BH, Tk // bk, nq),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=(kv_spec, kv_spec),
+            out_shape=(_out_struct((BH, Tk, D), kf.dtype, *operands),
+                       _out_struct((BH, Tk, D), vf.dtype, *operands)),
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)],
+            compiler_params=_GRID_PARAMS,
+            interpret=interpret, name="flash_bwd_dkv",
+        )(*operands)
+
+
 def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
                         block_q=None, block_k=None):
     """Flash attention backward: K/V-blocked dQ/dK/dV from the saved
-    logsumexp residual — no forward recomputation, no (T, T) tensor in
-    HBM (the einsum-vjp fallback materializes the full probability
-    matrix AND its gradient: ~2·B·H·T² values of HBM traffic per layer
-    that this kernel never touches).
+    logsumexp residual — the online softmax is not re-run (each kernel
+    recomputes its score tiles, one exp a score), and no (T, T) tensor
+    reaches HBM (the einsum-vjp fallback materializes the full
+    probability matrix AND its gradient: ~2·B·H·T² values of HBM traffic
+    per layer that these kernels never touch).
 
-    q/k/v/out/do: (B, T, H, D); ``lse``: (B*H, Tq, 128) f32 from
+    q/k/v/out/do: (B, T, H, D); ``lse``: (B*H, 1, Tq) f32 from
     :func:`fused_attention_fwd`.  Returns (dq, dk, dv) in the input
     dtypes.  Two pallas calls: dQ accumulates over the sequential
     k-axis, dK/dV over the sequential q-axis.  Block sizes default to
-    the autotune cache ("bwd" entry) falling back to 128/128."""
+    the autotune cache ("bwd" entry), then to
+    ``autotune.DEFAULT_FLASH_BLOCKS["bwd"]``, fitted to T."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if scale is None:
         scale = 1.0 / float(np.sqrt(D))
-    bq, bk = _pick_blocks(block_q, block_k, Tq, Tk, D, q.dtype, "bwd")
-    nq, nk = Tq // bq, Tk // bk
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    dof = do.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    outf = out.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    # delta = rowsum(dO · O): one cheap fused O(T·D) pass in XLA, then
-    # lane-broadcast like lse so both ride the same (bq, 128) blocks
-    delta = jnp.sum(dof.astype(jnp.float32) * outf.astype(jnp.float32),
-                    axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (B * H, Tq, _LSE_LANES))
     interpret = _interpret(q, k, v)
-    operands = (qf, kf, vf, dof, lse, delta)
-    with jax.enable_x64(False):
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, scale=scale,
-                              causal=causal, block_q=bq, block_k=bk,
-                              nk=nk),
-            grid=(B * H, nq, nk),
-            in_specs=[
-                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bq, _LSE_LANES),
-                             lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bq, _LSE_LANES),
-                             lambda b, i, j: (b, i, 0)),
-            ],
-            out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            out_shape=_out_struct((B * H, Tq, D), q.dtype, *operands),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            interpret=interpret, name="flash_bwd_dq",
-        )(*operands)
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                              causal=causal, block_q=bq, block_k=bk,
-                              nq=nq),
-            grid=(B * H, nk, nq),
-            in_specs=[
-                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bq, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, bq, _LSE_LANES),
-                             lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((None, bq, _LSE_LANES),
-                             lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=(
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j: (b, i, 0)),
-            ),
-            out_shape=(_out_struct((B * H, Tk, D), k.dtype, *operands),
-                       _out_struct((B * H, Tk, D), v.dtype, *operands)),
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
-            interpret=interpret, name="flash_bwd_dkv",
-        )(*operands)
+    bq, bk, sub_k = _pick_blocks(block_q, block_k, Tq, Tk, D, q.dtype,
+                                 "bwd", interpret)
+    qf, kf, vf, dof = (_heads_major(x) for x in (q, k, v, do))
+    # delta = rowsum(dO · O): one cheap fused O(T·D) pass in XLA, lane-
+    # major like lse so both ride the same (1, bq) blocks
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Tq)
+    geometry = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
+                    sub_k=sub_k, interpret=interpret)
+    dq_t = _flash_dq_call((qf, kf, _heads_major_t(k), vf, dof, lse, delta),
+                          **geometry)
+    dk, dv = _flash_dkv_call((qf, kf, vf, dof, lse, delta), **geometry)
 
     def unflat(x, T):
         return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
-    return unflat(dq, Tq), unflat(dk, Tk), unflat(dv, Tk)
+    return _from_heads_major_t(dq_t, B), unflat(dk, Tk), unflat(dv, Tk)
 
 
 # ---------------------------------------------------------------------------

@@ -126,25 +126,36 @@ def test_flash_candidates_respect_vmem_budget():
     assert cands, "candidate set must never be empty"
     for bq, bk in cands:
         assert bq <= 32768 and bk <= 32768
-    # a (512, 1024) backward tile at D=256 blows the 12MB budget
+    # blocks stay in the operands' dtype: in bfloat16 at D=64 the static
+    # defaults and everything up to 1024 x 1024 fit
+    for kind in ("fwd", "bwd"):
+        small = autotune._flash_candidates(kind, 32768, 32768, 64)
+        assert autotune.DEFAULT_FLASH_BLOCKS[kind] in small
+        assert (1024, 1024) in small
+    # a (1024, 2048) backward cell at D=256 blows the 12MB budget, and
+    # float32 operands (4 bytes) blow it sooner than bfloat16
     big = autotune._flash_candidates("bwd", 32768, 32768, 256)
-    assert (512, 1024) not in big
+    assert (512, 1024) in big and (1024, 2048) not in big
+    f32 = autotune._flash_candidates("bwd", 32768, 32768, 256, itemsize=4)
+    assert set(f32) < set(big)
+    # never a block longer than the sequence
+    assert autotune._flash_candidates("fwd", 256, 512, 64) \
+        == [(128, 128), (128, 256), (128, 512), (256, 128), (256, 256),
+            (256, 512)]
 
 
 def test_fused_attention_uses_cached_blocks(monkeypatch):
     """The kernel wrapper consults the cache at trace time: plant an
     entry and observe it win over the static default (visible through
     the clamping behavior at small T: a cached (8, 8) beats the
-    (128, 512) default)."""
+    static default)."""
     from mxnet_tpu.ops import pallas_kernels as pk
     seen = {}
     real = pk._flash_call
 
-    def spy(qf, kf, vf, dtype, *, scale, causal, bq, bk, with_lse,
-            interpret):
-        seen["blocks"] = (bq, bk)
-        return real(qf, kf, vf, dtype, scale=scale, causal=causal,
-                    bq=bq, bk=bk, with_lse=with_lse, interpret=interpret)
+    def spy(operands, *, block_q, block_k, **kw):
+        seen["blocks"] = (block_q, block_k)
+        return real(operands, block_q=block_q, block_k=block_k, **kw)
 
     monkeypatch.setattr(pk, "_flash_call", spy)
     rs = np.random.RandomState(0)

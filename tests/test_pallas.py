@@ -129,38 +129,177 @@ def test_fused_attention_op_flash_min_seq_attr():
 # flash backward (round 6): recompute-free dQ/dK/dV from the saved lse
 # ---------------------------------------------------------------------------
 
+# (dtype, (B, T, H, D), (block_q, block_k), inner sub-tile, rtol, atol).
+# The bfloat16 cases are the benchmark cell's arithmetic at a length that
+# has live, diagonal and dead blocks for every kernel; the sub-tile cases
+# walk several key sub-tiles inside each block, masked and not.
+_VJP_CASES = {
+    "sym": (np.float32, (2, 32, 2, 16), (16, 16), None, 1e-4, 1e-5),
+    "multi-k": (np.float32, (2, 32, 2, 16), (16, 8), None, 1e-4, 1e-5),
+    "multi-q": (np.float32, (2, 32, 2, 16), (8, 16), None, 1e-4, 1e-5),
+    "sub-tiles": (np.float32, (1, 128, 2, 16), (32, 64), 16, 1e-4, 1e-5),
+    "sub-tiles-wide-q": (np.float32, (1, 128, 2, 16), (64, 32), 8, 1e-4,
+                         1e-5),
+    "bf16-64x128": ("bfloat16", (2, 256, 2, 64), (64, 128), 32, 0.05,
+                    0.02),
+    "bf16-128x64": ("bfloat16", (2, 256, 2, 64), (128, 64), 32, 0.05,
+                    0.02),
+}
+
+
+@pytest.fixture
+def flash_sub(monkeypatch):
+    """Shrink the kernels' inner key sub-tile (512 rows on the chip) so
+    that a test-sized block holds several."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def set_sub(rows):
+        if rows is not None:
+            monkeypatch.setattr(pk, "_FLASH_SUB_K", rows)
+    return set_sub
+
+
+def _f32(x):
+    return jnp.asarray(np.asarray(x, np.float32))
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("blocks", [(16, 16), (16, 8), (8, 16)],
-                         ids=["sym", "multi-k", "multi-q"])
-def test_flash_backward_matches_einsum_vjp(causal, blocks):
-    """The flash dQ/dK/dV kernels against jax.vjp of the einsum
-    formulation, across block shapes that force the online accumulators
-    (multi-k: several score tiles per dQ row; multi-q: several per
-    dK/dV column) and the causal block-skipping."""
-    bq, bk = blocks
+@pytest.mark.parametrize("blocks", list(_VJP_CASES))
+def test_flash_backward_matches_einsum_vjp(causal, blocks, flash_sub):
+    """The flash forward and dQ/dK/dV kernels against jax.vjp of the
+    float32 einsum formulation, across block shapes that force the online
+    accumulators (multi-k: several score tiles per dQ row; multi-q:
+    several per dK/dV column), the causal block-skipping, the inner
+    sub-tile walk and bfloat16 operands."""
+    dtype, (B, T, H, D), (bq, bk), sub, rtol, atol = _VJP_CASES[blocks]
+    flash_sub(sub)
     rs = np.random.RandomState(7)
-    B, T, H, D = 2, 32, 2, 16
-    q = jnp.asarray(rs.normal(0, 1, (B, T, H, D)).astype(np.float32))
-    k = jnp.asarray(rs.normal(0, 1, (B, T, H, D)).astype(np.float32))
-    v = jnp.asarray(rs.normal(0, 1, (B, T, H, D)).astype(np.float32))
-    g = jnp.asarray(rs.normal(0, 1, (B, T, H, D)).astype(np.float32))
+    q, k, v, g = (jnp.asarray(rs.normal(0, 1, (B, T, H, D))
+                              .astype(np.float32)).astype(dtype)
+                  for _ in range(4))
     scale = float(1.0 / np.sqrt(D))
 
     out, lse = fused_attention_fwd(q, k, v, causal=causal,
                                    block_q=bq, block_k=bk)
-    np.testing.assert_allclose(
-        np.asarray(out),
-        np.asarray(_naive_attention(q, k, v, causal=causal)),
-        rtol=1e-4, atol=1e-5)
     dq, dk, dv = fused_attention_bwd(q, k, v, out, lse, g, causal=causal,
                                      block_q=bq, block_k=bk)
-    _, vjp = jax.vjp(
+    want, vjp = jax.vjp(
         lambda a, b, c: _naive_attention(a, b, c, causal=causal,
-                                         scale=scale), q, k, v)
-    wq, wk, wv = vjp(g)
-    for got, want in ((dq, wq), (dk, wk), (dv, wv)):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-4, atol=1e-5)
+                                         scale=scale),
+        _f32(q), _f32(k), _f32(v))
+    for got, ref in zip((out, dq, dk, dv), (want,) + vjp(_f32(g))):
+        assert got.dtype == q.dtype
+        np.testing.assert_allclose(_f32(got), np.asarray(ref),
+                                   rtol=rtol, atol=atol)
+
+
+def _kernel_dots(fn, *args):
+    """Every ``dot_general`` inside the Pallas kernel bodies ``fn``
+    traces, loops and branches included."""
+    found = []
+
+    def walk(jaxpr, in_kernel):
+        for eqn in jaxpr.eqns:
+            if in_kernel and eqn.primitive.name == "dot_general":
+                found.append(eqn)
+            inside = in_kernel or eqn.primitive.name == "pallas_call"
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple))
+                            else [val]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, inside)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_products_run_in_the_input_dtype(dtype):
+    """bfloat16 inputs: no product of the three kernels has a float32
+    operand (that is several MXU passes a product on the v5e), and each
+    accumulates in float32.  float32 inputs keep float32 products."""
+    rs = np.random.RandomState(3)
+    q, k, v, g = (jnp.asarray(rs.normal(0, 1, (1, 32, 2, 16))
+                              .astype(np.float32)).astype(dtype)
+                  for _ in range(4))
+
+    def fwd_bwd(q, k, v, g):
+        out, lse = fused_attention_fwd(q, k, v, causal=True, block_q=16,
+                                       block_k=16)
+        return fused_attention_bwd(q, k, v, out, lse, g, causal=True,
+                                   block_q=16, block_k=16)
+
+    dots = _kernel_dots(fwd_bwd, q, k, v, g) \
+        + _kernel_dots(lambda q, k, v: fused_attention(
+            q, k, v, causal=True, block_q=16, block_k=16), q, k, v)
+    # 2 forward (twice: with and without lse), 3 dQ, 4 dK/dV; each of the
+    # causal kernels traces its cell twice, masked and not
+    assert len(dots) == 2 * (2 + 3 + 4 + 2)
+    for eqn in dots:
+        assert [str(x.aval.dtype) for x in eqn.invars] == [dtype, dtype]
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+def test_flash_dead_block_copies_change_nothing(monkeypatch, flash_sub):
+    """The index maps point a dead causal cell at a block that is in VMEM
+    anyway; with every cell naming its own block instead (the copies
+    made), outputs and gradients are the same bit for bit."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    flash_sub(8)
+    rs = np.random.RandomState(11)
+    q, k, v, g = (jnp.asarray(rs.normal(0, 1, (1, 64, 2, 16))
+                              .astype(np.float32)) for _ in range(4))
+
+    def run():
+        out, lse = fused_attention_fwd(q, k, v, causal=True, block_q=16,
+                                       block_k=16)
+        return (out, lse) + fused_attention_bwd(
+            q, k, v, out, lse, g, causal=True, block_q=16, block_k=16)
+
+    skipped = run()
+    renamed = []
+
+    def own_block(live, index, held):
+        renamed.append(1)
+        return index
+
+    monkeypatch.setattr(pk, "_skip_dead_copy", own_block)
+    copied = run()
+    assert renamed, "the index maps no longer go through _skip_dead_copy"
+    for a, b in zip(skipped, copied):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("block_q,block_k,sub_k",
+                         [(16, 16, 8), (8, 32, 8), (32, 8, 8),
+                          (16, 64, 16), (64, 64, 32)])
+def test_live_sub_tiles_counts_match_the_mask(block_q, block_k, sub_k):
+    """``_live_sub_tiles`` against the causal mask itself: a sub-tile is
+    unmasked where every score is live, live where any is, and both sets
+    are prefixes of the block's sub-tiles."""
+    from mxnet_tpu.ops.pallas_kernels import _live_sub_tiles
+    T = 64
+    live = np.tril(np.ones((T, T), bool))           # [query, key]
+    for qi in range(T // block_q):
+        for ki in range(T // block_k):
+            with jax.enable_x64(False):     # as the kernels trace it
+                got = _live_sub_tiles(jnp.int32(qi), jnp.int32(ki),
+                                      causal=True, block_q=block_q,
+                                      block_k=block_k, sub_k=sub_k)
+            rows = live[qi * block_q:(qi + 1) * block_q]
+            tiles = [rows[:, ki * block_k + t * sub_k:
+                          ki * block_k + (t + 1) * sub_k]
+                     for t in range(block_k // sub_k)]
+            n_unmasked = sum(t.all() for t in tiles)
+            n_live = sum(t.any() for t in tiles)
+            assert (int(got[0]), int(got[1])) == (n_unmasked, n_live)
+            assert all(t.all() for t in tiles[:n_unmasked])
+            assert all(t.any() for t in tiles[:n_live])
+    assert _live_sub_tiles(0, 0, causal=False, block_q=block_q,
+                           block_k=block_k, sub_k=sub_k) \
+        == (block_k // sub_k,) * 2
 
 
 def test_flash_fwd_lse_is_row_logsumexp():
@@ -176,10 +315,10 @@ def test_flash_fwd_lse_is_row_logsumexp():
     s = np.where(np.tril(np.ones((T, T), bool)), s, -np.inf)
     want = np.log(np.exp(s - s.max(-1, keepdims=True)).sum(-1)) \
         + s.max(-1)                                   # (B,H,T)
-    got = np.asarray(lse)[:, :, 0].reshape(B, H, T)
+    # lane-major: one float32 a query, (B*H, 1, T)
+    assert lse.shape == (B * H, 1, T) and lse.dtype == jnp.float32
+    got = np.asarray(lse).reshape(B, H, T)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    # every lane carries the same broadcast value
-    assert np.all(np.asarray(lse) == np.asarray(lse)[:, :, :1])
 
 
 def test_flash_bwd_bf16_tolerance():

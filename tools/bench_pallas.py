@@ -4,10 +4,16 @@ formulations (VERDICT r3 item 2 — a perf kernel needs a perf number).
 
 Measures, on the real TPU:
   * fused_attention vs naive jnp attention (materialized (T,T) scores)
-    at T in {1024, ..., 16384}, causal, bf16, B=1 H=8 D=64 — forward
-    only (``--mode=fwd``, default) or the full fwd+bwd training path
-    (``--mode=fwdbwd``: Pallas flash forward + the r6 recompute-free
-    flash backward vs XLA differentiating the naive formulation).
+    at T in {1024, ..., 16384}, causal, bf16, ``--batch`` x ``--heads``
+    x ``--dim`` (default 1 x 8 x 64) — forward only (``--mode=fwd``,
+    default) or the full fwd+bwd training path (``--mode=fwdbwd``: the
+    flash forward and backward kernels vs the ``_contrib_fused_attention``
+    op's own einsum path (``flash_min_seq`` above T) vs XLA
+    differentiating the naive float32 formulation, each with the bytes its
+    compiled program plans; then, from a profiler trace, each ``flash_*``
+    kernel's device time beside the least time its counted matrix work
+    needs).  ``--mode=fwdbwd --seqs 1024 --batch 16 --heads 12 --no-reach``
+    is the shape of the benchmark's ``gpt2s.train-b16`` cell.
   * two_bit_compress vs the two-pass XLA formulation on a 25M-element
     gradient (ResNet-50 scale; fwd mode only).
 
@@ -21,9 +27,11 @@ methodology).
 """
 import argparse
 import functools
+import glob
 import json
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -101,6 +109,112 @@ def _naive_train_fn(scale):
     return jax.grad(loss, argnums=(0, 1, 2))
 
 
+def _op_train_fn(flash_min_seq):
+    """value_and_grad over the registered op with its dispatch pinned:
+    ``flash_min_seq`` above T is the einsum path a short sequence takes."""
+    from mxnet_tpu.ops.registry import get_op
+    op = get_op("_contrib_fused_attention")
+    attrs = op.parse_attrs(dict(causal=True, flash_min_seq=flash_min_seq))
+
+    def loss(q, k, v):
+        return jnp.sum(op.fn(attrs, q, k, v).astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def planned_bytes(fn, args):
+    """Arguments, results and temporaries of the compiled program: what
+    it holds at its fullest, from the compiler's plan."""
+    m = jax.jit(fn).lower(*args).compile().memory_analysis()
+    return int(m.argument_size_in_bytes + m.output_size_in_bytes
+               + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+# bf16 matrix peak by ``device_kind`` (Google Cloud documentation, "TPU
+# v5e"); a copy of benchmark/lib/peaks.py, which this tool does not import
+PEAK_FLOPS = {"TPU v5 lite": 197e12}
+
+# The matrix products the algorithm needs of each kernel, by the formula of
+# benchmark/lib/flops.py::flash_train_flops_bytes (copied, not imported):
+# 2 FLOPs a multiply-add over the causal T(T+1)/2 pairs, the forward's two
+# products and the backward's four (dP, dQ; dV, dK); the scores a backward
+# kernel recomputes are not counted.
+COUNTED_PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
+                    "flash_bwd": 4}
+
+
+def least_seconds(kernel, B, T, H, D, peak):
+    pairs = T * (T + 1) // 2
+    return COUNTED_PRODUCTS[kernel] * 2 * B * pairs * H * D / peak
+
+
+def kernel_seconds(fn, args, iters=10):
+    """Device seconds a call of each ``flash_*`` kernel, from a profiler
+    trace of ``iters`` calls (the trace's ``XLA Ops`` line names a Pallas
+    kernel by its ``name=``)."""
+    from jax.profiler import ProfileData
+    jax.block_until_ready(fn(*args))
+    totals = {}
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        out = None
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        files = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        for plane in ProfileData.from_file(sorted(files)[-1]).planes:
+            if not plane.name.startswith("/device:TPU:"):
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    # ``%jvp_flash_fwd_.3 = ...``: the call's name with the
+                    # transforms round it; the longest kernel name it holds
+                    name = ev.name.split(" = ", 1)[0]
+                    kernel = max((k for k in COUNTED_PRODUCTS if k in name),
+                                 key=len, default=None)
+                    if kernel:
+                        totals[kernel] = totals.get(kernel, 0.0) \
+                            + ev.duration_ns * 1e-9
+    return {k: v / iters for k, v in totals.items()}
+
+
+def report_kernels(fn, args, B, T, H, D):
+    """One line: each flash kernel's device milliseconds a call, and its
+    share of the least time its counted products need on this chip."""
+    peak = PEAK_FLOPS.get(jax.devices()[0].device_kind)
+    row = {"metric": "flash_kernel_ms", "T": T, "B": B, "H": H, "D": D}
+    for kernel, seconds in sorted(kernel_seconds(fn, args).items()):
+        row[kernel] = {"ms": round(seconds * 1e3, 4)}
+        if peak and kernel in COUNTED_PRODUCTS:
+            least = least_seconds(kernel, B, T, H, D, peak)
+            row[kernel].update(least_ms=round(least * 1e3, 4),
+                               share_pct=round(100 * least / seconds, 2))
+    print(json.dumps(row))
+
+
+def fwdbwd_row(qkv, scale):
+    """Forward + backward of one layer three ways: the flash kernels, the
+    op's own einsum path, XLA on the naive float32 formulation; each with
+    its milliseconds and the bytes its compiled program plans."""
+    B, T, H, D = qkv[0].shape
+    row = {"metric": "attention_fwdbwd_ms", "T": T, "B": B, "H": H, "D": D}
+    for name, fn in (("pallas", _flash_train_fn(True)),
+                     ("op_einsum", _op_train_fn(T + 1)),
+                     ("xla_naive", _naive_train_fn(scale))):
+        try:
+            row[name] = round(timed(jax.jit(fn), qkv) * 1e3, 3)
+            row[name + "_planned_bytes"] = planned_bytes(fn, qkv)
+        except Exception as e:       # the naive program runs out of HBM
+            row[name] = "FAILS (%s)" % type(e).__name__
+    if isinstance(row["xla_naive"], float):
+        row["speedup"] = round(row["xla_naive"] / row["pallas"], 2)
+    return row
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--mode", choices=["fwd", "fwdbwd"], default="fwd")
@@ -108,6 +222,9 @@ def main(argv=None):
                     help="run the block-size search first (forced on) "
                          "and persist the cache")
     ap.add_argument("--seqs", default="1024,2048,4096,8192,16384")
+    ap.add_argument("--batch", type=int, default=1)
+    ap.add_argument("--heads", type=int, default=8)
+    ap.add_argument("--dim", type=int, default=64, help="head dimension")
     ap.add_argument("--no-reach", action="store_true",
                     help="skip the T=32768 reach probe (interpret-mode "
                          "smoke runs)")
@@ -116,7 +233,7 @@ def main(argv=None):
     from mxnet_tpu.ops.pallas_kernels import (fused_attention,
                                               two_bit_compress)
     key = jax.random.PRNGKey(0)
-    B, H, D = 1, 8, 64
+    B, H, D = args.batch, args.heads, args.dim
     scale = 1.0 / float(np.sqrt(D))
     seqs = [int(t) for t in args.seqs.split(",") if t]
     for T in seqs:
@@ -136,23 +253,15 @@ def main(argv=None):
                 fused_attention, causal=True)), (q, k, v))
             t_naive = timed(jax.jit(functools.partial(
                 naive_attention, scale=scale)), (q, k, v))
-            name = "attention_ms"
+            print(json.dumps({
+                "metric": "attention_ms", "T": T,
+                "pallas": round(t_pallas * 1e3, 3),
+                "xla_naive": round(t_naive * 1e3, 3),
+                "speedup": round(t_naive / t_pallas, 2)}))
         else:
-            t_pallas = timed(jax.jit(_flash_train_fn(True)), (q, k, v))
-            try:
-                t_naive = timed(jax.jit(_naive_train_fn(scale)), (q, k, v))
-            except Exception as e:
-                print(json.dumps({
-                    "metric": "attention_fwdbwd_ms", "T": T,
-                    "pallas": round(t_pallas * 1e3, 3),
-                    "xla_naive": "FAILS (%s)" % type(e).__name__}))
-                continue
-            name = "attention_fwdbwd_ms"
-        print(json.dumps({
-            "metric": name, "T": T,
-            "pallas": round(t_pallas * 1e3, 3),
-            "xla_naive": round(t_naive * 1e3, 3),
-            "speedup": round(t_naive / t_pallas, 2)}))
+            report_kernels(jax.jit(_flash_train_fn(True)), (q, k, v),
+                           B, T, H, D)
+            print(json.dumps(fwdbwd_row((q, k, v), scale)))
     # reach probe: the flash kernel is HBM-bound, the naive program
     # needs the full (T, T) scores (and, in fwdbwd mode, their grads)
     if args.no_reach:
