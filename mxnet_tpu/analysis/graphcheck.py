@@ -818,15 +818,18 @@ _CACHE_WRITE_PRIMS = frozenset({"scatter", "dynamic_update_slice",
 
 def is_decode_shaped(jaxpr_like) -> bool:
     """Heuristic decode signature: the program writes in place into a
-    cache-like buffer (scatter / dynamic_update_slice) AND contracts a
-    query against an operand at least an order of magnitude larger (the
-    single-query-vs-cached-K/V shape of decode attention)."""
+    cache-like buffer (scatter / dynamic_update_slice, or a Pallas kernel
+    that aliases an operand into its result, as ``kv_write`` does) AND
+    contracts a query against an operand at least an order of magnitude
+    larger (the single-query-vs-cached-K/V shape of decode attention)."""
     has_write = False
     has_sq_attn = False
     for _path, jaxpr in _walk_jaxprs(jaxpr_like):
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            if name in _CACHE_WRITE_PRIMS:
+            if name in _CACHE_WRITE_PRIMS or (
+                    name == "pallas_call"
+                    and eqn.params.get("input_output_aliases")):
                 has_write = True
             elif name == "dot_general" and len(eqn.invars) >= 2:
                 sizes = []

@@ -32,7 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["two_bit_compress", "fused_attention", "fused_attention_fwd",
-           "fused_attention_bwd", "decode_attention", "quantize_weight",
+           "fused_attention_bwd", "decode_attention",
+           "decode_attention_pool", "kv_write", "kv_pack", "quantize_weight",
            "quant_matmul"]
 
 
@@ -653,33 +654,78 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
 
 
 # ---------------------------------------------------------------------------
-# paged single-query decode attention
+# paged single-query decode attention, and the write beside it
 # ---------------------------------------------------------------------------
 #
 # The serving decode path (mxnet_tpu/serving/decode.py) holds K/V in a
-# fixed PAGE POOL of shape (P, H, page, D): physical pages handed out by
-# a host-side allocator, one logical sequence = a per-slot row of page
-# ids.  Decode attention is then ONE query token per slot against that
-# pool.  The Pallas kernel walks a sequence's pages directly via
-# scalar-prefetched page-table indices (the PR-14 PrefetchScalarGridSpec
-# technique): grid (slot, logical_page), each step DMAs exactly one
-# (H, page, D) physical page — the pool never materializes per-sequence,
-# so HBM traffic is O(tokens_cached · D), not O(slots · max_seq · D).
-# The online-softmax state (running max / sum / accumulator) is the same
-# logsumexp machinery as the flash kernels above, carried across the
-# sequential page axis in VMEM scratch.
+# fixed PAGE POOL of six axes (L, 2, P, H, rows, lanes): physical pages
+# handed out by a host-side allocator, one logical sequence = a per-slot
+# row of page ids.  A page's (page, D) tokens lie LANE-DENSE in it:
+# ``pack = 128 // D`` tokens share one row of ``pack * D`` = 128 lanes
+# (:func:`kv_pack`; token t of a page sits in row t // pack, lanes
+# (t % pack) * D onward — the row-major reshape of (page, D)), so that
+# D = 64 pads nothing and the TPU's own layout for the array is the
+# row-major one a Mosaic kernel takes.  pack is 1 where D does not divide
+# 128 or pack does not divide the page: rows and lanes are then
+# (page, D) themselves.
+#
+# Inside the compiled step two kernels touch that pool, and both take it
+# WHOLE, in the layout it arrives in, and address it through
+# scalar-prefetched indices (PrefetchScalarGridSpec), so that XLA never
+# slices, scatters into, transposes or copies anything pool-sized:
+#
+# * ``kv_write`` puts one token's K and V per slot at (layer, 0|1,
+#   phys[s], :, token off[s]): grid (slot,), one (2, H, rows, lanes)
+#   block in, the same block out under ``input_output_aliases`` — the
+#   pool is updated where it lies.
+# * ``decode_attn`` is ONE query token per slot against the pool: grid
+#   (slot, logical_page), each cell DMAs exactly one (H, rows, lanes)
+#   physical page of K and one of V — the pool never materializes
+#   per-sequence, so HBM traffic is O(tokens_cached · D), not
+#   O(slots · max_seq · D).  The online-softmax state (running max / sum
+#   / accumulator) is the same logsumexp machinery as the flash kernels
+#   above, carried across the sequential page axis in VMEM scratch.
+#
+# The layer rides as a scalar-prefetch value, not as a Python constant:
+# the calls of every layer then share one Mosaic body.  A 4-D
+# (P, H, page, D) pool — the public :func:`decode_attention` — is the
+# six-axis one with two leading axes of length 1: one kernel, two ways in.
 
-def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                        acc_ref, m_ref, l_ref, *, page, n_pages, scale):
+_LANES = 128
+
+
+def kv_pack(page: int, head_dim: int) -> int:
+    """Tokens that share one row of a pool page (1: none do)."""
+    pack = _LANES // head_dim if _LANES % head_dim == 0 else 1
+    return pack if page % pack == 0 else 1
+
+
+def _lane_groups(shape, D, pack):
+    """For each of the ``pack`` tokens of a row, the mask of its D lanes
+    (None where a row is one token: nothing to mask)."""
+    if pack == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return [(lane >= g * D) & (lane < (g + 1) * D) for g in range(pack)]
+
+
+def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                        o_ref, acc_ref, m_ref, l_ref, *, page, D, n_pages,
+                        scale):
     """One (slot, logical page) cell.  Every value keeps the
-    (H, page|1, D|1) rank of the page block: a one-token query against a
-    page is a matrix-VECTOR product per head, which Mosaic's matmul does
+    (H, rows|1, lanes|1) rank of the page block: a one-token query against
+    a page is a matrix-VECTOR product per head, which Mosaic's matmul does
     not take (it refused the batched ``(H,page,D)·(H,D)`` dot_general:
     "failed to parse TPU_DotDimensionNumbersAttr"), so the scores and the
     weighted sum are broadcast-multiplies reduced over lanes / sublanes
-    on the VPU — decode attention is bandwidth-bound either way."""
+    on the VPU — decode attention is bandwidth-bound either way.  The
+    query arrives repeated once per token of a row; each token's score is
+    the sum over its own lanes."""
+    del layer_ref                   # the index maps' business
     s = pl.program_id(0)
     j = pl.program_id(1)
+    H, rows, lanes = k_ref.shape
+    pack = lanes // D
 
     @pl.when(j == 0)
     def _init():
@@ -695,64 +741,95 @@ def _decode_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[0].astype(jnp.float32)            # (H, 1, D)
-        k = k_ref[0].astype(jnp.float32)            # (H, page, D)
-        v = v_ref[0].astype(jnp.float32)
-        s_hp = jnp.sum(k * q, axis=-1, keepdims=True) * scale  # (H, page, 1)
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s_hp.shape, 1)
-        s_hp = jnp.where(pos < len_ref[s], s_hp, jnp.float32(_NEG_BIG))
+        q = q_ref[...].astype(jnp.float32)          # (H, 1, lanes)
+        k = k_ref[...].astype(jnp.float32)          # (H, rows, lanes)
+        v = v_ref[...].astype(jnp.float32)
+        kq = k * q
+        groups = _lane_groups(kq.shape, D, pack)
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, rows, 1), 1)
+        scores = []                                 # per token of a row
+        for g, mine in enumerate(groups):
+            s_g = jnp.sum(kq if mine is None else jnp.where(mine, kq, 0.0),
+                          axis=-1, keepdims=True) * scale  # (H, rows, 1)
+            pos = j * page + row * pack + g
+            scores.append(jnp.where(pos < len_ref[s], s_g,
+                                    jnp.float32(_NEG_BIG)))
         m_prev = m_ref[:]                           # (H, 1, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s_hp, axis=1, keepdims=True))
+        m_new = m_prev
+        for s_g in scores:
+            m_new = jnp.maximum(m_new, jnp.max(s_g, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_hp - m_new)                   # (H, page, 1)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        l_new = l_ref[:] * corr
+        p = 0.0                     # (H, rows, lanes): a token's weight
+        for s_g, mine in zip(scores, groups):       # on its own lanes
+            p_g = jnp.exp(s_g - m_new)              # (H, rows, 1)
+            l_new = l_new + jnp.sum(p_g, axis=1, keepdims=True)
+            p = p_g if mine is None else jnp.where(mine, p_g, p)
+        l_ref[:] = l_new
         m_ref[:] = m_new
         acc_ref[:] = acc_ref[:] * corr + jnp.sum(p * v, axis=1,
-                                                 keepdims=True)  # (H, 1, D)
+                                                 keepdims=True)  # (H,1,lanes)
 
     @pl.when(j == n_pages - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], jnp.float32(1e-37))
-                    ).astype(o_ref.dtype)
+        # each token of a row has summed onto its own lanes; the caller
+        # folds them (a (S, H, lanes) add, not worth a lane shuffle here)
+        o_ref[...] = (acc_ref[:] / jnp.maximum(l_ref[:], jnp.float32(1e-37))
+                      ).astype(o_ref.dtype)
 
 
-def _decode_attn_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
-                        interpret):
+def _layer_operand(layer):
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _decode_attn_pallas(q, k_pool, v_pool, layer, v_at, page_table,
+                        seq_lens, scale, interpret):
+    """``k_pool`` / ``v_pool``: six-axis ``(L, 2|1, P, H, rows, lanes)``
+    operands (the same array twice in the serving step); K is read at
+    ``[layer, 0]``, V at ``[layer, v_at]``."""
     S, H, D = q.shape
-    P, _, page, _ = k_pages.shape
+    rows, lanes = k_pool.shape[4:]
+    pack = lanes // D
     n_pages = page_table.shape[1]
-    kern = functools.partial(_decode_attn_kernel, page=page,
+    kern = functools.partial(_decode_attn_kernel, page=rows * pack, D=D,
                              n_pages=n_pages, scale=scale)
-    # q and the output ride as (S, H, 1, D): the block's trailing two dims
+
+    def page_block(at):
+        # leading three dims squeezed: the DMA per cell is one
+        # (H, rows, lanes) page, whose trailing two dims equal the array's
+        return pl.BlockSpec(
+            (None, None, None, H, rows, lanes),
+            lambda s, j, pt, ln, lyr: (lyr[0], at, pt[s, j], 0, 0, 0))
+
+    # q and the output ride as (S, H, 1, ·): the block's trailing two dims
     # then equal the array's, and the kernel never reshapes
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, n_pages),
         in_specs=[
-            pl.BlockSpec((1, H, 1, D), lambda s, j, pt, ln: (s, 0, 0, 0)),
-            pl.BlockSpec((1, H, page, D),
-                         lambda s, j, pt, ln: (pt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page, D),
-                         lambda s, j, pt, ln: (pt[s, j], 0, 0, 0)),
+            pl.BlockSpec((None, H, 1, lanes),
+                         lambda s, j, pt, ln, lyr: (s, 0, 0, 0)),
+            page_block(0),
+            page_block(v_at),
         ],
-        out_specs=pl.BlockSpec((1, H, 1, D),
-                               lambda s, j, pt, ln: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, H, 1, lanes),
+                               lambda s, j, pt, ln, lyr: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, 1, D), jnp.float32),     # acc
-            pltpu.VMEM((H, 1, 1), jnp.float32),     # running max
-            pltpu.VMEM((H, 1, 1), jnp.float32),     # running sum
+            pltpu.VMEM((H, 1, lanes), jnp.float32),  # acc
+            pltpu.VMEM((H, 1, 1), jnp.float32),      # running max
+            pltpu.VMEM((H, 1, 1), jnp.float32),      # running sum
         ],
     )
-    q4 = q.reshape(S, H, 1, D)
+    q4 = jnp.tile(q.reshape(S, H, 1, D), (1, 1, 1, pack))
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kern, grid_spec=grid_spec,
-            out_shape=_out_struct((S, H, 1, D), q.dtype, q4, k_pages,
-                                  v_pages),
+            out_shape=_out_struct((S, H, 1, lanes), q.dtype, q4, k_pool,
+                                  v_pool),
             interpret=interpret, name="decode_attn",
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-          q4, k_pages, v_pages)
-    return out.reshape(S, H, D)
+          _layer_operand(layer), q4, k_pool, v_pool)
+    return out.reshape(S, H, pack, D).sum(axis=2)
 
 
 def _decode_attn_xla(q, k_pages, v_pages, page_table, seq_lens, scale):
@@ -777,6 +854,21 @@ def _decode_attn_xla(q, k_pages, v_pages, page_table, seq_lens, scale):
     return out.astype(q.dtype)
 
 
+def decode_backend_is_pallas(S, H, D, page, dtype) -> bool:
+    """``MXNET_TPU_PALLAS_DECODE``: ``1`` / ``0`` / ``auto`` (the
+    ops/autotune cache's measured winner for this geometry, falling back
+    to pallas on TPU and XLA elsewhere)."""
+    knob = os.environ.get("MXNET_TPU_PALLAS_DECODE", "auto")
+    if knob in ("0", "1"):
+        return knob == "1"
+    from . import autotune as _autotune
+    return _autotune.decode_backend(S, H, D, page, str(dtype)) == "pallas"
+
+
+def _default_scale(D):
+    return 1.0 / float(np.sqrt(D))
+
+
 def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      page_table: jax.Array, seq_lens: jax.Array,
                      scale=None, use_pallas=None) -> jax.Array:
@@ -790,26 +882,88 @@ def decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     slot (0 = inactive slot, output is garbage-but-finite).  Returns
     (S, H, D).
 
-    ``use_pallas``: None consults ``MXNET_TPU_PALLAS_DECODE``
-    (``1``/``0``/``auto``; auto = the ops/autotune cache's measured
-    winner, falling back to pallas on TPU and XLA elsewhere)."""
+    ``use_pallas``: None consults :func:`decode_backend_is_pallas`."""
     S, H, D = q.shape
+    P, _, page, _ = k_pages.shape
     if scale is None:
-        scale = 1.0 / float(np.sqrt(D))
+        scale = _default_scale(D)
     if use_pallas is None:
-        knob = os.environ.get("MXNET_TPU_PALLAS_DECODE", "auto")
-        if knob in ("0", "1"):
-            use_pallas = knob == "1"
-        else:
-            from . import autotune as _autotune
-            use_pallas = _autotune.decode_backend(
-                S, H, D, k_pages.shape[2], str(q.dtype)) == "pallas"
+        use_pallas = decode_backend_is_pallas(S, H, D, page, q.dtype)
     if not use_pallas:
         return _decode_attn_xla(q, k_pages, v_pages, page_table, seq_lens,
                                 float(scale))
-    return _decode_attn_pallas(q, k_pages, v_pages, page_table, seq_lens,
-                               float(scale),
+    pack = kv_pack(page, D)
+    dense = (1, 1, P, H, page // pack, pack * D)
+    return _decode_attn_pallas(q, k_pages.reshape(dense),
+                               v_pages.reshape(dense), 0, 0, page_table,
+                               seq_lens, float(scale),
                                _interpret(q, k_pages, v_pages))
+
+
+def decode_attention_pool(q: jax.Array, kv: jax.Array, layer,
+                          page_table: jax.Array, seq_lens: jax.Array,
+                          scale=None) -> jax.Array:
+    """:func:`decode_attention` for one layer of the whole serving pool
+    ``kv`` (L, 2, P, H, rows, lanes), which the kernel takes as it is: K
+    at ``kv[layer, 0]``, V at ``kv[layer, 1]``, no slice made.  Pallas
+    only (the XLA formulation takes the 4-D slices)."""
+    if scale is None:
+        scale = _default_scale(q.shape[-1])
+    return _decode_attn_pallas(q, kv, kv, layer, 1, page_table, seq_lens,
+                               float(scale), _interpret(q, kv))
+
+
+def _kv_write_kernel(phys_ref, off_ref, layer_ref, new_ref, kv_ref, out_ref,
+                     *, D):
+    """One slot: its (2, H, rows, lanes) page with token ``off[s]``
+    replaced by the slot's K and V (which arrive repeated once per token
+    of a row)."""
+    del phys_ref, layer_ref         # the index maps' business
+    off = off_ref[pl.program_id(0)]
+    pack = kv_ref.shape[3] // D
+    hit = jax.lax.broadcasted_iota(jnp.int32, kv_ref.shape, 2) == off // pack
+    if pack > 1:                    # several tokens a row: this one's lanes
+        lane = jax.lax.broadcasted_iota(jnp.int32, kv_ref.shape, 3)
+        first = off % pack * D
+        hit = hit & (lane >= first) & (lane < first + D)
+    out_ref[...] = jnp.where(hit, new_ref[...], kv_ref[...])
+
+
+def kv_write(kv: jax.Array, layer, k: jax.Array, v: jax.Array,
+             phys: jax.Array, off: jax.Array) -> jax.Array:
+    """The pool ``kv`` (L, 2, P, H, rows, lanes) with token ``off[s]`` of
+    page ``phys[s]`` of ``layer`` set to ``k[s]`` (at ``[layer, 0]``) and
+    ``v[s]`` (``[layer, 1]``) for every slot ``s`` — written where the
+    pool lies (``input_output_aliases``; donate ``kv`` and nothing
+    pool-sized is copied).  ``k`` / ``v``: (S, H, D).  Slots that share a
+    page (the inactive ones, all on the trash page) may overwrite one
+    another there in any order."""
+    S, H, D = k.shape
+    rows, lanes = kv.shape[4:]
+    new = jnp.tile(jnp.stack([k, v], axis=1).astype(kv.dtype)
+                   .reshape(S, 2, H, 1, D), (1, 1, 1, 1, lanes // D))
+    page_block = pl.BlockSpec(
+        (None, 2, None, H, rows, lanes),
+        lambda s, ph, of, lyr: (lyr[0], 0, ph[s], 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((None, 2, H, 1, lanes),
+                         lambda s, ph, of, lyr: (s, 0, 0, 0, 0)),
+            page_block,
+        ],
+        out_specs=page_block,
+    )
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            functools.partial(_kv_write_kernel, D=D), grid_spec=grid_spec,
+            out_shape=_out_struct(kv.shape, kv.dtype, new, kv),
+            # operand 4 (after the three scalars and the rows) is the pool
+            input_output_aliases={4: 0},
+            interpret=_interpret(kv, k, v), name="kv_write",
+        )(phys.astype(jnp.int32), off.astype(jnp.int32),
+          _layer_operand(layer), new, kv)
 
 
 # ---------------------------------------------------------------------------

@@ -9,16 +9,22 @@ interactive half the TensorFlow system paper calls the core serving
 split (PAPERS.md): a decode loop whose per-token step
 
 * keeps K/V in a **paged cache**: one fixed physical page pool
-  ``(L, 2, P, H, page, D)`` plus per-slot page tables, so cache shapes
+  ``(L, 2, P, H, rows, lanes)`` (a page's ``(page, D)`` tokens packed
+  lane-dense) plus per-slot page tables, so cache shapes
   NEVER change — the step program compiles exactly once, whatever
   sequence lengths come and go (the recompile-per-token trap is
   graphcheck rule GC307);
-* writes the new token's K/V **in place** (donated pool, scatter at
-  ``(page, offset)`` from the page table) and attends with the Pallas
-  single-query flash kernel (:func:`~mxnet_tpu.ops.pallas_kernels
-  .decode_attention`) walking the slot's pages via scalar-prefetched
-  indices — or the XLA gather formulation, which is also what GSPMD
-  shards for tensor-parallel serving (``MXNET_TPU_PALLAS_DECODE``);
+* touches the pool with two Pallas kernels and nothing else: ``kv_write``
+  puts the new token's K/V row at ``(page, offset)`` **in place** (the
+  donated pool is aliased into the kernel's result) and ``decode_attn``
+  (:func:`~mxnet_tpu.ops.pallas_kernels.decode_attention_pool`) walks
+  the slot's pages via scalar-prefetched indices; both take the whole
+  six-axis pool as it lies (lane-dense pages,
+  :meth:`DecodeConfig.pool_shape`), so the compiled step holds no
+  slice, scatter or copy of anything pool-sized — or the XLA scatter /
+  gather formulation over per-layer slices, which is what the CPU runs
+  and GSPMD shards for tensor-parallel serving
+  (``MXNET_TPU_PALLAS_DECODE``);
 * runs **continuous token-level batching** (:class:`DecodeEngine`):
   a scheduler admits and retires sequences per STEP, so requests join
   and leave the running batch mid-generation — slot allocation from the
@@ -72,7 +78,8 @@ from .request import Request
 from .runtime import ServingRuntime, _env_int
 
 __all__ = ["DecodeConfig", "PagePool", "DecodeProgram", "DecodeRequest",
-           "DecodeEngine", "init_decode_params", "decode_tp_model_bytes"]
+           "DecodeEngine", "decode_param_shapes", "init_decode_params",
+           "decode_tp_model_bytes"]
 
 _MAGIC = "mxnet_tpu-decode-v1"
 
@@ -131,6 +138,18 @@ class DecodeConfig:
         n = _env_int("MXNET_TPU_DECODE_PAGES", 0)
         return int(n) if n > 0 else 1 + self.max_seqs * self.pages_per_seq
 
+    def pool_shape(self) -> tuple:
+        """``(L, 2, P, H, rows, lanes)``: a page's ``(page_size,
+        head_dim)`` tokens lie lane-dense in its last two axes,
+        ``pallas_kernels.kv_pack`` of them to a row of 128 lanes (the
+        row-major reshape of ``(page_size, head_dim)``), so that a
+        head_dim of 64 pads nothing on the TPU and the device's own layout
+        for the array is the row-major one the kernels read."""
+        from ..ops.pallas_kernels import kv_pack
+        pack = kv_pack(self.page_size, self.head_dim)
+        return (self.num_layers, 2, self.pool_pages(), self.heads,
+                self.page_size // pack, pack * self.head_dim)
+
     def to_meta(self) -> dict:
         return {k: getattr(self, k) for k in self.__slots__}
 
@@ -153,7 +172,7 @@ class DecodeConfig:
 class PagePool:
     """Host-side physical-page allocator over the fixed device pool.
 
-    Page 0 is reserved as the trash page: inactive slots scatter their
+    Page 0 is reserved as the trash page: inactive slots put their
     (masked, never-read) K/V writes there, so the step program needs no
     control flow for slot liveness."""
 
@@ -183,33 +202,43 @@ class PagePool:
             self._free.extend(int(p) for p in pages)
 
 
-def init_decode_params(config: DecodeConfig, seed: int = 0,
-                       scale: float = 0.02) -> Dict[str, np.ndarray]:
-    """Random parameters with the TRAINING graph's names and layouts
-    (models/transformer.get_symbol) — the decode program consumes a
-    trained module's ``arg_params`` directly; this helper only exists
-    for tests and benches that have no trained model at hand."""
-    rs = np.random.RandomState(seed)
+def decode_param_shapes(config: DecodeConfig) -> Dict[str, tuple]:
+    """Name -> shape of every parameter the decode program consumes: the
+    TRAINING graph's names and layouts (models/transformer.get_symbol)."""
     h, v, t = config.hidden, config.vocab_size, config.max_seq_len
-
-    def w(*shape):
-        return (rs.randn(*shape) * scale).astype(np.float32)
-
-    params = {"tok_embed_weight": w(v, h), "pos_embed": w(t, h),
-              "ln_f_gamma": np.ones(h, np.float32),
-              "ln_f_beta": np.zeros(h, np.float32),
-              "head_weight": w(v, h), "head_bias": np.zeros(v, np.float32)}
+    shapes = {"tok_embed_weight": (v, h), "pos_embed": (t, h),
+              "ln_f_gamma": (h,), "ln_f_beta": (h,),
+              "head_weight": (v, h), "head_bias": (v,)}
     for i in range(config.num_layers):
         p = "l%d_" % i
         for nm, shape in (("q", (h, h)), ("k", (h, h)), ("v", (h, h)),
                           ("proj", (h, h)), ("ff1", (4 * h, h)),
                           ("ff2", (h, 4 * h))):
-            params[p + nm + "_weight"] = w(*shape)
-            params[p + nm + "_bias"] = np.zeros(shape[0], np.float32)
+            shapes[p + nm + "_weight"] = shape
+            shapes[p + nm + "_bias"] = shape[:1]
         for ln in ("ln1", "ln2"):
-            params[p + ln + "_gamma"] = np.ones(h, np.float32)
-            params[p + ln + "_beta"] = np.zeros(h, np.float32)
-    return params
+            shapes[p + ln + "_gamma"] = (h,)
+            shapes[p + ln + "_beta"] = (h,)
+    return shapes
+
+
+def init_decode_params(config: DecodeConfig, seed: int = 0,
+                       scale: float = 0.02) -> Dict[str, np.ndarray]:
+    """Random parameters in :func:`decode_param_shapes` — the decode
+    program consumes a trained module's ``arg_params`` directly; this
+    helper only exists for tests and benches that have no trained model
+    at hand."""
+    rs = np.random.RandomState(seed)
+
+    def init(name, shape):
+        if name.endswith("gamma"):
+            return np.ones(shape, np.float32)
+        if name.endswith(("beta", "bias")):
+            return np.zeros(shape, np.float32)
+        return (rs.randn(*shape) * scale).astype(np.float32)
+
+    return {name: init(name, shape)
+            for name, shape in decode_param_shapes(config).items()}
 
 
 def decode_tp_model_bytes(config: DecodeConfig, tp: int,
@@ -271,6 +300,14 @@ class DecodeProgram:
     GSPMD (attention heads / FFN hidden / KV pool sharded over ``tp``).
     ``quantize`` (or ``config.quantize``): int8/int4 weight-only
     quantized matmuls, fixed at construction = "selected at export".
+
+    The page pool (:meth:`DecodeConfig.pool_shape`, float32) is the
+    step's one piece of state: made by :meth:`fresh_cache`, donated to
+    every step and handed back as the same buffer.  On one device with
+    the Pallas backend the step writes and reads it through ``kv_write``
+    and ``decode_attn`` alone, where it lies; the XLA backend and the tp
+    export scatter into and gather from per-layer slices, and XLA lays
+    the pool out as it sees fit for that.
     """
 
     def __init__(self, params: Dict, config: DecodeConfig, *, mesh=None,
@@ -379,15 +416,13 @@ class DecodeProgram:
                              P(None, None, None, "tp", None, None))
 
     def fresh_cache(self):
-        """Zeroed page pool ``(L, 2, P, H, page, D)`` on device (tp:
+        """Zeroed page pool :meth:`DecodeConfig.pool_shape` on device (tp:
         sharded over heads).  The engine owns exactly one and threads it
-        through every step (donated)."""
+        through every step (donated): the step hands back the same
+        buffer, written in place."""
         import jax
         import jax.numpy as jnp
-        c = self.config
-        shape = (c.num_layers, 2, c.pool_pages(), c.heads, c.page_size,
-                 c.head_dim)
-        z = jnp.zeros(shape, jnp.float32)
+        z = jnp.zeros(self.config.pool_shape(), jnp.float32)
         kv = jax.device_put(z, self.kv_sharding()) \
             if self.spec is not None else jax.device_put(z)
         telemetry.memory.tag(kv, "kv_cache",
@@ -396,9 +431,7 @@ class DecodeProgram:
 
     @property
     def cache_bytes(self) -> int:
-        c = self.config
-        return (c.num_layers * 2 * c.pool_pages() * c.heads *
-                c.page_size * c.head_dim * 4)
+        return int(np.prod(self.config.pool_shape())) * 4
 
     # -- the step program --------------------------------------------------
     def _make_step_fn(self, count=True):
@@ -431,6 +464,31 @@ class DecodeProgram:
             return (x32 - mean) * inv * p[name + "_gamma"] \
                 + p[name + "_beta"]
 
+        # The pool's two touches a layer, in two formulations.  Pallas:
+        # both kernels take the pool whole and address it by prefetched
+        # scalars, so the compiled step holds no slice, scatter or copy of
+        # anything pool-sized and the donated pool is updated where it
+        # lies.  XLA: a scatter and a gather over per-layer slices of the
+        # pool seen as (L, 2, P, H, page, D) — the form GSPMD can shard
+        # and the CPU runs; XLA is free to re-lay the pool for it (on the
+        # v5e it did, at two copies of the pool a step), so no chip cell
+        # runs it.
+        by_token = (c.num_layers, 2, c.pool_pages(), H, c.page_size, Dh)
+
+        def _write_xla(kv, i, k, v, phys, off):
+            kv = kv.reshape(by_token)
+            kv = kv.at[i, 0, phys, :, off, :].set(k.astype(kv.dtype))
+            kv = kv.at[i, 1, phys, :, off, :].set(v.astype(kv.dtype))
+            return kv.reshape(c.pool_shape())
+
+        def _attend_xla(q, kv, i, page_table, seq_lens):
+            kv = kv.reshape(by_token)
+            return pk.decode_attention(q, kv[i, 0], kv[i, 1], page_table,
+                                       seq_lens, use_pallas=False)
+
+        _pool_ops_xla = (_write_xla, _attend_xla)
+        _pool_ops_pallas = (pk.kv_write, pk.decode_attention_pool)
+
         def step(params, kv, tokens, positions, seq_lens, phys, off,
                  page_table):
             # ONE trace, ever: shapes are fixed by the config, token
@@ -438,6 +496,15 @@ class DecodeProgram:
             if count:
                 self.trace_count += 1
             S = c.max_seqs
+            # a pool handed over by token, (L, 2, P, H, page, D), is taken
+            # and given back in that shape; fresh_cache's needs no reshape
+            came_as = kv.shape
+            kv = kv.reshape(c.pool_shape())
+            # how the pool is written and read, decided once for both
+            write, attend = (_pool_ops_pallas if not sharded
+                             and pk.decode_backend_is_pallas(
+                                 S, H, Dh, c.page_size, kv.dtype)
+                             else _pool_ops_xla)
             # stable device-side names (jax.named_scope: metadata only);
             # no layer index in them, so the layers group in a trace
             scope = jax.named_scope
@@ -452,18 +519,11 @@ class DecodeProgram:
                     q = lin(params, a, pfx + "q").reshape(S, H, Dh)
                     k = lin(params, a, pfx + "k").reshape(S, H, Dh)
                     v = lin(params, a, pfx + "v").reshape(S, H, Dh)
-                # in-place paged write: scatter this token's K/V into
-                # (physical page, offset) per slot — donated pool, so
-                # XLA updates in place and shapes never change
+                # the pool is touched by these two and by nothing else
                 with scope("mx.decode.kv_write"):
-                    kv = kv.at[i, 0, phys, :, off, :].set(
-                        k.astype(kv.dtype))
-                    kv = kv.at[i, 1, phys, :, off, :].set(
-                        v.astype(kv.dtype))
+                    kv = write(kv, i, k, v, phys, off)
                 with scope("mx.decode.attn"):
-                    att = pk.decode_attention(
-                        q, kv[i, 0], kv[i, 1], page_table, seq_lens,
-                        use_pallas=False if sharded else None)
+                    att = attend(q, kv, i, page_table, seq_lens)
                 with scope("mx.decode.proj"):
                     att = lin(params, att.reshape(S, c.hidden),
                               pfx + "proj")
@@ -491,7 +551,7 @@ class DecodeProgram:
                                               PartitionSpec()))
             with scope("mx.decode.sample"):
                 next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return next_tok, logits, kv
+            return next_tok, logits, kv.reshape(came_as)
 
         return step
 

@@ -1,0 +1,291 @@
+"""The decode step and its page pool: the two kernels that touch the pool
+(``kv_write``, the six-axis entry of ``decode_attn``) take it whole, and
+nothing else in the step touches it.
+
+CPU, Pallas interpreter — except the last test, which compiles the 64-slot
+step for a described v5e (no chip; skipped where none can be described).
+The topology is described inside a fixture, never at import.
+"""
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import mxnet_tpu  # noqa: F401  (x64 + matmul precision config)
+from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.serving.decode import (DecodeConfig, DecodeProgram,
+                                      decode_param_shapes,
+                                      init_decode_params)
+
+LAYERS = 3
+
+
+# -- (a) decode_attn, six-axis entry --------------------------------------
+
+def _attention_reference(q, kp, vp, pt, lens):
+    """numpy reference of test_decode.py's paged-attention test."""
+    S, nH, D = q.shape
+    ref = np.zeros((S, nH, D), np.float32)
+    for s in range(S):
+        tl = int(lens[s])
+        if tl == 0:
+            continue
+        ks = np.concatenate([kp[p] for p in pt[s]], axis=1)[:, :tl]
+        vs = np.concatenate([vp[p] for p in pt[s]], axis=1)[:, :tl]
+        sc = np.einsum("hd,htd->ht", q[s], ks) / np.sqrt(D)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        ref[s] = np.einsum("ht,htd->hd", p, vs)
+    return ref
+
+
+# (head_dim, page): one token a row; two (the GPT-2 case in small); four
+GEOMETRIES = [(8, 4), (64, 4), (32, 8)]
+
+
+def _dense(by_token):
+    """A pool given by token, (..., page, D), as the program holds it."""
+    page, D = by_token.shape[-2:]
+    pack = pk.kv_pack(page, D)
+    return by_token.reshape(by_token.shape[:-2] + (page // pack, pack * D))
+
+
+def test_kv_pack():
+    assert [pk.kv_pack(page, D) for D, page in GEOMETRIES] == [1, 2, 4]
+    assert pk.kv_pack(16, 64) == 2 and pk.kv_pack(16, 128) == 1
+    assert pk.kv_pack(16, 96) == 1 and pk.kv_pack(3, 64) == 1
+
+
+@pytest.mark.parametrize("D,page", GEOMETRIES)
+@pytest.mark.parametrize("layer", range(LAYERS))
+def test_decode_attention_takes_the_whole_pool(layer, D, page):
+    rs = np.random.RandomState(layer)
+    S, nH, MP, P = 3, 2, 3, 10
+    q = rs.randn(S, nH, D).astype(np.float32)
+    kv = rs.randn(LAYERS, 2, P, nH, page, D).astype(np.float32)  # K != V
+    pt = rs.randint(0, P, (S, MP)).astype(np.int32)
+    # a partial page, every page full, an inactive slot
+    lens = np.array([page + 1, MP * page, 0], np.int32)
+    ref = _attention_reference(q, kv[layer, 0], kv[layer, 1], pt, lens)
+    out = np.asarray(pk.decode_attention_pool(q, _dense(kv), layer, pt,
+                                              lens))
+    assert np.abs(out[:2] - ref[:2]).max() < 1e-5
+    assert np.isfinite(out).all()    # inactive slot: garbage but finite
+    # the same body through the public 4-D way in
+    out4 = np.asarray(pk.decode_attention(q, kv[layer, 0], kv[layer, 1],
+                                          pt, lens, use_pallas=True))
+    assert np.array_equal(out[:2], out4[:2])
+
+
+# -- (b) kv_write ----------------------------------------------------------
+
+@pytest.mark.parametrize("D,page", GEOMETRIES)
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("case", ["distinct", "trash"])
+def test_kv_write_changes_exactly_its_rows(layer, case, D, page):
+    rs = np.random.RandomState(7 + layer)
+    S, nH, P = 5, 2, 9
+    kv = rs.randn(LAYERS, 2, P, nH, page, D).astype(np.float32)
+    k = rs.randn(S, nH, D).astype(np.float32)
+    v = rs.randn(S, nH, D).astype(np.float32)
+    if case == "distinct":
+        phys = np.array([3, 7, 1, 8, 5], np.int32)
+        off = np.array([0, 3, 2, 2, 1], np.int32)
+    else:       # slots 1, 3, 4 inactive: all on trash page 0, two collide
+        phys = np.array([3, 0, 6, 0, 0], np.int32)
+        off = np.array([1, 2, 3, 2, 0], np.int32)
+    out = np.asarray(pk.kv_write(_dense(kv), layer, k, v, phys, off))
+    assert out.shape == _dense(kv).shape and out.dtype == kv.dtype
+    out = out.reshape(kv.shape)
+    touched = np.zeros(kv.shape, bool)
+    for s in range(S):
+        touched[layer, :, phys[s], :, off[s], :] = True
+        # a live slot's row holds its K and V, bit for bit.  Slots share a
+        # page only on trash page 0, where they overwrite one another in
+        # any order (a later cell may write back the page as it was
+        # fetched): a row there holds what one of them wrote, or stays
+        rivals = [r for r in range(S)
+                  if (phys[r], off[r]) == (phys[s], off[s])]
+        assert phys[s] == 0 or rivals == [s]
+        for at, new in ((0, k), (1, v)):
+            row = out[layer, at, phys[s], :, off[s]]
+            held = [new[r] for r in rivals]
+            if phys[s] == 0:
+                held.append(kv[layer, at, 0, :, off[s]])
+            assert any(np.array_equal(row, h) for h in held), (s, at)
+    assert touched.sum() == len({(p, o) for p, o in zip(phys, off)}) \
+        * 2 * nH * D
+    assert np.array_equal(out[~touched], kv[~touched])
+
+
+# -- (c), (d) the step ------------------------------------------------------
+
+VOCAB, T = 61, 16
+
+
+@pytest.fixture(scope="module", params=[(32, 4), (128, 2)],
+                ids=["head_dim8", "head_dim64"])
+def cfg_and_params(request):
+    hidden, heads = request.param
+    cfg = DecodeConfig(VOCAB, LAYERS, hidden, heads, T, page_size=4,
+                       max_seqs=3)
+    return cfg, init_decode_params(cfg, seed=5)
+
+
+def _pool_touches(jaxpr, page_shape, min_size, inside=""):
+    """(primitive, where) of every equation, at any depth outside the
+    Pallas kernels' own bodies, with a pool-sized operand or result: one
+    that ends in a page's ``(H, page, D)`` and holds at least one layer's
+    keys."""
+    def pool_sized(x):
+        shape = tuple(getattr(getattr(x, "aval", None), "shape", ()))
+        return shape[-3:] == page_shape and np.prod(shape) >= min_size
+
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if any(pool_sized(x) for x in list(eqn.invars) + list(eqn.outvars)):
+            found.append((name, inside))
+        if name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _pool_touches(sub, page_shape, min_size,
+                                           inside + "/" + name)
+    return found
+
+
+def test_step_touches_the_pool_only_in_pallas_calls(cfg_and_params,
+                                                    monkeypatch):
+    cfg, params = cfg_and_params
+    monkeypatch.setenv("MXNET_TPU_PALLAS_DECODE", "1")
+    prog = DecodeProgram(params, cfg, name="jaxpr")
+    kv = prog.fresh_cache()
+    closed = jax.make_jaxpr(prog._make_step_fn(count=False))(
+        prog._params, kv, *prog._zero_step_args())
+    one_layers_k = kv.size // (2 * cfg.num_layers)   # kv[i, 0] is pool-sized
+    assert kv.shape == cfg.pool_shape() and kv.shape[-1] in (8, 128)
+    page = kv.shape[-3:]
+    touches = _pool_touches(closed.jaxpr, page, one_layers_k)
+    assert {name for name, _ in touches} == {"pallas_call"}, touches
+    # a write and a read a layer, each on the whole pool
+    assert len(touches) == 2 * cfg.num_layers
+    calls = [e for e in closed.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert sorted({e.params["name"] for e in calls}) \
+        == ["decode_attn", "kv_write"]
+    # GC307 still knows it for a decode step (the aliased write)
+    from mxnet_tpu.analysis.graphcheck import is_decode_shaped
+    assert is_decode_shaped(closed)
+    # and the XLA formulation is what it was: slices, scatters, gathers
+    monkeypatch.setenv("MXNET_TPU_PALLAS_DECODE", "0")
+    closed = jax.make_jaxpr(prog._make_step_fn(count=False))(
+        prog._params, kv, *prog._zero_step_args())
+    by_token = (cfg.heads, cfg.page_size, cfg.head_dim)
+    assert "scatter" in {n for n, _ in
+                         _pool_touches(closed.jaxpr, by_token, one_layers_k)}
+
+
+def _teacher_forced(prog, toks):
+    """Feed ``toks`` (steps, S) through the step; (next tokens, logits)."""
+    cfg = prog.config
+    S = cfg.max_seqs
+    table = np.zeros((S, cfg.pages_per_seq), np.int32)
+    for s in range(S - 1):          # the last slot stays inactive
+        table[s] = 1 + s * cfg.pages_per_seq + np.arange(cfg.pages_per_seq)
+    active = np.arange(S) < S - 1
+    kv = prog.fresh_cache()
+    nxt, logits = [], []
+    for t, tok in enumerate(toks):
+        pos = np.full(S, t % cfg.max_seq_len, np.int32)
+        if t == cfg.max_seq_len:    # contexts are full: start them again
+            kv = prog.fresh_cache()
+        n, lg, kv = prog.step(
+            kv, tok, pos, np.where(active, pos + 1, 0).astype(np.int32),
+            np.where(active, table[np.arange(S), pos // cfg.page_size], 0),
+            np.where(active, pos % cfg.page_size, 0).astype(np.int32),
+            table)
+        nxt.append(np.asarray(n)[active])
+        logits.append(np.asarray(lg)[active])
+    return np.stack(nxt), np.stack(logits)
+
+
+def test_pallas_step_agrees_with_xla_step(cfg_and_params, monkeypatch):
+    cfg, params = cfg_and_params
+    toks = np.random.RandomState(11).randint(
+        0, VOCAB, (40, cfg.max_seqs)).astype(np.int32)
+    got = {}
+    for knob in ("1", "0"):
+        monkeypatch.setenv("MXNET_TPU_PALLAS_DECODE", knob)
+        prog = DecodeProgram(params, cfg, name="knob" + knob)
+        got[knob] = _teacher_forced(prog, toks)
+        assert prog.trace_count == 1
+    assert np.array_equal(got["1"][0], got["0"][0])
+    assert np.abs(got["1"][1] - got["0"][1]).max() < 1e-5
+
+
+# -- the 64-slot step, compiled for a described v5e ------------------------
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return topo.devices[0]
+
+
+def test_64_slot_step_compiles_for_the_v5e(v5e_chip, monkeypatch):
+    """GPT-2 small at 64 slots, context 1024, page 16.  Before PR 30 the
+    TPU compiler refused this step ("Used 18.26G of 15.75G hbm": a padded
+    copy of the pool re-laid for the scatter).  Now the plan is the
+    arguments: nothing pool-sized but the pool, and the pool lane-dense."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("MXNET_TPU_PALLAS_DECODE", "1")
+    monkeypatch.setattr(pk, "_interpret", lambda *a: False)   # Mosaic
+    S = 64
+    cfg = DecodeConfig(50257, 12, 768, 12, 1024, page_size=16, max_seqs=S)
+    # the program object needs host arrays to exist; zeros do for a compile
+    weights = {k: np.zeros(shape, np.float32)
+               for k, shape in decode_param_shapes(cfg).items()}
+    prog = DecodeProgram(weights, cfg, name="aot64")
+    on = SingleDeviceSharding(v5e_chip)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=on)
+
+    pool = cfg.pool_shape()
+    compiled = jax.jit(prog._make_step_fn(count=False),
+                       donate_argnums=(1,)).lower(
+        {k: sds(v.shape, jnp.float32) for k, v in weights.items()},
+        sds(pool, jnp.float32), sds((S,)), sds((S,)), sds((S,)), sds((S,)),
+        sds((S,)), sds((S, cfg.pages_per_seq))).compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+               - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    print("64-slot decode step: planned %.2f GB, temporaries %.3f GB, pool "
+          "%.2f GB of data" % (planned / 1e9, ma.temp_size_in_bytes / 1e9,
+                               4 * np.prod(pool) / 1e9))
+    # every instruction with a pool-sized result is the entry parameter or
+    # one of the kernels that write it in place
+    pool_type = r"f32\[%s\]" % ",".join(map(str, pool))
+    makers = re.findall(r"= %s\S* ([\w\-]+)\(" % pool_type, text)
+    assert makers and set(makers) <= {"parameter", "custom-call"}, makers
+    assert makers.count("custom-call") == cfg.num_layers
+    mosaic = re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
+                        r'"tpu_custom_call"', text)
+    for kernel in ("kv_write", "decode_attn"):
+        assert sum(kernel in c for c in mosaic) == cfg.num_layers, mosaic
+    assert ma.temp_size_in_bytes < 0.5e9
+    # the pool arrives row-major and unpadded: 4.83 GB, all of it data
+    assert ma.alias_size_in_bytes == 4 * np.prod(pool)
+    assert planned < 8e9
