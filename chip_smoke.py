@@ -2,11 +2,11 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 Drives the main paths once, through the entry points a user would call, at
-the full width of the models bench.py measures (depth is what it is there;
+the full width of the models the benchmark measures (depth is what it is there;
 the weights are random, made from a seed):
 
   trainer/resnet50     models.resnet -> ShardedTrainer -> build_step_auto_layout
-                       (bench.py's path), 32 images per chip, batches put
+                       (the benchmark's path), 32 images per chip, batches put
                        from host memory every step
   trainer/transformer  models.transformer L12/H768/12 heads/V32768/T1024,
                        8 sequences per chip, bf16, contrib.fused_attention on
@@ -43,7 +43,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-# the width every phase runs at: bench.py's transformer geometry
+# the width every phase runs at: GPT-2 small's, with a 32k vocabulary
 VOCAB, HIDDEN, HEADS, LAYERS, SEQ = 32768, 768, 12, 12, 1024
 IMAGES_PER_CHIP, SEQS_PER_CHIP = 32, 8
 STEPS = 5
@@ -111,7 +111,7 @@ def where(arrays):
 
 def run_trainer(compiles, sym, devices, batches, lr, wd, steps,
                 want_kernels=(), dtype="bfloat16"):
-    """bench.py's training path over a dp mesh of ``devices``: init, AOT
+    """The benchmark's training path over a dp mesh of ``devices``: init, AOT
     step with compiler-chosen layouts, ``steps`` steps with every batch put
     from host memory, and the cross-entropy of the first batch (by the
     executor's own forward, over the same mesh) before and after.  Returns

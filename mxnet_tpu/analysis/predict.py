@@ -10,10 +10,9 @@ achievable fraction.  This module closes that gap in three pieces:
 * **calibration store** — achievable-fraction coefficients per
   ``device_kind × roofline bucket`` (compute / hbm / collective),
   fitted from the telemetry step histograms (every attribution report
-  with a measured step is a calibration sample) and from the committed
-  ``PERF_LEDGER.jsonl`` history (the ``*_mfu`` series are exactly the
-  compute-bucket fraction).  Persisted under the PR-13 shared cache
-  rule (:func:`~mxnet_tpu.compile.paths.cache_location`):
+  with a measured step is a calibration sample).  Persisted under the
+  PR-13 shared cache rule
+  (:func:`~mxnet_tpu.compile.paths.cache_location`):
   ``MXNET_TPU_CALIBRATION_CACHE`` overrides, off-values disable, default
   ``<checkout>/.cache/calibration.json``.
 
@@ -28,7 +27,7 @@ achievable fraction.  This module closes that gap in three pieces:
 * **runtime conformance** — :func:`conformance` compares measured
   histograms against a budget and hands back per-metric
   measured/predicted ratios with a WITHIN / DEGRADED / VIOLATED
-  verdict; the bands reuse the benchwatch drawdown-σ machinery
+  verdict; the bands are sized by the history's drawdown-σ
   (``max(σ·noise, floor)`` with the floor at the ~20% agreement
   target).  ``telemetry/perf.py`` folds the section into attribution
   reports, exports ``perf.conformance{entry,metric}`` gauges and a
@@ -53,7 +52,7 @@ from ..compile.paths import cache_location
 
 __all__ = ["DEFAULT_FRACTION", "achievable_fraction", "budget_table",
            "calibration_store_path", "conformance", "conformance_bands",
-           "digest_column", "fit_from_attribution", "fit_from_ledger",
+           "digest_column", "fit_from_attribution",
            "load_store", "note_budget", "noted_budget", "predict_budget",
            "predict_decode_budget", "reset", "runtime_conformance",
            "save_report", "save_store", "update_calibration"]
@@ -72,7 +71,7 @@ TARGET_DEVICE_KIND = "TPU v5 lite"
 DEFAULT_FRACTION = 0.5
 
 # conformance floor = the repo's ~20% prediction-agreement target; the
-# σ multiplier matches the benchwatch gate
+# σ multiplier sizes the band by the history's own noise
 CONFORMANCE_FLOOR = 0.20
 SIGMA_MULT = 4.0
 
@@ -206,38 +205,6 @@ def fit_from_attribution(store: Dict, data: Dict) -> Optional[Dict]:
             or device_kind())
     return update_calibration(store, kind, bucket,
                               device_roof / measured, source="telemetry")
-
-
-def fit_from_ledger(ledger_path: str, store: Optional[Dict] = None,
-                    kind: Optional[str] = None) -> Dict:
-    """Fit the compute bucket from a benchwatch trajectory ledger
-    (``BENCH_LEDGER``): every ``*_mfu`` metric IS an achievable-fraction
-    sample (MFU = analytic compute_s / measured step for a compute-bound
-    program)."""
-    store = load_store() if store is None else store
-    kind = kind or device_kind()
-    samples: List[float] = []
-    try:
-        with open(ledger_path) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    e = json.loads(line)
-                except ValueError:
-                    continue
-                for name, v in (e.get("metrics") or {}).items():
-                    if name.endswith("_mfu") and \
-                            isinstance(v, (int, float)) and 0 < v <= 1:
-                        samples.append(float(v))
-    except OSError:
-        return store
-    if samples:
-        update_calibration(store, kind, "compute",
-                           statistics.median(samples), source="ledger",
-                           weight=len(samples))
-    return store
 
 
 # ---------------------------------------------------------------------------
@@ -496,37 +463,25 @@ def noted_budget(program: str) -> Optional[Dict]:
 
 
 def _drawdown_sigma(history: List[float]) -> float:
-    """benchwatch's drawdown-σ (tools/benchwatch.py) when importable,
-    else the same computation inline — the bands must match the gate."""
-    try:
-        import importlib.util
-        root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        path = os.path.join(root, "tools", "benchwatch.py")
-        spec = importlib.util.spec_from_file_location("_mxt_benchwatch",
-                                                      path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return float(mod.drawdown_sigma(list(history)))
-    except Exception:
-        if len(history) < 2:
-            return 0.0
-        run_max = history[0]
-        draws = []
-        for v in history[1:]:
-            run_max = max(run_max, v)
-            draws.append((run_max - v) / run_max if run_max > 0 else 0.0)
-        if len(draws) < 2:
-            return 0.0
-        return statistics.stdev(draws)
+    """Standard deviation of a series' relative drawdowns from its
+    running maximum: the noise the conformance bands are sized by."""
+    if len(history) < 2:
+        return 0.0
+    run_max = history[0]
+    draws = []
+    for v in history[1:]:
+        run_max = max(run_max, v)
+        draws.append((run_max - v) / run_max if run_max > 0 else 0.0)
+    if len(draws) < 2:
+        return 0.0
+    return statistics.stdev(draws)
 
 
 def conformance_bands(history: Optional[List[float]] = None,
                       floor: float = CONFORMANCE_FLOOR,
                       sigma_mult: float = SIGMA_MULT) -> Dict:
     """Verdict bands for one metric: DEGRADED past ``max(σ·noise,
-    floor)`` in the bad direction, VIOLATED past twice that — the
-    benchwatch gate formula applied to prediction drift."""
+    floor)`` in the bad direction, VIOLATED past twice that."""
     noise = _drawdown_sigma(history or [])
     tol = max(sigma_mult * noise, floor)
     return {"degraded_tolerance": round(tol, 4),

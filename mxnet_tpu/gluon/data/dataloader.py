@@ -19,9 +19,8 @@ fork-safe).  So the worker plane is **numpy-only**:
   PIL release the GIL during decode) for datasets that do hold device
   arrays; it is also the automatic fallback where fork is unavailable.
 
-tools/bench_dataloader.py measures the two modes against a decode-bound
-dataset; on an 8-core host the process pool clears the GIL ceiling the
-thread pool hits (see PERF.md).
+Against a decode-bound dataset on an 8-core host the process pool
+clears the GIL ceiling the thread pool hits.
 """
 from __future__ import annotations
 
@@ -163,8 +162,7 @@ class DataLoader:
             # AND pay off — the dataset must yield fork-safe (numpy/
             # python) samples, the collate must be the default (a custom
             # batchify_fn runs in the parent's jax world), and the host
-            # must have cores to spend (on a 1-core box threads win 3×,
-            # tools/bench_dataloader.py)
+            # must have cores to spend (on a 1-core box threads win 3×)
             import os
             thread_workers = (
                 (os.cpu_count() or 1) < 4

@@ -291,8 +291,8 @@ def _flash_candidates(kind: str, Tq: int, Tk: int, D: int,
 def tune_flash(q, k, v, causal: bool = True, kinds=("fwd", "bwd"),
                iters: int = 10, force: bool = False) -> Dict[str, tuple]:
     """Search flash block sizes for these exact operand shapes on the
-    current device and persist the winners.  Timing uses the bench.py
-    methodology (a timed call chain ended by block_until_ready).
+    current device and persist the winners.  Timing is a call chain
+    ended by block_until_ready.
     Returns ``{kind: (bq, bk)}``."""
     import jax
     import jax.numpy as jnp

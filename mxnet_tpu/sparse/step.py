@@ -24,8 +24,8 @@ full-table-sized gradient bytes through an all-reduce/all-gather (the
 "you densified your embedding grad" footgun) gets a warning report in
 the standard forensics dir before devices execute it.
 
-Used by ``bench.py`` (``BENCH_MODEL=recommender``), the 8-device dryrun
-compose check (``__graft_entry__._sparse_embedding_check``) and
+Used by the 8-device dryrun compose check
+(``__graft_entry__._sparse_embedding_check``) and
 ``tests/test_sparse_plane.py``.
 """
 from __future__ import annotations

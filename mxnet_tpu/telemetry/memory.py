@@ -564,7 +564,7 @@ def _hint(by_tag: Dict[str, int], prog_mem: Optional[dict]) -> str:
         "activations": "activations dominate: enable gradient remat "
                        "(backward_mirror_policy) or cut the microbatch",
         "batch": "input batches dominate: reduce the global batch or "
-                 "feed in chunks (the BENCH_IO superbatch pattern)",
+                 "feed in chunks",
         "optimizer": "optimizer state dominates: shard it over dp "
                      "(ShardedTrainer(shard_optimizer_state=True), "
                      "ZeRO-style)",

@@ -28,8 +28,9 @@ Wire-up (``MXNET_TPU_ATTRIBUTION=1``): every compiled entry point —
 — writes one report per distinct program into the watchdog/preflight
 report dir (``attribution-<name>-*.json``).  Each is attributed ONCE
 per (name, input signature); the hooks never raise into the entry
-point.  ``bench.py`` calls :func:`attribute_compiled` directly and
-embeds :func:`phases_block` in its JSON line.
+point.  A caller that holds a compiled step can call
+:func:`attribute_compiled` directly; :func:`phases_block` is the
+report's compact form.
 """
 from __future__ import annotations
 
@@ -641,14 +642,14 @@ def reset_attributed():
 
 
 # ---------------------------------------------------------------------------
-# bench integration
+# the report's compact form
 # ---------------------------------------------------------------------------
 
 def phases_block(report: AttributionReport,
                  report_path: Optional[str] = None) -> Dict:
-    """The compact ``phases`` block bench.py embeds in its JSON line so
-    every BENCH_* artifact is self-describing: roofline shares, MFU,
-    overlap, and where the full report lives."""
+    """The compact ``phases`` block of a report, for a JSON result
+    line: roofline shares, MFU, overlap, and where the full report
+    lives."""
     d = report.to_dict()
     roof = d.get("roofline", {})
     shares = roof.get("shares", {})

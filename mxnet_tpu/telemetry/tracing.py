@@ -478,10 +478,9 @@ def note_compile(name: str, seconds: float, **attrs):
 def compile_summary() -> dict:
     """``{"count", "total_seconds", "by_name": {name: seconds},
     "by_result": {result: count}}`` over every compile this process has
-    seen (bench.py attaches ``total_seconds`` to its JSON as the
-    ``compile_seconds`` metric).  ``by_result`` counts the compile-cache
-    outcome tags (``hit``/``miss``/``standby``/...; events predating the
-    cache count as ``untagged``) — the drills assert warmness from it."""
+    seen.  ``by_result`` counts the compile-cache outcome tags
+    (``hit``/``miss``/``standby``/...; events predating the cache count
+    as ``untagged``) — the drills assert warmness from it."""
     events = list(_COMPILES_LOCK_FREE)
     by_name: Dict[str, float] = {}
     by_result: Dict[str, int] = {}

@@ -2,8 +2,9 @@
 rules it stands on, and where the compile caches live).  The smoke itself
 only passes on the chip; here every piece of it that is a refusal is held
 to refusing."""
-import json
+import ast
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -74,11 +75,19 @@ def test_accelerator_contexts_raise_where_there_is_none():
     assert mx.context.num_tpus() == 0
 
 
-def test_bench_refuses_to_time_a_cpu():
-    r = _run(["bench.py"], env={"BENCH_MODEL": "decode"})
-    assert r.returncode not in (0, None)
-    assert "found no accelerator" in r.stderr
+def test_benchmark_refuses_to_time_a_cpu():
+    """The benchmark of record runs nothing where there is no TPU, and has
+    no peaks for a device kind nobody published any for."""
+    r = _run(["benchmark/run.py", "--workload", "gpt2s.serve-closed32",
+              "--seed", "1", "--seconds", "1", "--trace", "0"])
+    assert r.returncode == 2
+    assert "needs 1 TPU chip(s)" in r.stderr and "nothing was run" in r.stderr
     assert r.stdout.strip() == ""
+    from benchmark.lib import peaks
+    assert peaks.chip_peaks("TPU v5 lite")["flops"] == 197e12
+    for kind in ("cpu", "TPU v9000", ""):
+        with pytest.raises(KeyError, match="no published peaks"):
+            peaks.chip_peaks(kind)
 
 
 def test_launcher_refuses_tpu_ranks():
@@ -201,18 +210,49 @@ def test_own_caches_default_inside_the_checkout(monkeypatch):
         assert not p.startswith(os.path.join(home, ".cache")), p
 
 
+def _code_strings(path):
+    """Every string constant of a Python file that is not a docstring."""
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    docstrings = set()
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
+                             ast.AsyncFunctionDef)):
+            first = node.body[0] if node.body else None
+            if isinstance(first, ast.Expr) and isinstance(
+                    first.value, ast.Constant):
+                docstrings.add(id(first.value))
+    return tree, [n.value for n in ast.walk(tree)
+                  if isinstance(n, ast.Constant) and isinstance(n.value, str)
+                  and id(n) not in docstrings]
+
+
+def _python_files(*tops):
+    for top in tops:
+        for root, dirs, files in os.walk(os.path.join(REPO, top)):
+            dirs[:] = [d for d in dirs if d != "__pycache__"]
+            for name in files:
+                if name.endswith(".py"):
+                    yield os.path.join(root, name)
+
+
 def test_repo_ledger_never_uses_the_drivers_file_name():
-    """PERF_LEDGER.jsonl at the root is the PR driver's record; the repo's
-    own trajectory tooling takes an explicit path."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import benchwatch
-    with pytest.raises(SystemExit):
-        benchwatch.main(["check"])          # --ledger is required
-    fixture = os.path.join(REPO, "tests", "fixtures", "bench_ledger.jsonl")
-    assert benchwatch.main(["check", "--ledger", fixture]) == 0
-    for rel in ("tools/benchwatch.py", "bench.py",
-                "mxnet_tpu/analysis/predict.py", "tools/tpulint.py"):
-        with open(os.path.join(REPO, rel)) as f:
-            text = f.read()
-        assert 'PERF_LEDGER.jsonl"' not in text, rel
-    assert json.loads(open(fixture).readline())["source"] == "BENCH_r01"
+    """PERF_LEDGER.jsonl at the root is the PR driver's record and the only
+    one: no code of the library or the tools holds its name as a path, and
+    the library loads nothing from tools/ (a tool may import the library,
+    never the other way round)."""
+    for path in _python_files("mxnet_tpu", "tools"):
+        rel = os.path.relpath(path, REPO)
+        tree, strings = _code_strings(path)
+        assert not [s for s in strings if "PERF_LEDGER.jsonl" in s], rel
+        if not rel.startswith("mxnet_tpu"):
+            continue
+        for node in ast.walk(tree):
+            names = ([a.name for a in node.names]
+                     if isinstance(node, ast.Import) else
+                     [node.module or ""] if isinstance(node, ast.ImportFrom)
+                     and not node.level else [])
+            assert not [n for n in names if n.split(".")[0] == "tools"], rel
+        # a path component of its own, or a literal that is a tool's path
+        assert not [s for s in strings if s == "tools"
+                    or re.fullmatch(r"(.*/)?tools/\w+\.py", s)], rel

@@ -1,6 +1,5 @@
 """Compile-time plane tests: the persistent executable cache, the warm
-standby pre-compiler, corruption quarantine, and the promoted
-compile_seconds benchwatch gate (ROADMAP item 5 / PR 13).
+standby pre-compiler and corruption quarantine (ROADMAP item 5 / PR 13).
 
 The acceptance-level facts proven here at unit scale (the 4-proc drill
 in tests/dist/dist_elastic_resize.py proves them across real process
@@ -13,9 +12,7 @@ relaunches):
 * a corrupted cache entry (chaos ``corrupt_compile_cache``) quarantines
   and falls back to a fresh compile — never a crash, never a stale or
   wrong executable (donated programs are refused on backends whose
-  deserialize path would mis-execute them);
-* a compile-time IMPROVEMENT can never read as a benchwatch regression,
-  a compile-time blow-up fails the gate.
+  deserialize path would mis-execute them).
 """
 import glob
 import json
@@ -390,88 +387,6 @@ def test_autotune_trials_write_through_cache(armed, tmp_path, monkeypatch):
                              force=True, lower=lower)
     assert win2 == 2
     assert telemetry.counter_total("compile.cache", result="hit") == 2.0
-
-
-# ---------------------------------------------------------------------------
-# benchwatch: compile_seconds is a gated, lower-is-better metric
-# ---------------------------------------------------------------------------
-
-def _benchwatch():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import benchwatch
-    return benchwatch
-
-
-def test_benchwatch_compile_seconds_gate():
-    bw = _benchwatch()
-    assert bw.lower_is_better("compile_seconds")
-    assert bw.lower_is_better("transformer_compile_seconds")
-    assert not bw.lower_is_better("resnet50_train_img_per_sec_per_chip")
-    # an IMPROVEMENT (75s -> 2s after the cache landed) never regresses
-    r = bw.check_series([75.0, 71.0, 74.0, 2.1], lower=True)
-    assert r["checked"] and not r["regression"]
-    # a blow-up fails the gate
-    r = bw.check_series([75.0, 71.0, 74.0, 2.1, 90.0], lower=True)
-    assert r["regression"]
-    # the same series through the higher-is-better path would have
-    # called the improvement a 97% "drop" — the inversion is the point
-    r = bw.check_series([75.0, 71.0, 74.0, 2.1], lower=False)
-    assert r["regression"]
-
-
-def test_benchwatch_extracts_and_merges_compile_seconds(tmp_path):
-    bw = _benchwatch()
-    doc = {"metric": "resnet", "value": 100.0,
-           "phases": {"compile_seconds": 42.5, "peak_hbm_bytes": 1000},
-           "transformer": {"metric": "transformer", "value": 5.0,
-                           "phases": {"compile_seconds": 7.25}}}
-    metrics = bw.extract_metrics(doc)
-    assert metrics["compile_seconds"] == 42.5
-    assert metrics["transformer_compile_seconds"] == 7.25
-    assert "compile_seconds" not in bw.extract_extra(doc)
-    # legacy rounds that recorded compile_seconds as an ungated extra
-    # feed the same gated series
-    ledger = str(tmp_path / "ledger.jsonl")
-    bw.append_entry(ledger, {"resnet": 100.0},
-                    extra={"compile_seconds": 70.0})
-    bw.append_entry(ledger, {"resnet": 101.0},
-                    extra={"compile_seconds": 72.0})
-    bw.append_entry(ledger, {"resnet": 99.5, "compile_seconds": 2.0})
-    entries = bw.read_ledger(ledger)
-    series = bw.metric_series(entries)
-    assert series["compile_seconds"] == [70.0, 72.0, 2.0]
-    ok, results = bw.check_ledger(entries)
-    assert ok, results                   # the improvement gates green
-    bw.append_entry(ledger, {"resnet": 100.0, "compile_seconds": 95.0})
-    ok, results = bw.check_ledger(bw.read_ledger(ledger))
-    assert not ok and results["compile_seconds"]["regression"]
-
-
-def test_benchwatch_single_excursion_uses_floor_band():
-    """One bad round in an otherwise-flat history used to widen the σ
-    band to 4x its own drawdown and wave the next regression through;
-    a single excursion now contributes no σ and the 5% floor gates."""
-    bw = _benchwatch()
-    assert bw.drawdown_sigma([100.0, 60.0]) == 0.0
-    assert bw.rise_sigma([60.0, 100.0]) == 0.0
-    # flat-then-drop: the 5% floor (not a self-sized band) catches it
-    r = bw.check_series([100.0, 100.0, 92.0])
-    assert r["checked"] and r["regression"]
-    assert r["band_basis"] == "floor"
-    # a genuinely noisy series still gets the wider σ band
-    noisy = bw.check_series([100.0, 80.0, 110.0, 75.0, 105.0, 75.0])
-    assert noisy["band_basis"] == "sigma"
-    assert not noisy["regression"]
-    # the too-short series contract is unchanged (and basis-free)
-    assert bw.check_series([1.0]) == {"checked": False,
-                                      "regression": False, "n": 1}
-
-
-def test_recorded_ledger_still_green():
-    bw = _benchwatch()
-    ok, results = bw.check_ledger(bw.read_ledger(
-        os.path.join(REPO, "tests", "fixtures", "bench_ledger.jsonl")))
-    assert ok, results
 
 
 # ---------------------------------------------------------------------------

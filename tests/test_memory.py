@@ -418,45 +418,6 @@ def test_predicted_peak_bytes_donation_accounting():
 
 
 # ---------------------------------------------------------------------------
-# benchwatch: peak HBM recorded (extra block), never gated
-# ---------------------------------------------------------------------------
-
-def _load_benchwatch():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "benchwatch_t7", os.path.join(REPO, "tools", "benchwatch.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_benchwatch_records_peak_hbm_as_ungated_extra(tmp_path):
-    bw = _load_benchwatch()
-    doc = {"metric": "resnet50_train_img_per_sec_per_chip", "value": 2000.0,
-           "phases": {"bound": "hbm", "peak_hbm_bytes": 7_000_000_000},
-           "transformer": {"metric": "transformer_train_tokens_per_sec"
-                                     "_per_chip", "value": 90000.0,
-                           "phases": {"peak_hbm_bytes": 5_000_000_000}}}
-    assert bw.extract_extra(doc) == {
-        "peak_hbm_bytes": 7_000_000_000,
-        "transformer_peak_hbm_bytes": 5_000_000_000}
-    ledger = str(tmp_path / "ledger.jsonl")
-    bw.append_entry(ledger, bw.extract_metrics(doc), source="t",
-                    extra=bw.extract_extra(doc))
-    # a later round where throughput holds but peak HBM DROPS (an
-    # improvement) must not read as a regression: extras are not gated
-    doc2 = dict(doc, phases={"peak_hbm_bytes": 3_000_000_000})
-    bw.append_entry(ledger, bw.extract_metrics(doc2), source="t",
-                    extra=bw.extract_extra(doc2))
-    entries = bw.read_ledger(ledger)
-    assert entries[0]["extra"]["peak_hbm_bytes"] == 7_000_000_000
-    assert entries[1]["extra"]["peak_hbm_bytes"] == 3_000_000_000
-    ok, results = bw.check_ledger(entries)
-    assert ok, results
-    assert not any("hbm" in name for name in results)
-
-
-# ---------------------------------------------------------------------------
 # memwatch live-tail rendering (the gauge console)
 # ---------------------------------------------------------------------------
 

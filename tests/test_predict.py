@@ -1,7 +1,7 @@
 """Prediction-conformance plane tests (ISSUE 20).
 
 Covers the calibration store (roundtrip, running-mean updates, fallback
-ladder, ledger fitting), pre-flight budgets + env-limit gating, the
+ladder), pre-flight budgets + env-limit gating, the
 conformance verdict bands, the CI-gated prediction-agreement loop for
 the trainer and ring entry points, input-bound detection on a genuinely
 starved toy run, and the fleet-level drill where a rank slow against its
@@ -99,29 +99,6 @@ def test_achievable_fraction_fallback_ladder():
     miss = predict.achievable_fraction(store, "gpu", "compute")
     assert miss["fraction"] == predict.DEFAULT_FRACTION
     assert miss["source"] == "default" and miss["n"] == 0
-
-
-def test_fit_from_ledger_fixture_and_synthetic(tmp_path):
-    # the recorded trajectory must yield a usable compute fraction
-    store = predict.fit_from_ledger(
-        os.path.join(REPO, "tests", "fixtures", "bench_ledger.jsonl"),
-        kind="cpu")
-    e = store["entries"]["cpu|compute"]
-    assert 0.0 < e["achievable_fraction"] <= 1.0
-    assert e["source"] == "ledger" and e["n"] >= 1
-    # synthetic ledger: median of the *_mfu metrics, junk rows ignored
-    path = tmp_path / "ledger.jsonl"
-    rows = [{"metrics": {"train_mfu": 0.30}},
-            {"metrics": {"train_mfu": 0.40}},
-            {"metrics": {"decode_mfu": 0.50}},
-            {"metrics": {"train_mfu": 0.0}},      # not a real sample
-            {"metrics": {"tokens_per_sec": 9e9}},  # not an mfu
-            {"not": "json-with-metrics"}]
-    path.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    store2 = predict.fit_from_ledger(str(path), kind="x")
-    e2 = store2["entries"]["x|compute"]
-    assert e2["achievable_fraction"] == pytest.approx(0.40)
-    assert e2["n"] == 3
 
 
 # ---------------------------------------------------------------------------
@@ -379,7 +356,7 @@ def test_input_starved_run_reads_bound_input(tmp_path, monkeypatch):
     assert d["roofline"]["bound"] == "input"
     assert d["roofline"]["input_share"] > 0.5
     assert d["step"]["io_s"] == pytest.approx(0.05, rel=0.5)
-    # the bench/servebench mirror: phases_block carries the verdict too
+    # the compact form mirrors it: phases_block carries the verdict too
     rep = perf.AttributionReport.load(
         os.path.join(str(tmp_path), reports[0]))
     block = perf.phases_block(rep, "r.json")

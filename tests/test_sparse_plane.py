@@ -141,6 +141,18 @@ def test_lookup_dedup_bounds_hot_row_load():
 # sharded lazy updates: bit-parity with the host reference
 # ---------------------------------------------------------------------------
 
+def _assert_matches_host(got, ref):
+    # Not bit for bit on the installed jax: XLA folds `segment_sum(rows) +
+    # wd * w` into one scatter-add that starts from `wd * w`, so a row's
+    # contributions (one per sender, up to 8) are added onto it one at a
+    # time with a rounding each, where the host sums them exactly and adds
+    # once.  Eight half-ulps at the running sum's scale (under 2^-4 at
+    # these sizes) bound the gradient's error by 2^-25; every factor from
+    # there to a compared value is under 1.  Measured: 1.9e-9 at most.  A
+    # lost or doubled contribution is 2^-10 in the gradient.
+    np.testing.assert_allclose(got, ref, rtol=0, atol=2.0 ** -25)
+
+
 def _host_sgd(w0, ids, grads, V, **kw):
     w_nd = mx.nd.array(w0.copy())
     m_nd = mx.nd.zeros(w0.shape)
@@ -166,8 +178,8 @@ def test_lazy_sgd_bit_matches_host_reference():
                                wd=0.0078125)
         ref_w, ref_m = _host_sgd(np.asarray(table)[:V], ids, grads, V,
                                  lr=0.5, momentum=0.5, wd=0.0078125)
-        np.testing.assert_array_equal(np.asarray(t2)[:V], ref_w)
-        np.testing.assert_array_equal(np.asarray(m2)[:V], ref_m)
+        _assert_matches_host(np.asarray(t2)[:V], ref_w)
+        _assert_matches_host(np.asarray(m2)[:V], ref_m)
 
 
 def test_lazy_sgd_arbitrary_hypers_roundoff():
@@ -232,9 +244,9 @@ def test_lazy_adam_bit_matches_host_reference():
     sp.adam_row_sparse_update(
         w_nd, sp.embedding_grad(ids, mx.nd.array(grads), V), me_nd, va_nd,
         **kw)
-    np.testing.assert_array_equal(np.asarray(t2)[:V], w_nd.asnumpy())
-    np.testing.assert_array_equal(np.asarray(me2)[:V], me_nd.asnumpy())
-    np.testing.assert_array_equal(np.asarray(va2)[:V], va_nd.asnumpy())
+    _assert_matches_host(np.asarray(t2)[:V], w_nd.asnumpy())
+    _assert_matches_host(np.asarray(me2)[:V], me_nd.asnumpy())
+    _assert_matches_host(np.asarray(va2)[:V], va_nd.asnumpy())
 
 
 def test_update_touches_only_active_rows():

@@ -22,8 +22,7 @@ Measures, on the real TPU:
 the persisted cache come from the same run.
 
 Prints one JSON line per measurement.  Timing: warmup, then a timed
-chain of `iters` calls ended by block_until_ready (the bench.py
-methodology).
+chain of `iters` calls ended by block_until_ready.
 """
 import argparse
 import functools
@@ -40,6 +39,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmark.lib import flops, peaks  # noqa: E402
 
 
 def timed(fn, args, iters=50, warmup=5):
@@ -130,22 +131,19 @@ def planned_bytes(fn, args):
                + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-# bf16 matrix peak by ``device_kind`` (Google Cloud documentation, "TPU
-# v5e"); a copy of benchmark/lib/peaks.py, which this tool does not import
-PEAK_FLOPS = {"TPU v5 lite": 197e12}
-
-# The matrix products the algorithm needs of each kernel, by the formula of
-# benchmark/lib/flops.py::flash_train_flops_bytes (copied, not imported):
-# 2 FLOPs a multiply-add over the causal T(T+1)/2 pairs, the forward's two
-# products and the backward's four (dP, dQ; dV, dK); the scores a backward
-# kernel recomputes are not counted.
+# The matrix products the algorithm needs of each kernel, out of the six
+# that benchmark/lib/flops.py::flash_train_flops_bytes counts for a layer
+# (2 FLOPs a multiply-add over the causal T(T+1)/2 pairs): the forward's two
+# and the backward's four (dP, dQ; dV, dK); the scores a backward kernel
+# recomputes are not counted.
 COUNTED_PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
                     "flash_bwd": 4}
 
 
 def least_seconds(kernel, B, T, H, D, peak):
-    pairs = T * (T + 1) // 2
-    return COUNTED_PRODUCTS[kernel] * 2 * B * pairs * H * D / peak
+    layer, _bytes = flops.flash_train_flops_bytes(
+        {"n_embd": H * D, "n_layer": 1}, B, T)
+    return COUNTED_PRODUCTS[kernel] * layer // 6 / peak
 
 
 def kernel_seconds(fn, args, iters=10):
@@ -185,7 +183,8 @@ def kernel_seconds(fn, args, iters=10):
 def report_kernels(fn, args, B, T, H, D):
     """One line: each flash kernel's device milliseconds a call, and its
     share of the least time its counted products need on this chip."""
-    peak = PEAK_FLOPS.get(jax.devices()[0].device_kind)
+    peak = peaks.CHIP_PEAKS.get(jax.devices()[0].device_kind,
+                                {}).get("flops")
     row = {"metric": "flash_kernel_ms", "T": T, "B": B, "H": H, "D": D}
     for kernel, seconds in sorted(kernel_seconds(fn, args).items()):
         row[kernel] = {"ms": round(seconds * 1e3, 4)}

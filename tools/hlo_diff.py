@@ -1,19 +1,12 @@
 #!/usr/bin/env python
-"""Dump + compare the optimized HLO of the framework train step
-(bench.py's exact program) vs the hand-written ideal
-(tools/bench_ideal.py).  Prints per-program op histograms and their
-diff — the evidence base for PERF.md's framework-vs-ideal analysis.
+"""Compare the optimized HLO of a program a graphcheck pre-flight flagged
+with its fixed variant, by per-program op histograms and their diff.
 
 Usage:
-    python tools/hlo_diff.py [batch]
-        classic mode — dump the ResNet-50 step, diff against the ideal
-        (BENCH_DUMP_HLO in bench_ideal.py); writes
-        /tmp/hlo_framework_bs{N}.txt
-
     python tools/hlo_diff.py --from-graphcheck REPORT.json \\
                              [--against OTHER.json|HLO.txt]
-        pre-flight mode — take the HLO artifact recorded in a graphcheck
-        pre-flight report (run training once with MXNET_TPU_PREFLIGHT=1
+        take the HLO artifact recorded in a graphcheck pre-flight report
+        (run training once with MXNET_TPU_PREFLIGHT=1
         MXNET_TPU_PREFLIGHT_HLO=1 to produce it) and diff it against a
         second report's artifact or a raw HLO text file.  This is how a
         flagged program is compared with its fixed variant WITHOUT
@@ -26,9 +19,6 @@ import os
 import re
 import sys
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                ".."))
-
 
 def histogram(path):
     ops = collections.Counter()
@@ -37,34 +27,6 @@ def histogram(path):
         if m:
             ops[m.group(1)] += 1
     return ops
-
-
-def dump_framework(batch):
-    import jax
-    import jax.numpy as jnp
-    import mxnet_tpu  # noqa: F401
-    from mxnet_tpu.models.resnet import get_symbol
-    from mxnet_tpu.parallel.mesh import MeshSpec, make_mesh
-    from mxnet_tpu.parallel.trainer import ShardedTrainer, sgd_step_fn
-
-    sym = get_symbol(num_classes=1000, num_layers=50,
-                     image_shape="3,224,224", dtype="bfloat16")
-    spec = MeshSpec(make_mesh((1,), ("dp",)))
-    trainer = ShardedTrainer(sym, spec, lr=0.1, momentum=0.9, wd=1e-4,
-                             param_dtype="bfloat16")
-    shapes = {"data": (batch, 3, 224, 224), "softmax_label": (batch,)}
-    params, mom, aux = trainer.init_state(shapes)
-    step = sgd_step_fn(trainer)
-    keys = trainer._keys()
-    data = jnp.zeros((batch, 3, 224, 224), jnp.float32)
-    label = jnp.zeros((batch,), jnp.float32)
-    lowered = step.lower(params, mom, aux,
-                         {"data": data, "softmax_label": label}, keys,
-                         trainer._guard_arrays())
-    txt = lowered.compile().as_text()
-    path = "/tmp/hlo_framework_bs%d.txt" % batch
-    open(path, "w").write(txt)
-    return path
 
 
 def hlo_from_report(path):
@@ -101,32 +63,28 @@ def print_diff(path_a, path_b, label_a, label_b):
 
 def main():
     argv = sys.argv[1:]
-    if "--from-graphcheck" in argv:
-        i = argv.index("--from-graphcheck")
-        report = argv[i + 1] if i + 1 < len(argv) else None
-        if not report:
-            raise SystemExit("--from-graphcheck needs a report path")
-        flagged = hlo_from_report(report)
-        against = None
-        if "--against" in argv:
-            j = argv.index("--against")
-            if j + 1 >= len(argv):
-                raise SystemExit("--against needs a report/HLO path")
-            against = hlo_from_report(argv[j + 1])
-        if against is None:
-            h = histogram(flagged)
-            print("%-28s %10s" % ("op", "count"))
-            for op, n in h.most_common():
-                print("%-28s %10d" % (op, n))
-            print("\ntotal lines: %d"
-                  % len(open(flagged).read().splitlines()))
-        else:
-            print_diff(flagged, against, "flagged", "fixed")
-        return
-    batch = int(argv[0]) if argv else 32
-    fw = dump_framework(batch)
-    ideal = "/tmp/hlo_ideal_bs%d.txt" % batch
-    print_diff(fw, ideal, "framework", "ideal")
+    if "--from-graphcheck" not in argv:
+        raise SystemExit(__doc__)
+    i = argv.index("--from-graphcheck")
+    report = argv[i + 1] if i + 1 < len(argv) else None
+    if not report:
+        raise SystemExit("--from-graphcheck needs a report path")
+    flagged = hlo_from_report(report)
+    against = None
+    if "--against" in argv:
+        j = argv.index("--against")
+        if j + 1 >= len(argv):
+            raise SystemExit("--against needs a report/HLO path")
+        against = hlo_from_report(argv[j + 1])
+    if against is None:
+        h = histogram(flagged)
+        print("%-28s %10s" % ("op", "count"))
+        for op, n in h.most_common():
+            print("%-28s %10d" % (op, n))
+        print("\ntotal lines: %d"
+              % len(open(flagged).read().splitlines()))
+    else:
+        print_diff(flagged, against, "flagged", "fixed")
 
 
 if __name__ == "__main__":

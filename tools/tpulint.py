@@ -223,13 +223,7 @@ def _predict_builtin():
 
     n = min(2, jax.device_count())
     mesh = make_mesh((n,), ("dp",))
-    # with BENCH_LEDGER naming a benchwatch trajectory, one calibration
-    # pass against it gives the budgets a fitted fraction even on a box
-    # that never ran telemetry
     store = predict.load_store()
-    if os.environ.get("BENCH_LEDGER"):
-        store = predict.fit_from_ledger(os.environ["BENCH_LEDGER"], store)
-        predict.save_store(store)
     reports = []
 
     def run(tag, fn):
