@@ -31,7 +31,10 @@ split (PAPERS.md): a decode loop whose per-token step
   page pool, prefill chunked into the running batch one token per step,
   admission-queue priorities/eviction and deadlines preserved (a
   retired or evicted sequence can never late-OK: the Request future is
-  one-shot);
+  one-shot); the loop keeps one step in flight — step n+1 is dispatched
+  before step n's tokens are fetched, a decoding slot's next token fed
+  forward on the device (``prev_tok``) — so the host's work hides
+  behind the device's;
 * optionally serves **weight-only quantized** matmuls (int8 / packed
   int4, per-channel scales, dequantization fused in the kernel —
   :func:`~mxnet_tpu.ops.pallas_kernels.quant_matmul`), selected at
@@ -334,6 +337,15 @@ class DecodeProgram:
         self.trace_count = 0          # bumps INSIDE the traced step: the
         # compile-once oracle (a retrace is a bug, not a slow path)
         self._jit_step = self._make_jit_step()
+        # what the step gets as prev_tok from a caller that has none;
+        # under a mesh, placed as the step's own next_tokens come back
+        # (replicated), or handing those in would be a second executable
+        self._no_prev_tok = np.zeros(config.max_seqs, np.int32)
+        if self.spec is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._no_prev_tok = jax.device_put(
+                self._no_prev_tok,
+                NamedSharding(self.spec.mesh, PartitionSpec()))
         self._compiled = False
         self._compile_lock = threading.Lock()
         # generic program surface (schema checks, canary, fleet batch
@@ -490,7 +502,7 @@ class DecodeProgram:
         _pool_ops_pallas = (pk.kv_write, pk.decode_attention_pool)
 
         def step(params, kv, tokens, positions, seq_lens, phys, off,
-                 page_table):
+                 page_table, prev_tok=None):
             # ONE trace, ever: shapes are fixed by the config, token
             # positions/lengths/page indices are all data (GC307)
             if count:
@@ -509,6 +521,10 @@ class DecodeProgram:
             # no layer index in them, so the layers group in a trace
             scope = jax.named_scope
             with scope("mx.decode.embed"):
+                if prev_tok is not None:
+                    # a negative token stands for "the one the last step
+                    # produced for this slot", which never left the device
+                    tokens = jnp.where(tokens < 0, prev_tok, tokens)
                 x = params["tok_embed_weight"][tokens] \
                     + params["pos_embed"][positions]      # (S, hidden)
             for i in range(c.num_layers):
@@ -568,13 +584,20 @@ class DecodeProgram:
                 np.zeros((S, c.pages_per_seq), i32))
 
     def step(self, kv, tokens, positions, seq_lens, phys, off,
-             page_table):
+             page_table, prev_tok=None):
         """One decode step for every slot; returns ``(next_tokens,
         logits, kv')``.  ``kv`` is DONATED — the caller must thread the
-        returned pool into the next call."""
+        returned pool into the next call.  Where ``tokens[i]`` is
+        negative the slot is fed ``prev_tok[i]``: hand in the last step's
+        ``next_tokens`` as it came back and a decoding slot's token need
+        not pass through the host (:class:`DecodeEngine`).  The jitted
+        step always gets the array (zeros when the caller has none), so
+        there is one trace and one executable either way."""
         self.ensure_compiled()
+        if prev_tok is None:
+            prev_tok = self._no_prev_tok
         return self._jit_step(self._params, kv, tokens, positions,
-                              seq_lens, phys, off, page_table)
+                              seq_lens, phys, off, page_table, prev_tok)
 
     def ensure_compiled(self):
         """Compile the step once, visibly: the first build rides a
@@ -592,7 +615,8 @@ class DecodeProgram:
                                 metric="compile.seconds", timed=True,
                                 program=self.name) as sp:
                 out = self._jit_step(self._params, kv,
-                                     *self._zero_step_args())
+                                     *self._zero_step_args(),
+                                     self._no_prev_tok)
             import jax
             jax.block_until_ready(out[0])
             telemetry.tracing.note_compile("decode_step", sp.duration,
@@ -733,7 +757,28 @@ class _Slot:
     def __init__(self, req: DecodeRequest, pages: List[int]):
         self.req = req
         self.pages = pages
-        self.pos = 0              # tokens fed so far (prompt + generated)
+        # tokens fed so far (prompt + generated), counting every step
+        # DISPATCHED for the slot, fetched or not
+        self.pos = 0
+
+
+class _InFlight:
+    """One dispatched step whose tokens the host has not taken in yet."""
+
+    __slots__ = ("seq", "takers", "attended", "overlapped", "next_tok",
+                 "guards", "t_dispatch")
+
+    def __init__(self, seq, takers, attended, overlapped):
+        self.seq = seq
+        # (slot index, request, takes a token?, its last by length?) of
+        # every slot the step ran for: the REQUEST, because by the time
+        # the tokens arrive the slot may be somebody else's
+        self.takers = takers
+        self.attended = attended
+        self.overlapped = overlapped    # dispatched behind another step
+        self.next_tok = None            # the step's out[0], as handed back
+        self.guards = None              # watchdog watch + OOM guard, open
+        self.t_dispatch = time.perf_counter()
 
 
 class DecodeEngine(ServingRuntime):
@@ -749,7 +794,24 @@ class DecodeEngine(ServingRuntime):
     running batch one token per step, so a long prompt never stalls
     other tenants' token cadence.  Admission, breaker, watchdog-armed
     dispatch, and the one-shot Request future (no late OKs, ever) are
-    inherited from :class:`ServingRuntime`."""
+    inherited from :class:`ServingRuntime`.
+
+    The loop keeps **one step in flight**: iteration k dispatches step k
+    and only then fetches step k-1's tokens, so the device always has its
+    next step queued and the host's admit / build / dispatch / retire and
+    the fetch's latency run beside the device's work.  What makes that
+    possible: everything a step needs but a decoding slot's token follows
+    from ``slot.pos``, which advances at dispatch; the token itself stays
+    on the device (``tokens[i] = -1`` and the last step's ``next_tokens``
+    handed to :meth:`DecodeProgram.step` as ``prev_tok``).  Bookkeeping
+    therefore happens twice a step.  At dispatch: positions advance, the
+    step's :class:`_InFlight` record notes which request sat in which
+    slot, and a sequence that ends by length gives up its slot and pages
+    at once.  At fetch, one iteration later: tokens go to the record's
+    requests, counters and stamps are taken, futures settle.  A request
+    found settled by then (swept, evicted, cancelled, failed, or ended on
+    ``eos_id``, which is only seen a step late) has its token dropped,
+    uncounted."""
 
     def __init__(self, program, *, max_new_default=None, **kw):
         prog = self._load_program(program)
@@ -760,6 +822,9 @@ class DecodeEngine(ServingRuntime):
         self._slots: List[Optional[_Slot]] = [None] * c.max_seqs
         self._pool = PagePool(c.pool_pages())
         self._kv = None
+        self._flight: Optional[_InFlight] = None
+        self._prev_tok = None     # out[0] of the last step dispatched
+        self._t_fetched = 0.0     # perf_counter at the last fetch's return
         self._table = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
         self._max_new_default = int(
             max_new_default if max_new_default is not None
@@ -843,15 +908,20 @@ class DecodeEngine(ServingRuntime):
         self._table[idx, :] = 0
         self._pool.free(slot.pages)
 
-    def _retire(self, idx: int, error: Optional[BaseException] = None):
-        """Retire one slot: settle its future exactly once (the loser of
-        the race is a no-op — a retired or evicted sequence can never
-        late-OK), free its pages."""
+    def _retire(self, idx: int, error: BaseException):
+        """Fail one running sequence: free its slot and pages, settle its
+        future.  A step in flight for it finds it settled and drops its
+        token."""
         slot = self._slots[idx]
         if slot is None:
             return
-        req = slot.req
         self._release_slot(idx)
+        self._settle(slot.req, error)
+
+    def _settle(self, req: DecodeRequest,
+                error: Optional[BaseException] = None):
+        """Settle a request's future exactly once (the loser of the race
+        is a no-op — a retired or evicted sequence can never late-OK)."""
         now = time.monotonic()
         req.t_exec_done = now
         delivered = False
@@ -959,134 +1029,230 @@ class DecodeEngine(ServingRuntime):
                     sp.annotate(admitted=self._counters["admitted_slots"]
                                 - before)
                 active = self._active()
-                if not active:
+                if active and self._breaker.dispatch_ok():
+                    self._engine_step(active)
+                elif self._flight is not None:
+                    # nothing to dispatch behind the step in flight (every
+                    # sequence's last step is out, or the circuit is
+                    # open): take it in now, not after a sleep
+                    self._drain()
+                elif active:
+                    time.sleep(0.02)
+                else:
                     req = self._queue.pop_live(timeout=0.05)
                     if req is not None:
                         self._queue.push_front(req)
-                    continue
-                if not self._breaker.dispatch_ok():
-                    time.sleep(0.02)
-                    continue
-                self._engine_step(active)
             except Exception:
                 if not self._stop:
                     raise
                 return
 
     def _engine_step(self, active: List[int]):
-        c = self._program.config
+        """Dispatch one step for ``active``, then take in the step that
+        was in flight while it was built."""
+        with self._lock:
+            self._batch_seq += 1
+            seq = self._batch_seq
+            prog = self._program
+        c = prog.config
         S = c.max_seqs
+        flight = self._flight
         with telemetry.span("serve/build", cat="serve", slots=len(active)):
             tokens = np.zeros(S, np.int32)
             positions = np.zeros(S, np.int32)
             seq_lens = np.zeros(S, np.int32)
             phys = np.zeros(S, np.int32)      # inactive -> trash page 0
             off = np.zeros(S, np.int32)
-            feeding = 0                       # slots still taking prompt
+            takers = []
             for i in active:
                 slot = self._slots[i]
                 req = slot.req
+                # past the prompt the token is the last step's, on the
+                # device: -1 takes prev_tok[i]
                 tokens[i] = (req.prompt[slot.pos]
-                             if slot.pos < req.n_prompt
-                             else req.generated[-1])
+                             if slot.pos < req.n_prompt else -1)
                 positions[i] = slot.pos
                 seq_lens[i] = slot.pos + 1
                 phys[i] = slot.pages[slot.pos // c.page_size]
                 off[i] = slot.pos % c.page_size
-                feeding += slot.pos + 1 < req.n_prompt
-            attended = int(seq_lens.sum())
-        with self._lock:
-            self._batch_seq += 1
-            seq = self._batch_seq
-            prog = self._program
-        armed = (contextlib.nullcontext()
-                 if self._exec_timeout is None else
-                 self._ensure_watchdog().watch(
-                     "%s.step" % self._name, kind="step", step=seq,
-                     timeout=self._exec_timeout))
-        try:
-            # the span is the outermost of the three, so that arming the
-            # watchdog and the OOM guard are host time a trace can name
-            with telemetry.span(
-                    "serve/decode_step", cat="serve", timed=True,
-                    batch=seq, slots=len(active), n_prefill=feeding,
-                    n_decode=len(active) - feeding,
-                    attended=attended) as sp, armed, \
-                    telemetry.memory.oom_guard(
-                        "%s.step" % self._name, step=seq):
-                chaos.maybe_exec_error(seq)
-                chaos.maybe_slow_exec(seq)
-                chaos.maybe_replica_crash(seq)
-                chaos.maybe_hedge_lag(seq)
-                with telemetry.span("serve/dispatch", cat="serve"):
-                    next_tok, _logits, kv = prog.step(
-                        self._kv, tokens, positions, seq_lens, phys, off,
-                        self._table)
-                with telemetry.span("serve/fetch", cat="serve"):
-                    next_np = np.asarray(next_tok)
-        except Exception as e:
-            # the pool was DONATED into a step that died: state is
-            # unknown, so fail every running sequence (typed) and start
-            # from a fresh pool — degraded, never wrong
-            self._breaker.record_failure()
-            with self._lock:
-                self._counters["exec_failures"] += 1
-            telemetry.count("serve.exec_failures")
-            err = ExecFailed("decode step failed: %r" % (e,))
-            for i in list(active):
-                req = self._slots[i].req if self._slots[i] else None
-                if req is not None and req.expired():
-                    self._retire(i, DeadlineExceeded(
-                        "deadline passed while the step was failing"))
-                else:
-                    self._retire(i, err)
-            self._kv = prog.fresh_cache()
-            return
-        self._kv = kv
-        self._breaker.record_success()
-        step_time = sp.duration
-        with telemetry.span("serve/retire", cat="serve") as rsp:
-            n_prefill = n_decode = n_retired = 0
-            now = time.monotonic()
-            for i in active:
-                slot = self._slots[i]
-                if slot is None:
-                    continue
-                req = slot.req
                 slot.pos += 1
-                if slot.pos < req.n_prompt:
-                    n_prefill += 1
-                    continue
-                n_decode += 1
-                tok = int(next_np[i])
-                req.generated.append(tok)
-                if req.token_times:
-                    self._itl_hist.observe(now - req.token_times[-1])
-                else:
-                    self._ttft_hist.observe(now - req.enqueued_at)
-                req.token_times.append(now)
-                done = (len(req.generated) >= req.max_new
-                        or (c.eos_id is not None and tok == c.eos_id)
-                        or slot.pos >= c.max_seq_len)
-                if done:
-                    self._retire(i)
-                    n_retired += 1
-            with self._lock:
-                self._exec_ewma = (step_time if self._exec_ewma == 0.0 else
-                                   0.8 * self._exec_ewma + 0.2 * step_time)
-                self._counters["steps"] += 1
-                self._counters["tokens_prefilled"] += n_prefill
-                self._counters["tokens_decoded"] += n_decode
-                self._counters["contexts_attended"] += attended
-            self._exec_hist.observe(step_time)
-            self._occ_hist.observe(len(active) / float(S))
-            telemetry.count("decode.tokens", float(n_decode), kind="decode")
-            if n_prefill:
-                telemetry.count("decode.tokens", float(n_prefill),
-                                kind="prefill")
-            telemetry.window_tick()
-            telemetry.memory.note_step(seq)
-            rsp.annotate(retired=n_retired)
+                takes = slot.pos >= req.n_prompt
+                last = takes and (
+                    slot.pos - req.n_prompt + 1 >= req.max_new
+                    or slot.pos >= c.max_seq_len)
+                takers.append((i, req, takes, last))
+            # the table as this step saw it: releases and admissions
+            # rewrite self._table while the step may still be reading
+            table = self._table.copy()
+            # a sequence whose last step this is needs no other: its slot
+            # and pages go to the next admission now (the device runs
+            # steps in order, so a new owner's step writes a page only
+            # after this one has read it); its future waits for the token
+            for i, _req, _takes, last in takers:
+                if last:
+                    self._release_slot(i)
+            n_decode = sum(t[2] for t in takers)
+            new = _InFlight(seq, takers, int(seq_lens.sum()),
+                            flight is not None)
+        try:
+            # the span is the outermost, so that arming the watchdog and
+            # the OOM guard are host time a trace can name; the two cover
+            # the step from here to its fetch, one iteration on
+            with telemetry.span(
+                    "serve/decode_step", cat="serve", batch=seq,
+                    slots=len(active), n_prefill=len(active) - n_decode,
+                    n_decode=n_decode, attended=new.attended,
+                    in_flight=int(new.overlapped)):
+                with contextlib.ExitStack() as guards:
+                    if self._exec_timeout is not None:
+                        guards.enter_context(self._ensure_watchdog().watch(
+                            "%s.step" % self._name, kind="step", step=seq,
+                            timeout=self._exec_timeout))
+                    guards.enter_context(telemetry.memory.oom_guard(
+                        "%s.step" % self._name, step=seq))
+                    chaos.maybe_exec_error(seq)
+                    chaos.maybe_slow_exec(seq)
+                    chaos.maybe_replica_crash(seq)
+                    chaos.maybe_hedge_lag(seq)
+                    with telemetry.span("serve/dispatch", cat="serve"):
+                        next_tok, _logits, self._kv = prog.step(
+                            self._kv, tokens, positions, seq_lens, phys,
+                            off, table, self._prev_tok)
+                        # what the step handed back, and nothing kept
+                        # elsewhere, is what the next step is fed
+                        self._prev_tok = new.next_tok = next_tok
+                        start_copy = getattr(next_tok,
+                                             "copy_to_host_async", None)
+                        if start_copy is not None:
+                            start_copy()
+                    new.guards = guards.pop_all()
+                self._flight = new
+                with telemetry.span("serve/fetch", cat="serve"):
+                    next_np = (None if flight is None
+                               else self._fetch(flight))
+        except Exception as e:
+            self._step_failed(e, (flight, new))
+            return
+        self._take_in(flight, next_np)
+
+    def _drain(self):
+        """Take in the step in flight with none dispatched behind it."""
+        flight, self._flight = self._flight, None
+        try:
+            with telemetry.span("serve/fetch", cat="serve"):
+                next_np = self._fetch(flight)
+        except Exception as e:
+            self._step_failed(e, (flight,))
+            return
+        self._take_in(flight, next_np)
+
+    @staticmethod
+    def _fetch(flight: _InFlight) -> np.ndarray:
+        """Wait for a step's tokens; its watchdog watch and OOM guard,
+        open since its dispatch, close here (on an error, with it)."""
+        with flight.guards:
+            return np.asarray(flight.next_tok)
+
+    def _step_failed(self, e: BaseException, records):
+        """A dispatch or a fetch raised.  The pool was DONATED into a
+        step that died: state is unknown, so fail every running sequence
+        (typed), drop what is in flight and start from a fresh pool —
+        degraded, never wrong."""
+        self._breaker.record_failure()
+        with self._lock:
+            self._counters["exec_failures"] += 1
+        telemetry.count("serve.exec_failures")
+        err = ExecFailed("decode step failed: %r" % (e,))
+        for req in self._abandon(records):
+            self._settle(req, DeadlineExceeded(
+                "deadline passed while the step was failing")
+                if req.expired() else err)
+        self._kv = self._program.fresh_cache()
+
+    def _abandon(self, records) -> List[DecodeRequest]:
+        """Free every slot and drop ``records`` (steps dispatched, not
+        taken in).  Returns the requests this leaves unsettled: the
+        slots', and those that gave up their slot at dispatch and wait
+        for a last token that will not come."""
+        self._flight = self._prev_tok = None
+        left = []
+        for i in self._active():
+            left.append(self._slots[i].req)
+            self._release_slot(i)
+        for rec in records:
+            if rec is not None:
+                if rec.guards is not None:
+                    rec.guards.close()
+                left.extend(t[1] for t in rec.takers)
+        # a request may sit in a slot and in both records
+        return [r for r in dict.fromkeys(left) if not r.done]
+
+    def _take_in(self, flight: Optional[_InFlight], next_np):
+        """``serve/retire``: a fetched step's tokens go to the requests
+        it ran for.  Counts are taken here and not at dispatch, so that a
+        token is never counted before it exists.  With no step fetched
+        (the iteration that starts a pipeline) the span is empty."""
+        with telemetry.span("serve/retire", cat="serve") as rsp:
+            rsp.annotate(retired=0 if flight is None
+                         else self._count_and_settle(flight, next_np))
+
+    def _count_and_settle(self, flight: _InFlight,
+                          next_np: np.ndarray) -> int:
+        c = self._program.config
+        self._breaker.record_success()
+        # what a step costs a caller: from the last fetch's return to
+        # this one's while the pipeline is full, from its own dispatch
+        # when it started one
+        t_fetched = time.perf_counter()
+        step_time = t_fetched - max(flight.t_dispatch, self._t_fetched)
+        self._t_fetched = t_fetched
+        n_prefill = n_decode = 0
+        ended = []
+        now = time.monotonic()
+        for i, req, takes, last in flight.takers:
+            if req.done:
+                # swept, evicted, cancelled or ended on eos while this
+                # step ran for it: no token, no count, no late OK
+                continue
+            if not takes:
+                n_prefill += 1
+                continue
+            n_decode += 1
+            tok = int(next_np[i])
+            req.generated.append(tok)
+            if req.token_times:
+                self._itl_hist.observe(now - req.token_times[-1])
+            else:
+                self._ttft_hist.observe(now - req.enqueued_at)
+            req.token_times.append(now)
+            if last or (c.eos_id is not None and tok == c.eos_id):
+                ended.append((i, req))
+        with self._lock:
+            self._exec_ewma = (step_time if self._exec_ewma == 0.0 else
+                               0.8 * self._exec_ewma + 0.2 * step_time)
+            self._counters["steps"] += 1
+            self._counters["steps_overlapped"] += flight.overlapped
+            self._counters["tokens_prefilled"] += n_prefill
+            self._counters["tokens_decoded"] += n_decode
+            self._counters["contexts_attended"] += flight.attended
+        # counted first, delivered second: a caller that has its answer
+        # finds its tokens in the counts
+        for i, req in ended:
+            slot = self._slots[i]
+            if slot is not None and slot.req is req:
+                # ended on eos: the step dispatched behind this one ran
+                # for it too, and its token will be dropped
+                self._release_slot(i)
+            self._settle(req)
+        self._exec_hist.observe(step_time)
+        self._occ_hist.observe(len(flight.takers) / float(c.max_seqs))
+        telemetry.count("decode.tokens", float(n_decode), kind="decode")
+        if n_prefill:
+            telemetry.count("decode.tokens", float(n_prefill),
+                            kind="prefill")
+        telemetry.window_tick()
+        telemetry.memory.note_step(flight.seq)
+        return len(ended)
 
     # -- swap / stats --------------------------------------------------------
     def _validate_swap(self, source, canary_inputs=None):
@@ -1158,6 +1324,9 @@ class DecodeEngine(ServingRuntime):
             # running sum of the steps' seq_lens: the contexts the
             # attention read, for bytes-per-step and pool-residency maths
             "contexts_attended": counters.get("contexts_attended", 0),
+            # steps dispatched while another was in flight: over "steps",
+            # how often the loop hid the host behind the device
+            "steps_overlapped": counters.get("steps_overlapped", 0),
             "compiles": self._program.trace_count,
             "quantize": c.quantize,
         }
@@ -1176,8 +1345,8 @@ class DecodeEngine(ServingRuntime):
 
     def close(self):
         super().close()
-        for i in self._active():
-            self._retire(i, ServingError("engine closed mid-generation"))
+        for req in self._abandon((self._flight,)):
+            self._settle(req, ServingError("engine closed mid-generation"))
 
 
 def decode_retrace_report(prog: DecodeProgram):

@@ -4,6 +4,7 @@ continuous token-level batching, quantized matmuls, tp serving
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -228,6 +229,218 @@ def test_engine_deadline_and_eviction_no_late_ok(toy):
     assert doomed.done and doomed.latency is not None
 
 
+def _greedy_alone(prog, prompt, max_new, slot=0):
+    """The serial greedy reference: ``DecodeProgram`` stepped alone, one
+    sequence in ``slot``, every token through the host (the
+    eight-argument call)."""
+    c = prog.config
+    S = c.max_seqs
+    table = np.zeros((S, c.pages_per_seq), np.int32)
+    table[slot] = 1 + np.arange(c.pages_per_seq)
+    kv = prog.fresh_cache()
+    fed, out = [int(t) for t in prompt], []
+    for pos in range(len(prompt) + max_new - 1):
+        args = [np.zeros(S, np.int32) for _ in range(5)]
+        for a, v in zip(args, (fed[pos], pos, pos + 1,
+                               table[slot, pos // c.page_size],
+                               pos % c.page_size)):
+            a[slot] = v
+        nxt, _logits, kv = prog.step(kv, *args, table)
+        if pos + 1 >= len(prompt):
+            out.append(int(np.asarray(nxt)[slot]))
+            fed.append(out[-1])
+            if out[-1] == c.eos_id:
+                break
+    return out
+
+
+def test_engine_eos_ends_a_step_late_and_drops_the_overrun(toy):
+    """(a) A sequence that emits ``eos_id`` is found out one step late:
+    the step dispatched behind the one that produced it ran for it too.
+    That step's token is dropped and not counted, the pages come back,
+    and the request that takes them over decodes as if alone."""
+    _cfg, params, _prog = toy
+    plain = DecodeConfig(VOCAB, L, H, HEADS, T, page_size=4, max_seqs=1)
+    rs = np.random.RandomState(0)
+    p1, p2 = rs.randint(0, VOCAB, 3), rs.randint(0, VOCAB, 5)
+    free_run = _greedy_alone(DecodeProgram(params, plain, name="noeos"),
+                             p1, 13)
+    eos = free_run[2]
+    want1 = free_run[:free_run.index(eos) + 1]
+    assert len(want1) < 13
+    cfg = DecodeConfig(VOCAB, L, H, HEADS, T, page_size=4, max_seqs=1,
+                       eos_id=eos)
+    prog = DecodeProgram(params, cfg, name="eos")
+    want2 = _greedy_alone(prog, p2, 11)
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        # each needs all four pages of the pool: the second can only run
+        # in the pages the first gives back
+        assert eng.stats()["decode"]["pages_total"] == 4
+        r1 = eng.submit(p1, max_new_tokens=13)
+        r2 = eng.submit(p2, max_new_tokens=11)
+        out1 = r1.result(timeout=60)[0].tolist()
+        out2 = r2.result(timeout=60)[0].tolist()
+        # r2 may have ended on eos too: let its overrun step come in
+        give_up = time.monotonic() + 10.0
+        while eng._flight is not None and time.monotonic() < give_up:
+            time.sleep(0.001)
+    st = eng.stats()        # closed: the worker has written its last
+    assert out1 == want1 and out1[-1] == eos
+    assert out2 == want2
+    # the overrun steps ran (they are steps) and produced nothing
+    overruns = 1 + (out2[-1] == eos and len(out2) < 11)
+    assert st["decode"]["tokens_decoded"] == len(out1) + len(out2)
+    assert st["decode"]["tokens_prefilled"] == (3 - 1) + (5 - 1)
+    assert st["counters"]["steps"] == (3 + len(out1) - 1) \
+        + (5 + len(out2) - 1) + overruns
+    assert len(r1.token_times) == len(out1)
+    assert st["counters"]["completed"] == 2
+    assert st["decode"]["pages_free"] == st["decode"]["pages_total"]
+    assert prog.trace_count == 1
+
+
+def test_pipelined_engine_equals_the_serial_reference(toy):
+    """(b) Mixed lengths through the loop that keeps one step in flight
+    come out token for token as from the program stepped alone, every
+    token fed is counted once, and the step is still one executable."""
+    cfg, _params, prog = toy
+    rs = np.random.RandomState(7)
+    work = [(rs.randint(0, VOCAB, n), m)
+            for n, m in ((3, 6), (7, 9), (1, 4), (5, 1), (4, 12), (9, 2),
+                         (2, 7))]
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        reqs = [eng.submit(p, max_new_tokens=m) for p, m in work]
+        outs = [r.result(timeout=60)[0].tolist() for r in reqs]
+        st = eng.stats()
+    for (p, m), out in zip(work, outs):
+        assert out == _greedy_alone(prog, p, m)
+    d = st["decode"]
+    assert d["tokens_prefilled"] + d["tokens_decoded"] \
+        == sum(len(p) + m - 1 for p, m in work)
+    assert d["tokens_decoded"] == sum(m for _p, m in work)
+    assert d["compiles"] == prog.trace_count == 1
+    # a pipeline starts only when nothing is in flight, which takes a new
+    # admission into an engine that had drained: once a request at most
+    steps = st["counters"]["steps"]
+    assert steps - len(work) <= d["steps_overlapped"] < steps
+    assert d["pages_free"] == d["pages_total"]
+
+
+def test_exec_error_with_a_step_in_flight(toy):
+    """(c) A dispatch that raises while the step before it is in flight:
+    every running request fails typed, the step in flight is dropped (no
+    token of it counted, nobody late-OKs), and the next request is
+    served from a fresh pool."""
+    from mxnet_tpu.resilience import chaos
+    from mxnet_tpu.serving.errors import ExecFailed
+    cfg, _params, prog = toy
+    rs = np.random.RandomState(2)
+    # prompts of six: steps 1-4 take prompt tokens only
+    p1, p2, p3 = (rs.randint(0, VOCAB, 6) for _ in range(3))
+    try:
+        with DecodeEngine(prog, default_deadline=60.0,
+                          breaker_threshold=100) as eng, \
+                chaos.inject("exec_error", at_step=4):
+            r1 = eng.submit(p1, max_new_tokens=8)
+            r2 = eng.submit(p2, max_new_tokens=8)
+            with pytest.raises(ExecFailed):
+                r1.result(timeout=60)
+            # r2 was running too, unless this thread was held up between
+            # the two submits for four whole steps
+            try:
+                served = [r2.result(timeout=60)[0].tolist()]
+                assert served[0] == _greedy_alone(prog, p2, 8)
+            except ExecFailed:
+                served = []
+            r3 = eng.submit(p3, max_new_tokens=5)
+            served.append(r3.result(timeout=60)[0].tolist())
+            assert served[-1] == _greedy_alone(prog, p3, 5)
+        st = eng.stats()    # closed: the worker has written its last
+    finally:
+        chaos.reset()
+    assert not r1.generated and r1.latency is not None
+    assert st["counters"]["exec_failures"] == 1
+    assert st["counters"]["completed"] == len(served)
+    assert st["decode"]["tokens_decoded"] == sum(len(o) for o in served)
+    if len(served) == 1:
+        # steps 1 and 2 were taken in, step 3 was in flight when step 4's
+        # dispatch raised and is gone with its two prompt tokens
+        assert st["counters"]["steps"] == 2 + (6 + 5 - 1)
+        assert st["decode"]["tokens_prefilled"] <= 2 * 2 + 5
+    assert st["decode"]["pages_free"] == st["decode"]["pages_total"]
+    assert prog.trace_count == 1
+
+
+def test_step_wrapper_sees_host_seq_lens_and_its_own_tokens(
+        toy, monkeypatch):
+    """(d) What a wrapper round ``prog.step`` (the benchmark's) relies
+    on: ``seq_lens`` by name at its place, a host array; a page table
+    that is the step's own copy; and the engine feeding the next step
+    exactly the tokens the wrapper handed back, altered or not."""
+    import inspect
+    cfg, _params, prog = toy
+    inner = prog.step
+    names = list(inspect.signature(inner).parameters)
+    assert names[:7] == ["kv", "tokens", "positions", "seq_lens", "phys",
+                         "off", "page_table"] and names[7] == "prev_tok"
+    calls, handed = [], []
+
+    def step(*args, **kwargs):
+        assert not kwargs
+        calls.append(args)
+        out = inner(*args)
+        tok = ((np.array(out[0]) + 1) % VOCAB).astype(np.int32)
+        handed.append(tok)
+        return (tok,) + tuple(out[1:])
+
+    monkeypatch.setattr(prog, "step", step)
+    prompt = np.arange(3) % VOCAB
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        out = eng.generate(prompt, max_new_tokens=5).tolist()
+        table = eng._table
+    assert len(calls) == 3 + 5 - 1
+    for k, args in enumerate(calls):
+        seq_lens, page_table = args[3], args[6]
+        assert type(seq_lens) is np.ndarray and seq_lens[0] == k + 1
+        assert type(page_table) is np.ndarray
+        assert not np.shares_memory(page_table, table)
+        # past the prompt the slot's token is -1 and comes from prev_tok,
+        # which is what the wrapper returned one call earlier
+        assert args[1][0] == (prompt[k] if k < 3 else -1)
+        assert args[7] is (handed[k - 1] if k else None)
+    # and the caller got the altered tokens, not the program's
+    assert out == [int(h[0]) for h in handed[2:]]
+
+
+def test_lone_generate_takes_its_steps_and_no_idle_sleep(toy, monkeypatch):
+    """(e) One request alone: n + m - 1 steps, all but the first
+    dispatched behind another, and when its last step is out the loop
+    fetches it at once and does not wait on the queue first."""
+    cfg, _params, prog = toy
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        waits = []
+        pop_live = eng._queue.pop_live
+
+        def watched(timeout=0):
+            if timeout and eng._flight is not None:
+                waits.append(timeout)
+            return pop_live(timeout=timeout)
+
+        monkeypatch.setattr(eng._queue, "pop_live", watched)
+        out = eng.generate(np.arange(4) % VOCAB, max_new_tokens=6)
+        st = eng.stats()
+        one = eng.generate(np.arange(1) % VOCAB, max_new_tokens=1)
+        st1 = eng.stats()
+    assert out.tolist() == _greedy_alone(prog, np.arange(4) % VOCAB, 6)
+    assert st["counters"]["steps"] == 4 + 6 - 1
+    assert st["decode"]["steps_overlapped"] == 4 + 6 - 2
+    # a one-token request is one step with nothing to overlap
+    assert len(one) == 1
+    assert st1["counters"]["steps"] - st["counters"]["steps"] == 1
+    assert st1["decode"]["steps_overlapped"] == 4 + 6 - 2
+    assert not waits
+
+
 def test_quantized_engine_logit_kl_probe(toy):
     """int8/int4 weight-only quantization stays within the quality
     probe: bounded max-KL between f32 and quantized next-token
@@ -385,6 +598,8 @@ def test_tp2_engine_kill_swap_drill(toy):
         st = eng.stats()
         assert st["decode"]["pages_free"] == st["decode"]["pages_total"]
         assert ok == 6 and late == 0
+    # one executable each, though b's first steps were fed a's tokens
+    assert p_a.trace_count == 1 and p_b.trace_count == 1
     # geometry mismatch is refused with the old model still serving
     cfg2 = DecodeConfig(VOCAB, L, H, HEADS, T * 2, page_size=4,
                         max_seqs=cfg.max_seqs)

@@ -166,19 +166,43 @@ def test_engine_emits_its_six_spans_once_a_step(toy, recorder):
     kept = []
     for i, item in enumerate(order):
         lone = (item == ("exit", "serve/admit")
-                and order[i + 1:i + 2] != [("enter", "serve/build")])
+                and order[i + 1:i + 2] not in ([("enter", "serve/build")],
+                                               [("enter", "serve/fetch")]))
         if lone:
             assert kept.pop() == ("enter", "serve/admit")
             continue
         kept.append(item)
-    one_step = [("enter", "serve/admit"), ("exit", "serve/admit"),
-                ("enter", "serve/build"), ("exit", "serve/build"),
-                ("enter", "serve/decode_step"),
-                ("enter", "serve/dispatch"), ("exit", "serve/dispatch"),
-                ("enter", "serve/fetch"), ("exit", "serve/fetch"),
-                ("exit", "serve/decode_step"),
-                ("enter", "serve/retire"), ("exit", "serve/retire")]
-    assert kept == one_step * steps
+    # The loop keeps one step in flight, so the fetch and the retire of
+    # iteration k are step k-1's.  An iteration that dispatches emits all
+    # six spans in the old order; the one that starts a pipeline has
+    # nothing in flight (in_flight=0) and its fetch and retire are empty.
+    # When nothing is left to dispatch the step in flight is taken in by a
+    # drain iteration: serve/admit, then serve/fetch and serve/retire with
+    # no serve/decode_step round them.
+    admit = [("enter", "serve/admit"), ("exit", "serve/admit")]
+    taken_in = [("enter", "serve/retire"), ("exit", "serve/retire")]
+    fetch = [("enter", "serve/fetch"), ("exit", "serve/fetch")]
+    one_step = admit + [("enter", "serve/build"), ("exit", "serve/build"),
+                        ("enter", "serve/decode_step"),
+                        ("enter", "serve/dispatch"),
+                        ("exit", "serve/dispatch")] + fetch \
+        + [("exit", "serve/decode_step")] + taken_in
+    drain = admit + fetch + taken_in
+    shape, at = [], 0           # "step" or "drain", iteration by iteration
+    while at < len(kept):
+        for kind, spans in (("step", one_step), ("drain", drain)):
+            if kept[at:at + len(spans)] == spans:
+                shape.append(kind)
+                at += len(spans)
+                break
+        else:
+            raise AssertionError("iteration %d: %s" % (len(shape),
+                                                       kept[at:at + 12]))
+    assert shape.count("step") == steps
+    assert shape[-1] == "drain"         # the last step's tokens came in
+    # a drain follows a step (there is one step in flight, never two)
+    assert all(shape[i - 1] == "step" for i, kind in enumerate(shape)
+               if kind == "drain") and shape[0] == "step"
 
     entered = [(name, attrs) for _t, what, name, attrs in log
                if what == "enter"]
@@ -191,6 +215,13 @@ def test_engine_emits_its_six_spans_once_a_step(toy, recorder):
     assert sum(a["n_decode"] for a in step_attrs) \
         == stats["decode"]["tokens_decoded"]
     assert [a["batch"] for a in step_attrs] == list(range(1, steps + 1))
+    # in_flight is 0 on the step that starts a pipeline (the first, and
+    # the one after each drain) and 1 on every other
+    starts = [i == 0 or shape[i - 1] == "drain"
+              for i, kind in enumerate(shape) if kind == "step"]
+    assert [a["in_flight"] for a in step_attrs] == [int(not st)
+                                                    for st in starts]
+    assert stats["decode"]["steps_overlapped"] == steps - sum(starts)
     assert all(set(a) == {"slots"} for n, a in entered
                if n == "serve/build")
     metas = {}
