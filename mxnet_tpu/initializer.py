@@ -79,7 +79,7 @@ class Initializer:
         (("gamma",), "_init_gamma"),
         (("beta",), "_init_beta"),
         (("moving_mean", "running_mean", "moving_inv_var", "moving_avg",
-          "min", "max"), "_init_zero"),
+          "min", "max", "expert_load"), "_init_zero"),
         (("moving_var", "running_var"), "_init_one"),
     )
 

@@ -10,6 +10,7 @@ from . import mobilenet
 from . import googlenet
 from . import inception_v4
 from . import transformer
+from . import afmoe
 
 get_resnet = resnet.get_symbol
 get_lenet = lenet.get_symbol

@@ -25,8 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..base import (attr_bool, attr_dtype, attr_float, attr_int, attr_shape,
-                    attr_str, Param)
+from ..base import (MXNetError, attr_bool, attr_dtype, attr_float, attr_int,
+                    attr_shape, attr_str, Param)
 from .registry import register, get_op
 
 
@@ -230,6 +230,7 @@ def _activation(attrs, x):
         # TPU-era extension (later-reference LeakyReLU gelu mode);
         # exact erf formulation, matching the reference GELU
         "gelu": lambda v: jax.nn.gelu(v, approximate=False),
+        "silu": jax.nn.silu,
     }[attrs.act_type](x)
 
 
@@ -359,6 +360,40 @@ def _layer_norm(attrs, x, gamma, beta):
     out = (x32 - mean) * inv * gamma.reshape(shape) + beta.reshape(shape)
     return (out.astype(x.dtype), jnp.squeeze(mean.astype(x.dtype), ax),
             jnp.squeeze(var.astype(x.dtype), ax))
+
+
+@register("RMSNorm", inputs=("data", "gamma"),
+          params=dict(axis=Param(int, -1), eps=attr_float(1e-5)))
+def _rms_norm(attrs, x, gamma):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over ``axis``: statistics in
+    float32, the result back in the input's dtype (as LayerNorm above: the
+    trainer keeps ``*_gamma`` float32)."""
+    ax = attrs.axis
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=ax, keepdims=True)
+                        + attrs.eps)
+    shape = [1] * x.ndim
+    shape[ax] = x.shape[ax]
+    return (x32 * inv * gamma.reshape(shape)).astype(x.dtype)
+
+
+@register("_contrib_rotary_embedding", inputs=("data",),
+          params=dict(theta=attr_float(10000.0)),
+          aliases=("rotary_embedding",))
+def _rotary_embedding(attrs, x):
+    """Rotary positions over (B, T, H, D), positions 0..T-1, the pairs
+    (i, i + D/2) turned by ``pos * theta^(-2i/D)``: angles and the turn in
+    float32, the result in the input's dtype."""
+    T, D = x.shape[1], x.shape[-1]
+    half = D // 2
+    freq = jnp.float32(attrs.theta) ** (
+        -jnp.arange(half, dtype=jnp.float32) / jnp.float32(half))
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 @register("LRN", inputs=("data",),
@@ -735,10 +770,17 @@ def _per_mesh_shard(fn):
 
 @register("_contrib_fused_attention", inputs=("query", "key", "value"),
           params=dict(causal=attr_bool(False), scale=attr_float(0.0),
-                      block_q=attr_int(0), flash_min_seq=attr_int(0)),
+                      block_q=attr_int(0), flash_min_seq=attr_int(0),
+                      window=attr_int(0)),
           aliases=("fused_attention",))
 def _contrib_fused_attention(attrs, q, k, v):
     """Attention over (B, T, H, D); dispatches by sequence length.
+
+    ``key`` and ``value`` may carry fewer heads than ``query``, (B, T, G, D)
+    with G dividing H: query head n reads key/value head n // (H // G) and
+    their gradients are summed over the group.  ``window`` (0 = none, needs
+    ``causal``): query i sees keys i - window < j <= i; the kernels skip
+    the key blocks wholly outside that band.
 
     Short sequences (T < flash_min_seq, default 1024, env
     MXNET_FLASH_MIN_SEQ) run the plain einsum formulation end-to-end:
@@ -756,6 +798,10 @@ def _contrib_fused_attention(attrs, q, k, v):
     backward."""
     scale = attrs.scale if attrs.scale > 0 else 1.0 / float(q.shape[-1]) ** 0.5
     causal = attrs.causal
+    window = attrs.window
+    if window < 0 or (window and not causal):
+        raise MXNetError("fused_attention: window must be >= 0 and needs "
+                         "causal=True, got %d" % window)
     block_q = attrs.block_q
     if block_q < 0:
         raise MXNetError("fused_attention: block_q must be >= 0 "
@@ -763,13 +809,23 @@ def _contrib_fused_attention(attrs, q, k, v):
     block_q = block_q or None          # 0 -> consult the autotune cache
 
     def naive(q, k, v):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        B, Tq, H, D = q.shape
+        Tk, G = k.shape[1:3]
+        if G == H:
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        else:   # a group's H // G query heads against its one key/value head
+            s = jnp.einsum("bqgrd,bkgd->bgrqk",
+                           q.reshape(B, Tq, G, H // G, D), k) * scale
         if causal:
-            Tq, Tk = q.shape[1], k.shape[1]
-            mask = jnp.tril(jnp.ones((Tq, Tk), bool))
-            s = jnp.where(mask, s, -jnp.inf)
+            gap = jnp.arange(Tq)[:, None] - jnp.arange(Tk)[None, :]
+            seen = gap >= 0
+            if window:
+                seen = seen & (gap < window)
+            s = jnp.where(seen, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        if G == H:
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(B, Tq, H, D)
 
     flash_min = attrs.flash_min_seq or _FLASH_MIN_SEQ
     if q.shape[1] < flash_min:
@@ -777,7 +833,7 @@ def _contrib_fused_attention(attrs, q, k, v):
 
     from . import pallas_kernels as pk
     # the kernels fit block_q/block_k to T themselves
-    kw = dict(causal=causal, scale=scale, block_q=block_q)
+    kw = dict(causal=causal, scale=scale, block_q=block_q, window=window)
 
     @jax.custom_vjp
     def attn(q, k, v):
@@ -800,3 +856,46 @@ def _contrib_fused_attention(attrs, q, k, v):
 
     attn.defvjp(fwd, bwd)
     return attn(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of a top-k expert layer (parallel/moe.py) as a graph op
+# ---------------------------------------------------------------------------
+
+@register("_contrib_moe_ffn",
+          inputs=("data", "router_weight", "shared_w1", "shared_w3",
+                  "shared_w2", "expert_w1", "expert_w3", "expert_w2",
+                  "expert_bias", "expert_load"),
+          params=dict(num_experts=attr_int(required=True),
+                      experts_held=attr_int(required=True),
+                      first_expert=attr_int(0), top_k=attr_int(1),
+                      num_hidden=attr_int(required=True),
+                      route_norm=attr_bool(True), route_scale=attr_float(1.0),
+                      bias_update_rate=attr_float(0.001)),
+          num_outputs=3, num_visible_outputs=1,
+          writeback={8: 1, 9: 2}, aux_inputs=(8, 9), mode_dependent=True,
+          aliases=("moe_ffn",))
+def _contrib_moe_ffn(attrs, x, wr, s1, s3, s2, w1, w3, w2, bias, load):
+    """Sigmoid top-k routed experts with one shared expert over (..., d)
+    tokens, as the chip that holds experts ``first_expert`` ..
+    ``first_expert + experts_held`` of ``num_experts`` computes it
+    (:func:`mxnet_tpu.parallel.moe.moe_ffn_held`): routing over all of
+    them, no capacity and no drop, the absent experts' part left out.
+
+    Auxiliary states, both over ALL experts: ``expert_bias`` (the
+    selection bias of auxiliary-loss-free balancing) and ``expert_load``
+    (tokens routed to each expert by the last training step).  A training
+    forward writes this step's load and moves the bias by
+    ``bias_update_rate * sign(mean(load) - load)``; the output uses the
+    bias it was given."""
+    from ..parallel import moe
+    m = x.reshape(-1, x.shape[-1])
+    out, n = moe.moe_ffn_held(
+        m, wr, bias, (s1, s3, s2), (w1, w3, w2),
+        num_experts=attrs.num_experts, first_expert=attrs.first_expert,
+        top_k=attrs.top_k, route_norm=attrs.route_norm,
+        route_scale=attrs.route_scale)
+    if attrs.get("_train", False):
+        n = jax.lax.stop_gradient(n)
+        bias, load = moe.balanced_bias(bias, n, attrs.bias_update_rate), n
+    return out.reshape(x.shape), bias, load

@@ -22,6 +22,7 @@ a stable ``name=`` so a compiled program can be checked for it
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -34,7 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["two_bit_compress", "fused_attention", "fused_attention_fwd",
            "fused_attention_bwd", "decode_attention",
            "decode_attention_pool", "kv_write", "kv_pack", "quantize_weight",
-           "quant_matmul"]
+           "quant_matmul", "grouped_matmul"]
 
 
 def _interpret(*arrays) -> bool:
@@ -230,28 +231,44 @@ def _pick_blocks(block_q, block_k, Tq, Tk, D, dtype, kind, interpret):
     return bq, bk, _fit_block(_FLASH_SUB_K, bk, lanes)
 
 
-def _live_sub_tiles(qi, ki, *, causal, block_q, block_k, sub_k):
-    """``(n_unmasked, n_live)`` of the cell's ``block_k // sub_k`` key
-    sub-tiles, counted from the block's first: how many lie at or below
-    the diagonal for every query of the block (no mask to apply), and how
-    many hold a live score at all.  The sub-tiles between the two
-    straddle the diagonal; those past ``n_live`` are never touched."""
+def _live_sub_tiles(qi, ki, *, causal, window, block_q, block_k, sub_k):
+    """``(first, unmasked_from, unmasked_to, n_live)`` over the cell's
+    ``block_k // sub_k`` key sub-tiles, counted from the block's first.
+    Sub-tiles before ``first`` lie wholly left of the window's band and
+    those from ``n_live`` on wholly above the diagonal: neither is ever
+    touched.  ``[unmasked_from, unmasked_to)`` hold a live score for every
+    query of the block and take no mask; the sub-tiles round them straddle
+    the band's left edge (``[first, unmasked_from)``, a window only) or
+    the diagonal (``[unmasked_to, n_live)``).  With no window the first two
+    are the constant 0."""
     n = block_k // sub_k
     if not causal:
-        return n, n
+        return 0, 0, n, n
     first_q = qi * block_q - ki * block_k       # relative to the key block
     n_unmasked = jnp.minimum(
         jax.lax.div(jnp.maximum(first_q + 1, 0), sub_k), n)
     n_live = jnp.minimum(
         jax.lax.div(jnp.maximum(first_q + block_q - 1 + sub_k, 0), sub_k),
         n)
-    return n_unmasked, n_live
+    if not window:
+        return 0, 0, n_unmasked, n_live
+    # query i sees keys i - window < j <= i: the block's first query sets
+    # the leftmost live key, its last the leftmost key all of them see
+    first = jnp.minimum(
+        jax.lax.div(jnp.maximum(first_q - window + 1, 0), sub_k), n_live)
+    full = jax.lax.div(
+        jnp.maximum(first_q + block_q - window, 0) + sub_k - 1, sub_k)
+    unmasked_from = jnp.clip(full, first, n_live)
+    return first, unmasked_from, jnp.maximum(n_unmasked, unmasked_from), \
+        n_live
 
 
 def _walk_sub_tiles(tile, qi, ki, **geometry):
-    """Run ``tile(t, masked)`` over the cell's live key sub-tiles: the
-    unmasked ones first, then those on the diagonal."""
-    n_unmasked, n_live = _live_sub_tiles(qi, ki, **geometry)
+    """Run ``tile(t, masked)`` over the cell's live key sub-tiles: those
+    on the band's left edge (a window only), the unmasked ones, then
+    those on the diagonal."""
+    first, unmasked_from, unmasked_to, n_live = _live_sub_tiles(
+        qi, ki, **geometry)
 
     def run(masked):
         def body(t, carry):
@@ -259,9 +276,11 @@ def _walk_sub_tiles(tile, qi, ki, **geometry):
             return carry
         return body
 
-    jax.lax.fori_loop(0, n_unmasked, run(False), None)
+    if geometry["window"]:
+        jax.lax.fori_loop(first, unmasked_from, run(True), None)
+    jax.lax.fori_loop(unmasked_from, unmasked_to, run(False), None)
     if geometry["causal"]:
-        jax.lax.fori_loop(n_unmasked, n_live, run(True), None)
+        jax.lax.fori_loop(unmasked_to, n_live, run(True), None)
 
 
 def _sub_tile_start(t, sub_k, block_k):
@@ -271,16 +290,20 @@ def _sub_tile_start(t, sub_k, block_k):
     return 0 if sub_k == block_k else pl.multiple_of(t * sub_k, sub_k)
 
 
-def _scores_t(k, q, scale, diagonal):
+def _scores_t(k, q, scale, diagonal, window=0):
     """The (sub_k, bq) transposed score tile ``K·Qᵀ·scale``; ``diagonal``
     is None, or the tile's first (key, query) position for the causal
-    mask, which only a tile that straddles the diagonal needs."""
+    mask (and the window's, where there is one), which only a tile that
+    straddles the diagonal or the band's left edge needs."""
     st = _mxu_dot(k, q, _NT) * scale
     if diagonal is not None:
         k_first, q_first = diagonal
         k_idx = k_first + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
         q_idx = q_first + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        st = jnp.where(q_idx >= k_idx, st, jnp.float32(_NEG_BIG))
+        seen = q_idx >= k_idx
+        if window:
+            seen = seen & (q_idx - k_idx < window)
+        st = jnp.where(seen, st, jnp.float32(_NEG_BIG))
     return st
 
 
@@ -292,21 +315,39 @@ def _skip_dead_copy(live, index, held):
     return jnp.where(live, index, held)
 
 
-def _live_k_block(causal, block_q, block_k):
+def _first_live_k_block(i, window, block_q, block_k):
+    """First key block that query block ``i`` sees: 0 without a window."""
+    if not window:
+        return 0
+    return jax.lax.div(jnp.maximum(i * block_q - window + 1, 0), block_k)
+
+
+def _live_k_block(causal, window, block_q, block_k):
     """Key block a cell of the grids whose inner axis runs over key blocks
     (``flash_fwd``, ``flash_bwd_dq``) copies.  A block wholly above the
-    diagonal names block 0: the copy starts under the row's last live cell
-    and is what the next row of cells reads first."""
+    diagonal names the first block the NEXT row of cells reads (block 0
+    without a window): the copy starts under the row's last live cell.  A
+    block wholly left of the window's band names the row's first live one."""
     def block(i, j):
         if causal:
+            first = functools.partial(_first_live_k_block, window=window,
+                                      block_q=block_q, block_k=block_k)
+            if window:
+                j = jnp.maximum(j, first(i))
             j = _skip_dead_copy(j * block_k <= i * block_q + block_q - 1,
-                                j, 0)
+                                j, first(i + 1))
         return j
     return block
 
 
+def _kv_head(b, rep):
+    """Flat (batch, key/value head) index that flat (batch, query head)
+    ``b`` reads: ``rep`` query heads share one."""
+    return b if rep == 1 else jax.lax.div(b, rep)
+
+
 def _flash_kernel(q_ref, k_ref, vt_ref, ot_ref, *rest, scale, causal,
-                  block_q, block_k, sub_k, nk, with_lse):
+                  window, block_q, block_k, sub_k, nk, with_lse):
     """Flash attention cell: one (block_q, D) query block against one
     (block_k, D) K block and (D, block_k) Vᵀ block, walked in sub_k keys,
     with the running (max, sum, acc) online-softmax state in VMEM
@@ -332,7 +373,7 @@ def _flash_kernel(q_ref, k_ref, vt_ref, ot_ref, *rest, scale, causal,
         keys = pl.ds(start, sub_k)
         st = _scores_t(k_ref[keys, :], q_ref[:], scale,
                        (ki * block_k + start, qi * block_q)
-                       if masked else None)             # (sub_k, bq)
+                       if masked else None, window)     # (sub_k, bq)
         m_prev = m_ref[:]                               # (1, bq)
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -343,9 +384,10 @@ def _flash_kernel(q_ref, k_ref, vt_ref, ot_ref, *rest, scale, causal,
         acc_ref[:] = acc_ref[:] * corr + _mxu_dot(vt, pt.astype(vt.dtype),
                                                   _NN)
 
-    # causal: sub-tiles wholly above this q block's last row are skipped
-    _walk_sub_tiles(_tile, qi, ki, causal=causal, block_q=block_q,
-                    block_k=block_k, sub_k=sub_k)
+    # causal: sub-tiles wholly above this q block's last row are skipped,
+    # and with a window those wholly left of its first row's band
+    _walk_sub_tiles(_tile, qi, ki, causal=causal, window=window,
+                    block_q=block_q, block_k=block_k, sub_k=sub_k)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -364,13 +406,15 @@ _GRID_PARAMS = pltpu.CompilerParams(
 
 
 def _flash_call(operands, *, with_lse, interpret, **geometry):
-    """``flash_fwd`` over (B*H, T, D) q, k and (B*H, D, T) vᵀ: the output
+    """``flash_fwd`` over (B*H, T, D) q, (B*G, T, D) k and (B*G, D, T) vᵀ,
+    ``rep`` = H // G query heads to a key/value head: the output
     transposed, (B*H, D, Tq), and the (B*H, 1, Tq) logsumexp or None."""
     qf, kf, _ = operands
     BH, Tq, D = qf.shape
     bq, bk = geometry["block_q"], geometry["block_k"]
     nk = kf.shape[1] // bk
-    live_k = _live_k_block(geometry["causal"], bq, bk)
+    rep = BH // kf.shape[0]
+    live_k = _live_k_block(geometry["causal"], geometry["window"], bq, bk)
     out_shape = [_out_struct((BH, D, Tq), qf.dtype, *operands)]
     out_specs = [pl.BlockSpec((None, D, bq), lambda b, i, j: (b, 0, i))]
     if with_lse:
@@ -387,10 +431,10 @@ def _flash_call(operands, *, with_lse, interpret, **geometry):
             grid=(BH, Tq // bq, nk),
             in_specs=[
                 pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((None, bk, D),
-                             lambda b, i, j: (b, live_k(i, j), 0)),
-                pl.BlockSpec((None, D, bk),
-                             lambda b, i, j: (b, 0, live_k(i, j))),
+                pl.BlockSpec((None, bk, D), lambda b, i, j: (
+                    _kv_head(b, rep), live_k(i, j), 0)),
+                pl.BlockSpec((None, D, bk), lambda b, i, j: (
+                    _kv_head(b, rep), 0, live_k(i, j))),
             ],
             out_specs=tuple(out_specs) if with_lse else out_specs[0],
             out_shape=tuple(out_shape) if with_lse else out_shape[0],
@@ -423,7 +467,18 @@ def _from_heads_major_t(xt, B):
     return xt.reshape(B, BH // B, D, T).transpose(0, 3, 1, 2)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
+def _check_heads_and_window(q, k, v, causal, window):
+    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+        raise ValueError("fused_attention: key and value of one shape whose "
+                         "head count divides the query's, got q %s k %s v %s"
+                         % (q.shape, k.shape, v.shape))
+    if window and (window < 0 or not causal):
+        raise ValueError("fused_attention: a window (%d) is a band below "
+                         "the diagonal, so it needs causal=True" % window)
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse, window):
+    _check_heads_and_window(q, k, v, causal, window)
     D = q.shape[-1]
     if scale is None:
         scale = 1.0 / float(np.sqrt(D))
@@ -432,18 +487,22 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
                                  D, q.dtype, "fwd", interpret)
     out_t, lse = _flash_call(
         (_heads_major(q), _heads_major(k), _heads_major_t(v)), scale=scale,
-        causal=causal, block_q=bq, block_k=bk, sub_k=sub_k,
-        with_lse=with_lse, interpret=interpret)
+        causal=causal, window=int(window), block_q=bq, block_k=bk,
+        sub_k=sub_k, with_lse=with_lse, interpret=interpret)
     return _from_heads_major_t(out_t, q.shape[0]), lse
 
 
 def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, scale=None,
-                    block_q=None, block_k=None) -> jax.Array:
+                    block_q=None, block_k=None, window: int = 0) -> jax.Array:
     """Flash attention forward: K/V-blocked online softmax.
 
-    q/k/v: (B, T, H, D) (the parallel/ring.py layout).  Returns
-    (B, T, H, D).  Per grid cell only (block_q + 2*block_k, D) tiles and
+    q: (B, T, H, D) (the parallel/ring.py layout); k/v: (B, T, G, D) with
+    G dividing H, query head n reading key/value head n // (H // G).
+    ``window`` (0 = none; needs ``causal``): query i sees keys
+    i - window < j <= i, and key blocks wholly left of that band are
+    skipped like those above the diagonal.  Returns (B, T, H, D).
+    Per grid cell only (block_q + 2*block_k, D) tiles and
     a (sub_k, block_q) score tile live in VMEM — HBM traffic is
     O(T*D) and the sequence length is bounded by HBM, not VMEM (the
     round-3 kernel held ALL of K/V in VMEM and topped out near T=8k;
@@ -455,28 +514,30 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``autotune.DEFAULT_FLASH_BLOCKS["fwd"]`` (from v5e timings), fitted
     to T; on the chip a query block is a multiple of 128 or all of T.
     """
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, False)[0]
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, False,
+                      window)[0]
 
 
 def fused_attention_fwd(q, k, v, causal=False, scale=None,
-                        block_q=None, block_k=None):
+                        block_q=None, block_k=None, window=0):
     """Forward for the custom vjp: returns ``(out, lse)`` where ``lse``
     is the per-row logsumexp of the scaled logits, shape
     ``(B*H, 1, Tq)`` f32 (lane-major, 4 bytes a query).  With this
     residual the backward never rematerializes the softmax normalizer:
     one extra O(T) output instead of re-running the online softmax."""
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, True)
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, True,
+                      window)
 
 
 def _p_and_ds_t(start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref, *,
-                scale, diagonal, sub_k):
+                scale, diagonal, sub_k, window):
     """What both backward kernels rebuild of the sub-tile whose first key
     is ``start``, transposed, (sub_k, bq): ``p`` from the saved row logsumexp (the scores are
     recomputed, one exp each; the online softmax is not), and
     ``ds = p·(dp - delta)·scale`` with ``delta = rowsum(dO·O)`` folding
     the normalizer's term."""
     keys = pl.ds(start, sub_k)
-    st = _scores_t(k_ref[keys, :], q_ref[:], scale, diagonal)
+    st = _scores_t(k_ref[keys, :], q_ref[:], scale, diagonal, window)
     pt = jnp.exp(st - lse_ref[:])               # masked scores -> 0
     dpt = _mxu_dot(v_ref[keys, :], do_ref[:], _NT)
     return keys, pt, pt * (dpt - dl_ref[:]) * scale
@@ -484,7 +545,7 @@ def _p_and_ds_t(start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref, *,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref,
                          dl_ref, dqt_ref, acc_ref, *, scale, causal,
-                         block_q, block_k, sub_k, nk):
+                         window, block_q, block_k, sub_k, nk):
     """dQ cell: one (bq, D) query block against the sequential k-axis,
     ``dQᵀ += Kᵀ·dSᵀ`` into a (D, bq) accumulator."""
     qi = pl.program_id(1)
@@ -498,14 +559,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref,
         start = _sub_tile_start(t, sub_k, block_k)
         keys, _, dst = _p_and_ds_t(
             start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref,
-            scale=scale, sub_k=sub_k,
+            scale=scale, sub_k=sub_k, window=window,
             diagonal=(ki * block_k + start, qi * block_q) if masked
             else None)
         kt = kt_ref[:, keys]                        # (D, sub_k)
         acc_ref[:] = acc_ref[:] + _mxu_dot(kt, dst.astype(kt.dtype), _NN)
 
-    _walk_sub_tiles(_tile, qi, ki, causal=causal, block_q=block_q,
-                    block_k=block_k, sub_k=sub_k)
+    _walk_sub_tiles(_tile, qi, ki, causal=causal, window=window,
+                    block_q=block_q, block_k=block_k, sub_k=sub_k)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -514,13 +575,17 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                          causal, block_q, block_k, sub_k, nq):
+                          causal, window, block_q, block_k, sub_k, nq, rep):
     """dK/dV cell: one (bk, D) key/value block against the sequential
-    q-axis, ``dV += Pᵀ·dO`` and ``dK += dSᵀ·Q`` into VMEM scratch."""
+    inner axis, ``dV += Pᵀ·dO`` and ``dK += dSᵀ·Q`` into VMEM scratch.
+    The inner axis runs over the q blocks of each of the ``rep`` query
+    heads that read this key/value head, one head after the other, so the
+    group's sum forms in the scratch."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = step if rep == 1 else jax.lax.rem(step, nq)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -529,7 +594,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         start = _sub_tile_start(t, sub_k, block_k)
         keys, pt, dst = _p_and_ds_t(
             start, q_ref, do_ref, k_ref, v_ref, lse_ref, dl_ref,
-            scale=scale, sub_k=sub_k,
+            scale=scale, sub_k=sub_k, window=window,
             diagonal=(ki * block_k + start, qi * block_q) if masked
             else None)
         do = do_ref[:]
@@ -540,10 +605,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                                                      q, _NN)
 
     # causal: q blocks entirely ABOVE this k block see none of it
-    _walk_sub_tiles(_tile, qi, ki, causal=causal, block_q=block_q,
-                    block_k=block_k, sub_k=sub_k)
+    _walk_sub_tiles(_tile, qi, ki, causal=causal, window=window,
+                    block_q=block_q, block_k=block_k, sub_k=sub_k)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == rep * nq - 1)
     def _finish():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -551,17 +616,19 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 def _flash_dq_call(operands, *, interpret, **geometry):
     """dQᵀ, (B*H, D, Tq): grid (B*H, q blocks, k blocks), k innermost.
-    ``operands``: q, k, kᵀ, v, do, lse, delta."""
+    ``operands``: q, k, kᵀ, v, do, lse, delta; k, kᵀ and v by key/value
+    head."""
     qf, kf = operands[:2]
     BH, Tq, D = qf.shape
     bq, bk = geometry["block_q"], geometry["block_k"]
     nk = kf.shape[1] // bk
-    live_k = _live_k_block(geometry["causal"], bq, bk)
+    rep = BH // kf.shape[0]
+    live_k = _live_k_block(geometry["causal"], geometry["window"], bq, bk)
     q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((None, bk, D),
-                          lambda b, i, j: (b, live_k(i, j), 0))
-    kt_spec = pl.BlockSpec((None, D, bk),
-                           lambda b, i, j: (b, 0, live_k(i, j)))
+    k_spec = pl.BlockSpec((None, bk, D), lambda b, i, j: (
+        _kv_head(b, rep), live_k(i, j), 0))
+    kt_spec = pl.BlockSpec((None, D, bk), lambda b, i, j: (
+        _kv_head(b, rep), 0, live_k(i, j)))
     row_spec = pl.BlockSpec((None, 1, bq), lambda b, i, j: (b, 0, i))
     with jax.enable_x64(False):
         return pl.pallas_call(
@@ -578,35 +645,54 @@ def _flash_dq_call(operands, *, interpret, **geometry):
 
 
 def _flash_dkv_call(operands, *, interpret, **geometry):
-    """dK, dV, (B*H, Tk, D): grid (B*H, k blocks, q blocks), q innermost.
+    """dK, dV, (B*G, Tk, D): grid (B*G, k blocks, rep x q blocks), the q
+    blocks of a group's ``rep`` query heads innermost.
     ``operands``: q, k, v, do, lse, delta."""
     qf, kf, vf = operands[:3]
     BH, Tq, D = qf.shape
-    Tk = kf.shape[1]
+    BG, Tk = kf.shape[:2]
     bq, bk = geometry["block_q"], geometry["block_k"]
+    window = geometry["window"]
     nq = Tq // bq
+    rep = BH // BG
 
     def live_q(j, i):
         # the q blocks above k block j see none of it and come first in
-        # its row of cells; they name the first live one
+        # its row of cells; they name the first live one.  With a window
+        # those past the band come last and name the last live one
         if geometry["causal"]:
+            if window:
+                i = jnp.minimum(i, jax.lax.div(j * bk + bk + window - 2, bq))
             i = _skip_dead_copy(i * bq + bq - 1 >= j * bk, i,
                                 jnp.minimum(jax.lax.div(j * bk, bq), nq - 1))
         return i
 
-    q_spec = pl.BlockSpec((None, bq, D),
-                          lambda b, j, i: (b, live_q(j, i), 0))
-    row_spec = pl.BlockSpec((None, 1, bq),
-                            lambda b, j, i: (b, 0, live_q(j, i)))
-    kv_spec = pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0))
+    def q_at(b, j, t):
+        """(flat query head, q block) of inner step ``t``."""
+        if rep == 1:
+            return b, live_q(j, t)
+        return b * rep + jax.lax.div(t, nq), live_q(j, jax.lax.rem(t, nq))
+
+    def q_index(b, j, t):
+        head, i = q_at(b, j, t)
+        return head, i, 0
+
+    def row_index(b, j, t):
+        head, i = q_at(b, j, t)
+        return head, 0, i
+
+    q_spec = pl.BlockSpec((None, bq, D), q_index)
+    row_spec = pl.BlockSpec((None, 1, bq), row_index)
+    kv_spec = pl.BlockSpec((None, bk, D), lambda b, j, t: (b, j, 0))
     with jax.enable_x64(False):
         return pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, nq=nq, **geometry),
-            grid=(BH, Tk // bk, nq),
+            functools.partial(_flash_bwd_dkv_kernel, nq=nq, rep=rep,
+                              **geometry),
+            grid=(BG, Tk // bk, rep * nq),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
             out_specs=(kv_spec, kv_spec),
-            out_shape=(_out_struct((BH, Tk, D), kf.dtype, *operands),
-                       _out_struct((BH, Tk, D), vf.dtype, *operands)),
+            out_shape=(_out_struct((BG, Tk, D), kf.dtype, *operands),
+                       _out_struct((BG, Tk, D), vf.dtype, *operands)),
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                             pltpu.VMEM((bk, D), jnp.float32)],
             compiler_params=_GRID_PARAMS,
@@ -615,7 +701,7 @@ def _flash_dkv_call(operands, *, interpret, **geometry):
 
 
 def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
-                        block_q=None, block_k=None):
+                        block_q=None, block_k=None, window=0):
     """Flash attention backward: K/V-blocked dQ/dK/dV from the saved
     logsumexp residual — the online softmax is not re-run (each kernel
     recomputes its score tiles, one exp a score), and no (T, T) tensor
@@ -623,14 +709,17 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
     probability matrix AND its gradient: ~2·B·H·T² values of HBM traffic
     per layer that these kernels never touch).
 
-    q/k/v/out/do: (B, T, H, D); ``lse``: (B*H, 1, Tq) f32 from
+    q/out/do: (B, T, H, D), k/v: (B, T, G, D) as in
+    :func:`fused_attention`; ``lse``: (B*H, 1, Tq) f32 from
     :func:`fused_attention_fwd`.  Returns (dq, dk, dv) in the input
-    dtypes.  Two pallas calls: dQ accumulates over the sequential
+    dtypes and shapes: dK and dV are summed over a group's query heads.
+    Two pallas calls: dQ accumulates over the sequential
     k-axis, dK/dV over the sequential q-axis.  Block sizes default to
     the autotune cache ("bwd" entry), then to
     ``autotune.DEFAULT_FLASH_BLOCKS["bwd"]``, fitted to T."""
+    _check_heads_and_window(q, k, v, causal, window)
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, G = k.shape[1:3]
     if scale is None:
         scale = 1.0 / float(np.sqrt(D))
     interpret = _interpret(q, k, v)
@@ -641,16 +730,105 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
     # major like lse so both ride the same (1, bq) blocks
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1).reshape(B * H, 1, Tq)
-    geometry = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
-                    sub_k=sub_k, interpret=interpret)
+    geometry = dict(scale=scale, causal=causal, window=int(window),
+                    block_q=bq, block_k=bk, sub_k=sub_k, interpret=interpret)
     dq_t = _flash_dq_call((qf, kf, _heads_major_t(k), vf, dof, lse, delta),
                           **geometry)
     dk, dv = _flash_dkv_call((qf, kf, vf, dof, lse, delta), **geometry)
 
-    def unflat(x, T):
-        return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    def unflat(x):
+        return x.reshape(B, G, Tk, D).transpose(0, 2, 1, 3)
 
-    return _from_heads_major_t(dq_t, B), unflat(dk, Tk), unflat(dv, Tk)
+    return _from_heads_major_t(dq_t, B), unflat(dk), unflat(dv)
+
+
+# ---------------------------------------------------------------------------
+# grouped matrix product (the held experts of parallel/moe.py)
+# ---------------------------------------------------------------------------
+#
+# ``x`` (m, k) holds the rows of ``g`` groups one after the other,
+# ``sizes[i]`` rows for group i, and each group has its own (k, n) matrix.
+# On the TPU this is jax's megablox kernel pair (``gmm`` for the product
+# and for the rows' gradient, ``tgmm`` for the matrices'): its grid runs
+# over the row tiles the groups really own (a dynamic bound), so time
+# follows ``sum(sizes)`` and not m.  ``jax.lax.ragged_dot`` lowers to a
+# kernel of XLA's own that is as fast (17 ms a step of
+# trinity-mini.train-b1x8k against a least time of 6.3; PERF.md section 6,
+# PR 33) but drops the ``jax.named_scope`` path of the call, so no trace
+# reader can tell whose time it is; a Pallas call keeps it.  Anywhere else
+# (the CPU tests) ``ragged_dot``'s reference lowering does the work.
+#
+# Rows past ``sum(sizes)`` belong to no group: no kernel writes them, and
+# what the result holds there is whatever the buffer held before.  The
+# caller masks them.
+
+_GMM_TILING = (512, 1024, 1024)     # rows, contracted, columns
+
+
+def _gmm_tiling(m):
+    tm, tk, tn = _GMM_TILING
+    while m % tm:
+        tm //= 2
+    return tm, tk, tn
+
+
+def _megablox():
+    # the package's ``gmm`` attribute is its custom_vjp function, which
+    # hides the module of the same name that holds gmm and tgmm
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _mxu_operands(dtype):
+    """Context in which a kernel that names no precision of its own is
+    traced: operands narrower than float32 go to the MXU as they are (the
+    package-wide "highest" would make Mosaic refuse them, see
+    :func:`_mxu_dot`)."""
+    if dtype == jnp.float32:
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision("default")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_tpu(x, w, sizes, out_dtype):
+    megablox = _megablox()
+    with _mxu_operands(x.dtype), jax.enable_x64(False):
+        return megablox.gmm(x, w, sizes, out_dtype, _gmm_tiling(x.shape[0]))
+
+
+def _gmm_tpu_fwd(x, w, sizes, out_dtype):
+    return _gmm_tpu(x, w, sizes, out_dtype), (x, w, sizes)
+
+
+def _gmm_tpu_bwd(out_dtype, saved, dy):
+    megablox = _megablox()
+    x, w, sizes = saved
+    tiling = _gmm_tiling(x.shape[0])
+    dy = dy.astype(x.dtype)
+    with _mxu_operands(x.dtype), jax.enable_x64(False):
+        dx = megablox.gmm(dy, w, sizes, x.dtype, tiling, transpose_rhs=True)
+        dw = megablox.tgmm(x.swapaxes(0, 1), dy, sizes, w.dtype, tiling)
+    return dx, dw, None
+
+
+_gmm_tpu.defvjp(_gmm_tpu_fwd, _gmm_tpu_bwd)
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array,
+                   out_dtype=None) -> jax.Array:
+    """``x[rows of group i] @ w[i]`` for x (m, k) sorted by group, w
+    (g, k, n) and ``sizes`` (g,) int32, accumulated in float32; (m, n) in
+    ``out_dtype`` (default x's).  Differentiable in x and w.  Rows past
+    ``sum(sizes)`` of the result, and of x's gradient, are NOT written:
+    mask them."""
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    if _interpret(x, w):
+        precision = None if x.dtype == jnp.float32 \
+            else jax.lax.Precision.DEFAULT
+        return jax.lax.ragged_dot(x, w, sizes, precision=precision,
+                                  preferred_element_type=out_dtype)
+    return _gmm_tpu(x, w, sizes.astype(jnp.int32), out_dtype)
 
 
 # ---------------------------------------------------------------------------

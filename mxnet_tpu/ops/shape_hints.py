@@ -67,6 +67,17 @@ def _layer_norm(attrs, shapes):
     return {1: (c,), 2: (c,)}
 
 
+def _rms_norm(attrs, shapes):
+    return {1: (shapes[0][attrs.get("axis", -1)],)}
+
+
+def _moe_ffn(attrs, shapes):
+    d, f = shapes[0][-1], attrs["num_hidden"]
+    e, held = attrs["num_experts"], attrs["experts_held"]
+    return {1: (d, e), 2: (d, f), 3: (d, f), 4: (f, d), 5: (held, d, f),
+            6: (held, d, f), 7: (held, f, d), 8: (e,), 9: (e,)}
+
+
 def _embedding(attrs, shapes):
     return {1: (attrs["input_dim"], attrs["output_dim"])}
 
@@ -120,6 +131,8 @@ def install():
     get_op("BatchNorm").infer_params = _bn
     get_op("InstanceNorm").infer_params = _in_norm
     get_op("LayerNorm").infer_params = _layer_norm
+    get_op("RMSNorm").infer_params = _rms_norm
+    get_op("_contrib_moe_ffn").infer_params = _moe_ffn
     get_op("Embedding").infer_params = _embedding
     get_op("_contrib_SparseEmbedding").infer_params = _embedding
     get_op("RNN").infer_params = _rnn

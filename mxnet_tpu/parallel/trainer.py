@@ -265,8 +265,12 @@ class ShardedTrainer:
         mom = tuple(jax.device_put(np.zeros(known[n].shape, np.float32),
                                    self.mom_sharding(n, known[n].shape))
                     for n in self.param_names)
+        # a moving variance starts at one; a moving mean, and an expert
+        # layer's selection bias and load (_contrib_moe_ffn), at zero
+        zero_aux = ("expert_bias", "expert_load")
         aux = tuple(jax.device_put(
-            (np.zeros if "mean" in n else np.ones)(known[n].shape, np.float32),
+            (np.zeros if "mean" in n or n.endswith(zero_aux)
+             else np.ones)(known[n].shape, np.float32),
             rep) for n in self.prog.aux_names)
         # memory plane: bucket the trainer's persistent state so live-HBM
         # accounting and OOM forensics can name it (one bool when off)

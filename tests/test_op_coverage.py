@@ -18,6 +18,16 @@ from mxnet_tpu.test_utils import check_numeric_gradient
 RS = np.random.RandomState(42)
 
 
+def _rotary_ref(x, theta):
+    """Rotary positions over (B, T, H, D): pairs (i, i + D/2)."""
+    T, D = x.shape[1], x.shape[-1]
+    half = D // 2
+    ang = np.arange(T)[:, None] * theta ** (-np.arange(half) / half)[None]
+    cos, sin = np.cos(ang)[None, :, None, :], np.sin(ang)[None, :, None, :]
+    a, b = x[..., :half], x[..., half:]
+    return np.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
 def _pos(*shape):
     return (RS.rand(*shape) * 0.8 + 0.2).astype(np.float32)
 
@@ -221,6 +231,12 @@ SPECS.update({
                    dict(fix_gamma=False), grad=True, train=True,
                    grad_nodes=["x0", "x1", "x2"]),
     "LayerNorm": S([_any(2, 5), _pos(5), _any(5)], grad=True),
+    "RMSNorm": S([_any(2, 5), _pos(5)], dict(eps=1e-5), grad=True,
+                 ref=lambda x, g, **kw: x / np.sqrt(
+                     (x * x).mean(-1, keepdims=True) + 1e-5) * g),
+    "_contrib_rotary_embedding": S(
+        [_any(1, 3, 2, 4)], dict(theta=100.0), grad=True,
+        ref=lambda x, **kw: _rotary_ref(x, 100.0)),
     "InstanceNorm": S([_any(2, 3, 4, 4), _pos(3), _any(3)], grad=True),
     "LRN": S([_any(1, 4, 3, 3)], dict(nsize=3), grad=True),
     "L2Normalization": S([_farz(2, 5)], grad=True),
@@ -450,6 +466,8 @@ KNOWN_ELSEWHERE = {
     "Custom": "tests/test_custom_op.py (frontend-defined ops)",
     "_contrib_fused_attention":
         "tests/test_transformer.py (naive parity + custom-vjp gradients)",
+    "_contrib_moe_ffn":
+        "benchmark/tests/test_afmoe.py (per-token loop, shares, aux state)",
 }
 
 

@@ -272,34 +272,47 @@ def test_flash_dead_block_copies_change_nothing(monkeypatch, flash_sub):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("window", [0, 8, 24, 5])
 @pytest.mark.parametrize("block_q,block_k,sub_k",
                          [(16, 16, 8), (8, 32, 8), (32, 8, 8),
                           (16, 64, 16), (64, 64, 32)])
-def test_live_sub_tiles_counts_match_the_mask(block_q, block_k, sub_k):
-    """``_live_sub_tiles`` against the causal mask itself: a sub-tile is
-    unmasked where every score is live, live where any is, and both sets
-    are prefixes of the block's sub-tiles."""
+def test_live_sub_tiles_counts_match_the_mask(block_q, block_k, sub_k,
+                                              window):
+    """``_live_sub_tiles`` against the mask itself (causal, and inside the
+    window where there is one): the sub-tiles it calls unmasked hold a live
+    score everywhere, every sub-tile that holds a live score lies in
+    ``[first, n_live)``, and with no window the unmasked and the live ones
+    are the prefixes they were."""
     from mxnet_tpu.ops.pallas_kernels import _live_sub_tiles
     T = 64
-    live = np.tril(np.ones((T, T), bool))           # [query, key]
+    gap = np.arange(T)[:, None] - np.arange(T)[None, :]    # [query, key]
+    live = (gap >= 0) & ((gap < window) if window else True)
     for qi in range(T // block_q):
         for ki in range(T // block_k):
             with jax.enable_x64(False):     # as the kernels trace it
                 got = _live_sub_tiles(jnp.int32(qi), jnp.int32(ki),
-                                      causal=True, block_q=block_q,
-                                      block_k=block_k, sub_k=sub_k)
+                                      causal=True, window=window,
+                                      block_q=block_q, block_k=block_k,
+                                      sub_k=sub_k)
+            first, full_from, full_to, n_live = (int(g) for g in got)
             rows = live[qi * block_q:(qi + 1) * block_q]
             tiles = [rows[:, ki * block_k + t * sub_k:
                           ki * block_k + (t + 1) * sub_k]
                      for t in range(block_k // sub_k)]
-            n_unmasked = sum(t.all() for t in tiles)
-            n_live = sum(t.any() for t in tiles)
-            assert (int(got[0]), int(got[1])) == (n_unmasked, n_live)
-            assert all(t.all() for t in tiles[:n_unmasked])
-            assert all(t.any() for t in tiles[:n_live])
-    assert _live_sub_tiles(0, 0, causal=False, block_q=block_q,
+            assert 0 <= first <= full_from <= full_to <= n_live <= len(tiles)
+            assert all(t.all() for t in tiles[full_from:full_to])
+            assert not any(t.any() for t in tiles[:first] + tiles[n_live:])
+            # no more is walked, and no more is masked, than has to be
+            assert all(t.any() for t in tiles[first:n_live])
+            assert not any(t.all() for t in tiles[first:full_from]
+                           + tiles[full_to:n_live]) or full_from == full_to
+            if not window:
+                assert (first, full_from) == (0, 0)
+                assert full_to == sum(t.all() for t in tiles)
+                assert n_live == sum(t.any() for t in tiles)
+    assert _live_sub_tiles(0, 0, causal=False, window=0, block_q=block_q,
                            block_k=block_k, sub_k=sub_k) \
-        == (block_k // sub_k,) * 2
+        == (0, 0) + (block_k // sub_k,) * 2
 
 
 def test_flash_fwd_lse_is_row_logsumexp():
