@@ -857,12 +857,44 @@ def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array,
 #   block in, the same block out under ``input_output_aliases`` — the
 #   pool is updated where it lies.
 # * ``decode_attn`` is ONE query token per slot against the pool: grid
-#   (slot, logical_page), each cell DMAs exactly one (H, rows, lanes)
-#   physical page of K and one of V — the pool never materializes
-#   per-sequence, so HBM traffic is O(tokens_cached · D), not
-#   O(slots · max_seq · D).  The online-softmax state (running max / sum
-#   / accumulator) is the same logsumexp machinery as the flash kernels
-#   above, carried across the sequential page axis in VMEM scratch.
+#   (slot,), and a cell walks THE SLOT'S LIVE PAGES, G at a time.  The
+#   pool stays in HBM (``memory_space=pl.ANY``); a group's pages — their
+#   ids read from the scalar-prefetched table, ``ceil(seq_len / page)`` of
+#   them and never a dead one — come in by the kernel's own asynchronous
+#   copies, into one of two buffers while the other is worked on, and a
+#   slot's last group starts the next slot's first, so the copies never
+#   stop between cells.  HBM traffic is O(tokens_cached · D), and so is
+#   the time: 72–76% of the byte roofline at the serve cell's contexts,
+#   86–91% with every context full (v5e, PR 34).
+#
+#   Inside a group nothing is reduced further than it must be.  The one
+#   cross-lane step is a token's score (the sum of k·q over its D lanes,
+#   on the XLU; put back on the token's own lanes); scores, weights and
+#   the accumulator keep the page's (H, rows, lanes) shape, so every row
+#   and every token of a row runs a softmax OF ITS OWN over the pages
+#   (running max / sum / accumulator in VMEM scratch), with one new
+#   maximum and one rescale per GROUP, and the rows * pack partial
+#   softmaxes are merged once, at the slot's end, the way split
+#   flash-decode merges its parts.
+#
+#   Why not a page a cell (the form before PR 34: grid (slot, page), one
+#   page by BlockSpec): measured on the v5e at the serve cell's geometry,
+#   a cell cost 0.10 us to visit whether its page was live or dead (three
+#   in four were dead: 2.5 of the step's 4.5 ms) and a live page 0.34 us
+#   more, against 0.12 us for its 98 KB at 819 GB/s: the page's scores
+#   went through (H, rows, 1) values, one lane in 128 of every register
+#   they touched, with a rescale of the accumulator per 16 tokens and the
+#   pipeline's prologue per cell.
+#
+#   G (:func:`_decode_pages_per_cell`) comes from the shapes: what fits a
+#   VMEM budget, at most ``_DECODE_CELL_TOKENS`` tokens.  More pages a
+#   group keep more bytes in flight (full contexts: 3.41 ms a step at
+#   G = 8, 3.23 at 16) but a slot's last group works on its dead pages
+#   too, masked (the cell's contexts: 1.13 / 1.00 / 1.05 ms at G = 4 / 8 /
+#   16; one token a slot: 0.29 / 0.41 / 0.65).  Looping over the live
+#   pages alone instead of unrolling the group lost a third (1.35 ms): the
+#   XLU's latency then shows on every page.  The same lane sum as a
+#   float32 product with a 0/1 matrix on the idle MXU: 1.41 ms.
 #
 # The layer rides as a scalar-prefetch value, not as a Python constant:
 # the calls of every layer then share one Mosaic body.  A 4-D
@@ -887,73 +919,167 @@ def _lane_groups(shape, D, pack):
     return [(lane >= g * D) & (lane < (g + 1) * D) for g in range(pack)]
 
 
-def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                        o_ref, acc_ref, m_ref, l_ref, *, page, D, n_pages,
-                        scale):
-    """One (slot, logical page) cell.  Every value keeps the
-    (H, rows|1, lanes|1) rank of the page block: a one-token query against
-    a page is a matrix-VECTOR product per head, which Mosaic's matmul does
-    not take (it refused the batched ``(H,page,D)·(H,D)`` dot_general:
-    "failed to parse TPU_DotDimensionNumbersAttr"), so the scores and the
-    weighted sum are broadcast-multiplies reduced over lanes / sublanes
-    on the VPU — decode attention is bandwidth-bound either way.  The
-    query arrives repeated once per token of a row; each token's score is
-    the sum over its own lanes."""
-    del layer_ref                   # the index maps' business
+# What a cell of decode_attn may hold in VMEM: K and V of G pages, twice
+# (the next group's copies land while this one is worked on) in the
+# pool's dtype, and the group's scores once in float32.  A fifth of the
+# 16 MiB a Mosaic kernel gets by default; the rest is the compiler's own
+# (the spills of the unrolled group, the q and output blocks).
+_DECODE_VMEM_BUDGET = 3 << 20
+# and no more tokens than this to a group: the serve cell's best (above)
+_DECODE_CELL_TOKENS = 128
+
+
+def _decode_pages_per_cell(H, rows, lanes, D, itemsize, n_pages):
+    """G, the pages a ``decode_attn`` cell takes at a time: what fits
+    ``_DECODE_VMEM_BUDGET``, at most ``_DECODE_CELL_TOKENS`` tokens and at
+    most the slot's whole table, at least one.  A function of the shapes
+    the kernel sees and nothing else: 8 at GPT-2 small (H = 12, D = 64,
+    page 16, float32: a 49 KB page), 2 at H = 32, D = 128 (262 KB)."""
+    page_elems = H * rows * lanes
+    per_page = 4 * page_elems * itemsize + 4 * page_elems
+    tokens = rows * (lanes // D)
+    return max(1, min(_DECODE_VMEM_BUDGET // per_page,
+                      _DECODE_CELL_TOKENS // tokens, n_pages))
+
+
+def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                        o_ref, k_buf, v_buf, s_buf, m_ref, l_ref, acc_ref,
+                        sems, buf_ref, *, G, D, v_at, scale, n_pages):
+    """One slot: its live pages, G at a time (the block above has why).
+
+    ``k_hbm`` / ``v_hbm`` are the whole pool(s), in HBM; ``k_buf`` /
+    ``v_buf`` (2, G, H, rows, lanes) the two landing buffers, ``sems``
+    (buffer, K|V) their copies' semaphores, ``buf_ref`` the buffer this
+    slot's first group was sent to by the slot before.  ``s_buf`` keeps a
+    group's scores between the pass that finds the group's maximum and
+    the pass that weighs V.  Every value keeps the (H, rows, lanes) shape
+    of a page: a one-token query against a page is a matrix-VECTOR
+    product per head, which Mosaic's matmul does not take (it refused the
+    batched ``(H,page,D)·(H,D)`` dot_general: "failed to parse
+    TPU_DotDimensionNumbersAttr"), so the scores and the weighted sum are
+    broadcast-multiplies on the VPU.  The query arrives repeated once per
+    token of a row; each token's score is the sum over its own lanes."""
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    H, rows, lanes = k_ref.shape
+    S = pl.num_programs(0)
+    _, _, H, rows, lanes = k_buf.shape
     pack = lanes // D
+    page = rows * pack
+    layer = layer_ref[0]
+    shape = (H, rows, lanes)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, jnp.float32(_NEG_BIG))
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def live_pages(slot):
+        # at least one, so that an inactive slot's cell has a page (its
+        # table's first entry, the trash page) and writes finite numbers.
+        # lax.div: the operands are never negative, and the scalar core
+        # pays for every sign it has to think about
+        return jnp.maximum(jax.lax.div(len_ref[slot] + (page - 1), page), 1)
 
-    # a page with no valid token (beyond this slot's cached length) is
-    # skipped entirely — the DMA still happened (the index map runs for
-    # every grid cell; unused table entries point at the trash page) but
-    # no FLOPs or state updates are spent on it
-    live = j * page < len_ref[s]
+    def live_copies(slot, g, b, act):
+        """``act`` (start or wait) on the K and the V copy of every live
+        page of group ``g`` of ``slot`` into buffer ``b``: the same
+        descriptors to start as to wait."""
+        n = live_pages(slot)
+        for i in range(G):
+            j = g * G + i
+            # a dead page's copy is never made, but its table entry is
+            # read: keep the index inside the table's last group
+            at = j if n_pages % G == 0 else jnp.minimum(j, n_pages - 1)
+            phys = pt_ref[slot, at]
+            ck = pltpu.make_async_copy(k_hbm.at[layer, 0, phys],
+                                       k_buf.at[b, i], sems.at[b, 0])
+            cv = pltpu.make_async_copy(v_hbm.at[layer, v_at, phys],
+                                       v_buf.at[b, i], sems.at[b, 1])
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[...].astype(jnp.float32)          # (H, 1, lanes)
-        k = k_ref[...].astype(jnp.float32)          # (H, rows, lanes)
-        v = v_ref[...].astype(jnp.float32)
-        kq = k * q
-        groups = _lane_groups(kq.shape, D, pack)
-        row = jax.lax.broadcasted_iota(jnp.int32, (H, rows, 1), 1)
-        scores = []                                 # per token of a row
-        for g, mine in enumerate(groups):
+            @pl.when(j < n)
+            def _():
+                act(ck)
+                act(cv)
+
+    def start(slot, g, b):
+        live_copies(slot, g, b, lambda copy: copy.start())
+
+    @pl.when(s == 0)
+    def _first():
+        # a dead page of a group keeps what its buffer held: weight 0
+        # times that must be 0, so it must never be what VMEM woke up with
+        v_buf[...] = jnp.zeros_like(v_buf)
+        buf_ref[0] = 0
+        start(0, 0, 0)
+
+    m_ref[...] = jnp.full_like(m_ref, jnp.float32(_NEG_BIG))
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    n_live = live_pages(s)
+    n_groups = jax.lax.div(n_live + (G - 1), G)
+    b0 = buf_ref[0]
+    q = q_ref[...].astype(jnp.float32)              # (H, 1, lanes)
+    # the token of its page that a lane belongs to, and the lanes of each
+    # of a row's tokens
+    tok = (jax.lax.broadcasted_iota(jnp.int32, shape, 1) * pack
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 2) // D)
+    groups = _lane_groups(shape, D, pack)
+
+    def scores(b, i, left):
+        """Page ``i`` of buffer ``b`` against the query: every lane of a
+        token holds that token's score, a token past the slot's length
+        the floor.  ``left``: the slot's tokens from this page on."""
+        kq = k_buf[b, i].astype(jnp.float32) * q
+        sc = None
+        for mine in groups:
             s_g = jnp.sum(kq if mine is None else jnp.where(mine, kq, 0.0),
-                          axis=-1, keepdims=True) * scale  # (H, rows, 1)
-            pos = j * page + row * pack + g
-            scores.append(jnp.where(pos < len_ref[s], s_g,
-                                    jnp.float32(_NEG_BIG)))
-        m_prev = m_ref[:]                           # (H, 1, 1)
-        m_new = m_prev
-        for s_g in scores:
-            m_new = jnp.maximum(m_new, jnp.max(s_g, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:] * corr
-        p = 0.0                     # (H, rows, lanes): a token's weight
-        for s_g, mine in zip(scores, groups):       # on its own lanes
-            p_g = jnp.exp(s_g - m_new)              # (H, rows, 1)
-            l_new = l_new + jnp.sum(p_g, axis=1, keepdims=True)
-            p = p_g if mine is None else jnp.where(mine, p_g, p)
-        l_ref[:] = l_new
-        m_ref[:] = m_new
-        acc_ref[:] = acc_ref[:] * corr + jnp.sum(p * v, axis=1,
-                                                 keepdims=True)  # (H,1,lanes)
+                          axis=-1, keepdims=True)           # (H, rows, 1)
+            sc = (jnp.broadcast_to(s_g, shape) if sc is None
+                  else jnp.where(mine, s_g, sc))
+        return jnp.where(tok < left, sc * scale, jnp.float32(_NEG_BIG))
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        # each token of a row has summed onto its own lanes; the caller
-        # folds them (a (S, H, lanes) add, not worth a lane shuffle here)
-        o_ref[...] = (acc_ref[:] / jnp.maximum(l_ref[:], jnp.float32(1e-37))
-                      ).astype(o_ref.dtype)
+    def group(g, carry):
+        b = (b0 + g) & 1
+        last = g + 1 == n_groups
+        nxt = jnp.where(last, s + 1, s)
+
+        @pl.when(nxt < S)
+        def _prefetch():
+            start(jnp.minimum(nxt, S - 1), jnp.where(last, 0, g + 1), 1 - b)
+
+        live_copies(s, g, b, lambda copy: copy.wait())
+
+        # the scores of the whole group, then ONE new maximum for it
+        left = len_ref[s] - g * (G * page)          # tokens from here on
+        m_old = m_ref[...]
+        m_new = m_old
+        for i in range(G):
+            sc = scores(b, i, left - i * page)
+            s_buf[i] = sc
+            m_new = jnp.maximum(m_new, sc)
+        corr = jnp.exp(m_old - m_new)
+        l_new = l_ref[...] * corr
+        acc = acc_ref[...] * corr
+        for i in range(G):
+            p = jnp.exp(s_buf[i] - m_new)           # a token's weight, on
+            l_new = l_new + p                       # its own lanes
+            acc = acc + p * v_buf[b, i].astype(jnp.float32)
+        m_ref[...] = m_new
+        l_ref[...] = l_new
+        acc_ref[...] = acc
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    buf_ref[0] = (b0 + n_groups) & 1
+
+    # the rows and the tokens of a row each kept a softmax of their own:
+    # merge them as split flash-decode merges its parts
+    m = m_ref[...]
+    m_all = jnp.max(jnp.max(m, axis=2, keepdims=True), axis=1, keepdims=True)
+    w = jnp.exp(m - m_all)                          # (H, rows, lanes)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    l_all = jnp.sum(jnp.sum(jnp.where(lane % D == 0, l_ref[...] * w, 0.0),
+                            axis=2, keepdims=True), axis=1, keepdims=True)
+    # each token of a row has summed onto its own lanes; the caller folds
+    # them (a (S, H, lanes) add, not worth a lane shuffle here)
+    o_ref[...] = (jnp.sum(acc_ref[...] * w, axis=1, keepdims=True)
+                  / jnp.maximum(l_all, jnp.float32(1e-37))
+                  ).astype(o_ref.dtype)
 
 
 def _layer_operand(layer):
@@ -963,39 +1089,49 @@ def _layer_operand(layer):
 def _decode_attn_pallas(q, k_pool, v_pool, layer, v_at, page_table,
                         seq_lens, scale, interpret):
     """``k_pool`` / ``v_pool``: six-axis ``(L, 2|1, P, H, rows, lanes)``
-    operands (the same array twice in the serving step); K is read at
-    ``[layer, 0]``, V at ``[layer, v_at]``."""
+    operands (the same array twice in the serving step), left where they
+    lie; K is read at ``[layer, 0]``, V at ``[layer, v_at]``."""
+    H = q.shape[1]
+    rows, lanes = k_pool.shape[4:]
+    G = _decode_pages_per_cell(H, rows, lanes, q.shape[2],
+                               k_pool.dtype.itemsize, page_table.shape[1])
+    return _decode_attn_call(q, k_pool, v_pool, layer, page_table, seq_lens,
+                             G=G, v_at=v_at, scale=scale,
+                             interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("G", "v_at", "scale", "interpret"))
+def _decode_attn_call(q, k_pool, v_pool, layer, page_table, seq_lens, *, G,
+                      v_at, scale, interpret):
+    """The kernel's call.  Under ``jax.jit`` for the trace's sake, not the
+    call's: the layer is an operand, so every layer of a step is the same
+    jitted call and the kernel's unrolled body is traced and lowered once
+    a step, not once a layer (two seconds of set-up at twelve layers);
+    XLA inlines the calls."""
     S, H, D = q.shape
     rows, lanes = k_pool.shape[4:]
     pack = lanes // D
-    n_pages = page_table.shape[1]
-    kern = functools.partial(_decode_attn_kernel, page=rows * pack, D=D,
-                             n_pages=n_pages, scale=scale)
-
-    def page_block(at):
-        # leading three dims squeezed: the DMA per cell is one
-        # (H, rows, lanes) page, whose trailing two dims equal the array's
-        return pl.BlockSpec(
-            (None, None, None, H, rows, lanes),
-            lambda s, j, pt, ln, lyr: (lyr[0], at, pt[s, j], 0, 0, 0))
-
+    kern = functools.partial(_decode_attn_kernel, G=G, D=D, v_at=v_at,
+                             scale=scale, n_pages=page_table.shape[1])
     # q and the output ride as (S, H, 1, ·): the block's trailing two dims
     # then equal the array's, and the kernel never reshapes
+    row_block = pl.BlockSpec((None, H, 1, lanes),
+                             lambda s, pt, ln, lyr: (s, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    state = pltpu.VMEM((H, rows, lanes), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, n_pages),
-        in_specs=[
-            pl.BlockSpec((None, H, 1, lanes),
-                         lambda s, j, pt, ln, lyr: (s, 0, 0, 0)),
-            page_block(0),
-            page_block(v_at),
-        ],
-        out_specs=pl.BlockSpec((None, H, 1, lanes),
-                               lambda s, j, pt, ln, lyr: (s, 0, 0, 0)),
+        grid=(S,),
+        in_specs=[row_block, in_hbm, in_hbm],
+        out_specs=row_block,
         scratch_shapes=[
-            pltpu.VMEM((H, 1, lanes), jnp.float32),  # acc
-            pltpu.VMEM((H, 1, 1), jnp.float32),      # running max
-            pltpu.VMEM((H, 1, 1), jnp.float32),      # running sum
+            pltpu.VMEM((2, G, H, rows, lanes), k_pool.dtype),
+            pltpu.VMEM((2, G, H, rows, lanes), v_pool.dtype),
+            pltpu.VMEM((G, H, rows, lanes), jnp.float32),   # scores
+            state, state, state,            # running max, sum, accumulator
+            pltpu.SemaphoreType.DMA((2, 2)),                # buffer, K|V
+            pltpu.SMEM((1,), jnp.int32),    # the buffer the slot starts in
         ],
     )
     q4 = jnp.tile(q.reshape(S, H, 1, D), (1, 1, 1, pack))
@@ -1004,6 +1140,9 @@ def _decode_attn_pallas(q, k_pool, v_pool, layer, v_at, page_table,
             kern, grid_spec=grid_spec,
             out_shape=_out_struct((S, H, 1, lanes), q.dtype, q4, k_pool,
                                   v_pool),
+            # a slot's first copies are started by the slot before it
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=interpret, name="decode_attn",
         )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
           _layer_operand(layer), q4, k_pool, v_pool)

@@ -765,16 +765,17 @@ class _Slot:
 class _InFlight:
     """One dispatched step whose tokens the host has not taken in yet."""
 
-    __slots__ = ("seq", "takers", "attended", "overlapped", "next_tok",
-                 "guards", "t_dispatch")
+    __slots__ = ("seq", "takers", "attended", "pages", "overlapped",
+                 "next_tok", "guards", "t_dispatch")
 
-    def __init__(self, seq, takers, attended, overlapped):
+    def __init__(self, seq, takers, attended, pages, overlapped):
         self.seq = seq
         # (slot index, request, takes a token?, its last by length?) of
         # every slot the step ran for: the REQUEST, because by the time
         # the tokens arrive the slot may be somebody else's
         self.takers = takers
-        self.attended = attended
+        self.attended = attended        # sum of the step's seq_lens
+        self.pages = pages              # and of the pages they lie on
         self.overlapped = overlapped    # dispatched behind another step
         self.next_tok = None            # the step's out[0], as handed back
         self.guards = None              # watchdog watch + OOM guard, open
@@ -1093,6 +1094,7 @@ class DecodeEngine(ServingRuntime):
                     self._release_slot(i)
             n_decode = sum(t[2] for t in takers)
             new = _InFlight(seq, takers, int(seq_lens.sum()),
+                            int((-(-seq_lens // c.page_size)).sum()),
                             flight is not None)
         try:
             # the span is the outermost, so that arming the watchdog and
@@ -1235,6 +1237,7 @@ class DecodeEngine(ServingRuntime):
             self._counters["tokens_prefilled"] += n_prefill
             self._counters["tokens_decoded"] += n_decode
             self._counters["contexts_attended"] += flight.attended
+            self._counters["pages_attended"] += flight.pages
         # counted first, delivered second: a caller that has its answer
         # finds its tokens in the counts
         for i, req in ended:
@@ -1324,6 +1327,9 @@ class DecodeEngine(ServingRuntime):
             # running sum of the steps' seq_lens: the contexts the
             # attention read, for bytes-per-step and pool-residency maths
             "contexts_attended": counters.get("contexts_attended", 0),
+            # and of the pages they lie on: over steps x max_seqs x
+            # pages_per_seq, the share of the page table decode_attn walks
+            "pages_attended": counters.get("pages_attended", 0),
             # steps dispatched while another was in flight: over "steps",
             # how often the loop hid the host behind the device
             "steps_overlapped": counters.get("steps_overlapped", 0),

@@ -93,14 +93,19 @@ def test_quantize_weight_and_quant_matmul():
         assert rel < tol, (bits, rel)
 
 
-def test_decode_attention_paged_matches_reference():
+@pytest.mark.parametrize("lens", [
+    [5, 12, 0],         # partial page, full, inactive
+    [3, 4, 5],          # one before, on and one after a page boundary
+    [8, 9, 1],          # the same a page on, and a single token
+], ids=["partial_full_inactive", "page_edges", "second_page_edges"])
+def test_decode_attention_paged_matches_reference(lens):
     rs = np.random.RandomState(0)
     S, nH, D, page, MP, P = 3, 2, 8, 4, 3, 10
     q = rs.randn(S, nH, D).astype(np.float32)
     kp = rs.randn(P, nH, page, D).astype(np.float32)
     vp = rs.randn(P, nH, page, D).astype(np.float32)
     pt = rs.randint(0, P, (S, MP)).astype(np.int32)
-    lens = np.array([5, 12, 0], np.int32)   # partial page, full, inactive
+    lens = np.array(lens, np.int32)
 
     ref = np.zeros((S, nH, D), np.float32)
     for s in range(S):
@@ -119,7 +124,8 @@ def test_decode_attention_paged_matches_reference():
     for use_pallas in (False, True):
         out = np.asarray(pk.decode_attention(q, kp, vp, pt, lens,
                                              use_pallas=use_pallas))
-        assert np.abs(out[:2] - ref[:2]).max() < 1e-5, use_pallas
+        live = lens > 0
+        assert np.abs(out[live] - ref[live]).max() < 1e-5, use_pallas
         assert np.isfinite(out).all()    # inactive slot: garbage but finite
 
 

@@ -58,6 +58,19 @@ def test_kv_pack():
     assert pk.kv_pack(16, 96) == 1 and pk.kv_pack(3, 64) == 1
 
 
+def _both_entries(q, kv, layer, pt, lens):
+    """The six-axis entry's result, after holding the public 4-D entry to
+    the same numbers (one body, two ways in)."""
+    out = np.asarray(pk.decode_attention_pool(q, _dense(kv), layer, pt,
+                                              lens))
+    out4 = np.asarray(pk.decode_attention(q, kv[layer, 0], kv[layer, 1],
+                                          pt, lens, use_pallas=True))
+    live = lens > 0
+    assert np.array_equal(out[live], out4[live])
+    assert np.isfinite(out).all() and np.isfinite(out4).all()
+    return out
+
+
 @pytest.mark.parametrize("D,page", GEOMETRIES)
 @pytest.mark.parametrize("layer", range(LAYERS))
 def test_decode_attention_takes_the_whole_pool(layer, D, page):
@@ -69,14 +82,108 @@ def test_decode_attention_takes_the_whole_pool(layer, D, page):
     # a partial page, every page full, an inactive slot
     lens = np.array([page + 1, MP * page, 0], np.int32)
     ref = _attention_reference(q, kv[layer, 0], kv[layer, 1], pt, lens)
-    out = np.asarray(pk.decode_attention_pool(q, _dense(kv), layer, pt,
-                                              lens))
+    out = _both_entries(q, kv, layer, pt, lens)
     assert np.abs(out[:2] - ref[:2]).max() < 1e-5
-    assert np.isfinite(out).all()    # inactive slot: garbage but finite
-    # the same body through the public 4-D way in
-    out4 = np.asarray(pk.decode_attention(q, kv[layer, 0], kv[layer, 1],
-                                          pt, lens, use_pallas=True))
-    assert np.array_equal(out[:2], out4[:2])
+
+
+def _scattered_table(rs, lens, page, MP, P):
+    """Live pages drawn without order from pages 1..P-1, every dead entry
+    on trash page 0."""
+    pt = np.zeros((len(lens), MP), np.int32)
+    free = 1 + rs.permutation(P - 1)
+    at = 0
+    for s, tl in enumerate(lens):
+        n = -(-int(tl) // page)
+        pt[s, :n] = free[at:at + n]
+        at += n
+    return pt
+
+
+# a cell takes G pages at a time: 3 here, of a table of 8 (not a multiple)
+GROUP, TABLE = 3, 8
+
+
+@pytest.mark.parametrize("D,page", GEOMETRIES)
+@pytest.mark.parametrize("case", ["page_edges", "group_edges",
+                                  "full_one_none"])
+def test_decode_attention_walks_live_pages_in_groups(case, D, page,
+                                                     monkeypatch):
+    monkeypatch.setattr(pk, "_DECODE_CELL_TOKENS", GROUP * page)
+    nH, MP = 2, TABLE
+    rows, lanes = _dense(np.zeros((page, D))).shape
+    assert pk._decode_pages_per_cell(nH, rows, lanes, D, 4, MP) == GROUP
+    lens = {
+        # on, one before and one after a page boundary
+        "page_edges": [page, page - 1, page + 1, 2 * page, 2 * page + 1],
+        # the same round a group boundary, and round the table's last,
+        # shorter group
+        "group_edges": [GROUP * page, GROUP * page - 1, GROUP * page + 1,
+                        2 * GROUP * page, 2 * GROUP * page + 1],
+        # the full context beside one token beside an inactive slot
+        "full_one_none": [MP * page, 1, 0, MP * page - 1, 0],
+    }[case]
+    lens = np.array(lens, np.int32)
+    S, P = len(lens), 1 + len(lens) * MP
+    rs = np.random.RandomState(D + page)
+    q = rs.randn(S, nH, D).astype(np.float32)
+    kv = rs.randn(LAYERS, 2, P, nH, page, D).astype(np.float32)
+    kv[:, :, 0] = 1e3           # the trash page: loud if it is attended
+    pt = _scattered_table(rs, lens, page, MP, P)
+    ref = _attention_reference(q, kv[1, 0], kv[1, 1], pt, lens)
+    out = _both_entries(q, kv, 1, pt, lens)
+    live = lens > 0
+    assert np.abs(out[live] - ref[live]).max() < 1e-5
+    # the live slots do not feel their neighbours: alone they read the same
+    alone = np.asarray(pk.decode_attention_pool(
+        q[:1], _dense(kv), 1, pt[:1], lens[:1]))
+    assert np.abs(alone[0] - out[0]).max() < 1e-6
+
+
+def test_decode_attention_one_group_holds_the_whole_table():
+    """With the rule's own numbers these small tables are one group, and
+    a table shorter than a group clamps it."""
+    rs = np.random.RandomState(4)
+    D, page, nH, MP, P = 64, 4, 3, 5, 12
+    lens = np.array([MP * page, 7, 0, 1], np.int32)
+    rows, lanes = _dense(np.zeros((page, D))).shape
+    assert pk._decode_pages_per_cell(nH, rows, lanes, D, 4, MP) == MP
+    q = rs.randn(len(lens), nH, D).astype(np.float32)
+    kv = rs.randn(LAYERS, 2, P, nH, page, D).astype(np.float32)
+    pt = _scattered_table(rs, lens, page, MP, P)
+    ref = _attention_reference(q, kv[2, 0], kv[2, 1], pt, lens)
+    out = _both_entries(q, kv, 2, pt, lens)
+    assert np.abs(out - ref)[lens > 0].max() < 1e-5
+
+
+@pytest.mark.parametrize("name,H,rows,lanes,D,itemsize,n_pages", [
+    ("gpt2_small", 12, 8, 128, 64, 4, 64),      # a 49 KB page, 16 tokens
+    ("page_262KB", 32, 16, 128, 128, 4, 64),    # H = 32, D = 128
+    ("bf16_pool", 12, 8, 128, 64, 2, 64),
+    ("short_table", 12, 8, 128, 64, 4, 3),
+    ("huge_page", 64, 64, 128, 128, 4, 64),     # one page over the budget
+])
+def test_pages_per_cell_follows_the_rule(name, H, rows, lanes, D, itemsize,
+                                         n_pages):
+    """G is the most pages that fit the VMEM budget (K and V twice in the
+    pool's dtype, the scores once in float32), the token cap and the
+    table; one at least; shapes alone decide it."""
+    G = pk._decode_pages_per_cell(H, rows, lanes, D, itemsize, n_pages)
+    tokens = rows * (lanes // D)
+
+    def held(g):
+        return g * H * rows * lanes * (4 * itemsize + 4)
+
+    def fits(g):
+        return (held(g) <= pk._DECODE_VMEM_BUDGET
+                and g * tokens <= pk._DECODE_CELL_TOKENS and g <= n_pages)
+
+    assert 1 <= G <= n_pages
+    assert fits(G) or G == 1
+    assert not fits(G + 1)
+    # what the chip's sweep chose (PERF.md, PR 34), and what the budget
+    # leaves of it for a page five times the size
+    assert G == {"gpt2_small": 8, "page_262KB": 2, "short_table": 3,
+                 "huge_page": 1}.get(name, G)
 
 
 # -- (b) kv_write ----------------------------------------------------------
@@ -146,7 +253,10 @@ def _pool_touches(jaxpr, page_shape, min_size, inside=""):
     found = []
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
-        if any(pool_sized(x) for x in list(eqn.invars) + list(eqn.outvars)):
+        # a jitted call (decode_attn's, one trace for all layers) hands
+        # its operands through: what counts is what it holds
+        if name != "jit" and any(
+                pool_sized(x) for x in list(eqn.invars) + list(eqn.outvars)):
             found.append((name, inside))
         if name == "pallas_call":
             continue
@@ -157,6 +267,17 @@ def _pool_touches(jaxpr, page_shape, min_size, inside=""):
                     found += _pool_touches(sub, page_shape, min_size,
                                            inside + "/" + name)
     return found
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation, inside jitted calls too."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append(eqn)
+        elif eqn.primitive.name == "jit":
+            calls += _pallas_calls(eqn.params["jaxpr"].jaxpr)
+    return calls
 
 
 def test_step_touches_the_pool_only_in_pallas_calls(cfg_and_params,
@@ -174,9 +295,7 @@ def test_step_touches_the_pool_only_in_pallas_calls(cfg_and_params,
     assert {name for name, _ in touches} == {"pallas_call"}, touches
     # a write and a read a layer, each on the whole pool
     assert len(touches) == 2 * cfg.num_layers
-    calls = [e for e in closed.jaxpr.eqns
-             if e.primitive.name == "pallas_call"]
-    assert sorted({e.params["name"] for e in calls}) \
+    assert sorted({e.params["name"] for e in _pallas_calls(closed.jaxpr)}) \
         == ["decode_attn", "kv_write"]
     # GC307 still knows it for a decode step (the aliased write)
     from mxnet_tpu.analysis.graphcheck import is_decode_shaped
@@ -242,21 +361,23 @@ def v5e_chip():
     return topo.devices[0]
 
 
-def test_64_slot_step_compiles_for_the_v5e(v5e_chip, monkeypatch):
-    """GPT-2 small at 64 slots, context 1024, page 16.  Before PR 30 the
-    TPU compiler refused this step ("Used 18.26G of 15.75G hbm": a padded
-    copy of the pool re-laid for the scatter).  Now the plan is the
-    arguments: nothing pool-sized but the pool, and the pool lane-dense."""
+@pytest.mark.parametrize("S", [64, 32])
+def test_64_slot_step_compiles_for_the_v5e(v5e_chip, monkeypatch, S):
+    """GPT-2 small at 64 slots (and at the serve cell's 32), context 1024,
+    page 16.  Before PR 30 the TPU compiler refused this step ("Used
+    18.26G of 15.75G hbm": a padded copy of the pool re-laid for the
+    scatter).  Now the plan is the arguments: nothing pool-sized but the
+    pool, and the pool lane-dense; ``decode_attn`` leaves it in HBM and
+    copies a slot's live pages out of it, so it brings no temporary."""
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
     monkeypatch.setenv("MXNET_TPU_PALLAS_DECODE", "1")
     monkeypatch.setattr(pk, "_interpret", lambda *a: False)   # Mosaic
-    S = 64
     cfg = DecodeConfig(50257, 12, 768, 12, 1024, page_size=16, max_seqs=S)
     # the program object needs host arrays to exist; zeros do for a compile
     weights = {k: np.zeros(shape, np.float32)
                for k, shape in decode_param_shapes(cfg).items()}
-    prog = DecodeProgram(weights, cfg, name="aot64")
+    prog = DecodeProgram(weights, cfg, name="aot%d" % S)
     on = SingleDeviceSharding(v5e_chip)
 
     def sds(shape, dtype=jnp.int32):
@@ -272,8 +393,8 @@ def test_64_slot_step_compiles_for_the_v5e(v5e_chip, monkeypatch):
     ma = compiled.memory_analysis()
     planned = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
-    print("64-slot decode step: planned %.2f GB, temporaries %.3f GB, pool "
-          "%.2f GB of data" % (planned / 1e9, ma.temp_size_in_bytes / 1e9,
+    print("%d-slot decode step: planned %.2f GB, temporaries %.3f GB, pool "
+          "%.2f GB of data" % (S, planned / 1e9, ma.temp_size_in_bytes / 1e9,
                                4 * np.prod(pool) / 1e9))
     # every instruction with a pool-sized result is the entry parameter or
     # one of the kernels that write it in place
@@ -285,7 +406,9 @@ def test_64_slot_step_compiles_for_the_v5e(v5e_chip, monkeypatch):
                         r'"tpu_custom_call"', text)
     for kernel in ("kv_write", "decode_attn"):
         assert sum(kernel in c for c in mosaic) == cfg.num_layers, mosaic
+    assert len(mosaic) == 2 * cfg.num_layers, mosaic    # and no third
     assert ma.temp_size_in_bytes < 0.5e9
-    # the pool arrives row-major and unpadded: 4.83 GB, all of it data
+    # the pool arrives row-major and unpadded: 4.83 GB at 64 slots, all of
+    # it data
     assert ma.alias_size_in_bytes == 4 * np.prod(pool)
     assert planned < 8e9

@@ -6,6 +6,7 @@ consumer and the tripping program, rendered by tools/memwatch.py),
 the leak watchdog, digest/fleet memory columns, the checkpoint-restore
 double-residency fix, and the disarmed zero-cost gate.
 """
+import gc
 import glob
 import json
 import os
@@ -225,6 +226,10 @@ def test_ring_memory_predicted_vs_compiled_within_20pct():
 
 def test_oom_drill_postmortem_and_memwatch_report(tmp_path, monkeypatch):
     monkeypatch.setenv("MXNET_TPU_WATCHDOG_DIR", str(tmp_path))
+    # the report's table holds the LARGEST live buffers: what an earlier
+    # test of this worker left for the collector (a GPT-2-sized program's
+    # weights, in a cycle) would crowd this trainer's out of it
+    gc.collect()
     telemetry.arm()
     trainer, (params, mom, aux), shapes = _toy_trainer(hidden=512)
     batch = {"data": np.random.rand(8, 32).astype(np.float32),

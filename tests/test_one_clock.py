@@ -157,6 +157,11 @@ def test_engine_emits_its_six_spans_once_a_step(toy, recorder):
     attended = sum(f * (f + 1) // 2 for f in fed)
     assert stats["decode"]["contexts_attended"] == attended
     assert stats["counters"]["contexts_attended"] == attended
+    # and the pages those contexts lie on: ceil(context / page) each step
+    pages = sum(-(-ctx // cfg.page_size) for f in fed
+                for ctx in range(1, f + 1))
+    assert stats["decode"]["pages_attended"] == pages
+    assert stats["counters"]["pages_attended"] == pages
     steps = stats["counters"]["steps"]
     assert steps >= max(fed)
 
