@@ -408,6 +408,127 @@ def phase_decode(compiles, devices):
                sum("decode_attn" in k for k in kernels), place, programs))
 
 
+LATENT_MODEL = dict(
+    hidden_size=512, num_attention_heads=8, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, kv_lora_rank=512, v_head_dim=128,
+    intermediate_size=1024, moe_intermediate_size=256, num_experts=4,
+    num_experts_per_tok=2, num_shared_experts=1, first_k_dense_replace=1,
+    routed_scaling_factor=2.5, rms_norm_eps=1e-6, rope_theta=10000,
+    rope_scaling=dict(type="deepseek_yarn", factor=40, beta_fast=32,
+                      beta_slow=1, mscale=1, mscale_all_dim=1,
+                      original_max_position_embeddings=4096),
+    router_width=8, first_expert=0)
+LATENT_LAYERS, LATENT_VOCAB, LATENT_SEQ = 3, 2048, 1024
+
+
+def latent_program(dtype, name):
+    from mxnet_tpu.serving.decode import (DecodeConfig, LatentDecodeProgram,
+                                          init_decode_params)
+    m = LATENT_MODEL
+    cfg = DecodeConfig(LATENT_VOCAB, LATENT_LAYERS, m["hidden_size"],
+                       m["num_attention_heads"], LATENT_SEQ, page_size=64,
+                       max_seqs=8, family="sarvam_mla", dtype=dtype,
+                       prefill_tokens_per_step=64, model=m)
+    return LatentDecodeProgram(init_decode_params(cfg, seed=0, scale=0.05),
+                               cfg, name=name)
+
+
+def one_mixed_step(prog):
+    """Slot 0 decodes at position 99 over a cache of that step's own making,
+    slot 1 takes 40 prompt rows from position 70: (logits, layer 0 of the
+    pool) after a 100-row fill and that step."""
+    c = prog.config
+    S, page, R = c.max_seqs, c.page_size, prog.rows
+    table = np.zeros((S, c.pages_per_seq), np.int32)
+    table[0], table[1] = 1 + np.arange(16), 17 + np.arange(16)
+    rs = np.random.RandomState(6)
+    kv = prog.fresh_cache()
+    out = None
+    for first, n0, n1 in ((0, 0, 64), (64, 0, 6), (70, 1, 40)):
+        tokens = np.zeros(R, np.int32)
+        positions = np.full(R, -1, np.int32)
+        row_slot = np.zeros(R, np.int32)
+        row_slot[:S] = np.arange(S)
+        seq_lens = np.zeros(S, np.int32)
+        out_row = np.arange(S, dtype=np.int32)
+        rows = S + np.arange(n1)
+        positions[rows] = first + np.arange(n1)
+        row_slot[S:] = 1
+        seq_lens[1], out_row[1] = first + n1, rows[-1]
+        if n0:
+            positions[0], seq_lens[0] = 99, 100
+        tokens[positions >= 0] = rs.randint(0, LATENT_VOCAB,
+                                            int((positions >= 0).sum()))
+        live = np.maximum(positions, 0)
+        phys = np.where(positions >= 0, table[row_slot, live // page], 0)
+        out = prog.step(kv, tokens, positions, seq_lens,
+                        phys.astype(np.int32), (live % page).astype(np.int32),
+                        table, None, row_slot, out_row)
+        kv = out[2]
+    return np.asarray(out[1], np.float32), np.asarray(kv[0], np.float32)
+
+
+def phase_latent_decode(compiles, devices):
+    """A small ``sarvam_mla`` program (latent rows of 576 in 640 lanes, 4 of
+    8 experts held) through the engine: Mosaic ``mla_attn`` and
+    ``latent_write`` once a layer, one trace, float32 tokens equal to the XLA
+    formulation's; and one mixed step in bfloat16, the benchmark's dtype,
+    close to it (greedy tokens of a bfloat16 model flip on rounding)."""
+    rs = np.random.RandomState(7)
+    prompts = [rs.randint(0, LATENT_VOCAB, n).astype(np.int32)
+               for n in rs.randint(40, 300, 12)]
+    new_tokens = 24
+    prog = latent_program("float32", "smoke-mla")
+    outs, stats = serve(prog, prompts, new_tokens)
+    check(prog.trace_count == 1 and stats["decode"]["compiles"] == 1,
+          "the many-token step traced %d times" % prog.trace_count)
+    check(stats["decode"]["tokens_prefilled"] == sum(map(len, prompts))
+          and stats["decode"]["tokens_decoded"] == 12 * new_tokens,
+          "counted %s" % stats["decode"])
+    text = prog.lowered_step_text()
+    check_no_interpreter(text, "the many-token step")
+    kernels = mosaic_kernels(text)
+    for name in ("mla_attn", "latent_write"):
+        n = sum(name in k for k in kernels)
+        check(n == LATENT_LAYERS, "%d Mosaic calls of %s in a step of %d "
+              "layers (found %s)" % (n, name, LATENT_LAYERS, kernels))
+    place, devs = where(list(prog._params.values()))
+    check(devs == {devices[0]} and devices[0].platform == "tpu",
+          "weights live on %s" % sorted(map(str, devs)))
+    low = one_mixed_step(latent_program("bfloat16", "smoke-mla-bf16"))
+    os.environ["MXNET_TPU_PALLAS_DECODE"] = "0"
+    try:
+        ref_prog = latent_program("float32", "smoke-mla-xla")
+        ref_outs, _ = serve(ref_prog, prompts, new_tokens)
+        check(not any("mla_attn" in k or "latent_write" in k for k in
+                      mosaic_kernels(ref_prog.lowered_step_text())),
+              "the XLA reference ran a latent-pool kernel")
+        ref_low = one_mixed_step(latent_program("bfloat16", "smoke-mla-bf16x"))
+    finally:
+        del os.environ["MXNET_TPU_PALLAS_DECODE"]
+    wrong = [i for i, (a, b) in enumerate(zip(outs, ref_outs))
+             if not np.array_equal(a, b)]
+    check(not wrong, "float32 greedy tokens differ from the XLA formulation "
+          "in requests %s" % wrong)
+    gap = np.abs(low[0][:2] - ref_low[0][:2]).max() / np.abs(ref_low[0][:2]).max()
+    check(gap < 0.03, "bfloat16 logits of the mixed step lie %.3f of their "
+          "scale from the XLA formulation's" % gap)
+    # layer 0's rows are made of the same numbers on both sides: all but the
+    # trash page must be equal to the bit
+    check(np.array_equal(low[1][1:], ref_low[1][1:]),
+          "latent_write left other rows in layer 0 of the pool than the "
+          "XLA scatter")
+    return ("%d requests, prompts %s tokens, %d new tokens each == the XLA "
+            "formulation's in float32; %d engine steps, step traced once, "
+            "Mosaic mla_attn x %d and latent_write x %d, no interpreter; a "
+            "mixed bfloat16 step within %.4f of its logits' scale, layer 0 "
+            "of the pool equal to the bit; weights on %s"
+            % (len(prompts), [len(p) for p in prompts], new_tokens,
+               stats["counters"]["steps"],
+               sum("mla_attn" in k for k in kernels),
+               sum("latent_write" in k for k in kernels), gap, place))
+
+
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
@@ -468,6 +589,7 @@ def main():
     phases = [("trainer/resnet50", phase_resnet),
               ("trainer/transformer", phase_transformer),
               ("server/decode", phase_decode),
+              ("server/latent-decode", phase_latent_decode),
               ("api/module_fit", phase_module_fit)]
     if len(devices) > 1:
         phases.insert(2, ("trainer/dp-parity", phase_dp_parity))

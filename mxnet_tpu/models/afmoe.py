@@ -14,7 +14,8 @@ holds ``experts_held`` of the ``num_experts`` the router scores, from
 ``first_expert`` on (``ops/nn.py::_contrib_moe_ffn``); with
 ``experts_held == num_experts`` the layer is whole.  Only training is
 built here: serving this model needs a page pool with two kinds of layer
-and a many-token prefill that ``serving/decode.py`` does not have.
+(window and full) that ``serving/decode.py`` does not have; the many-token
+step and experts inside it came with ``models/sarvam_mla.py``.
 """
 from .. import symbol as sym
 

@@ -35,7 +35,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["two_bit_compress", "fused_attention", "fused_attention_fwd",
            "fused_attention_bwd", "decode_attention",
            "decode_attention_pool", "kv_write", "kv_pack", "quantize_weight",
-           "quant_matmul", "grouped_matmul"]
+           "quant_matmul", "grouped_matmul", "mla_attention", "latent_write",
+           "mla_chunk_rows", "latent_row_lanes"]
 
 
 def _interpret(*arrays) -> bool:
@@ -929,17 +930,22 @@ _DECODE_VMEM_BUDGET = 3 << 20
 _DECODE_CELL_TOKENS = 128
 
 
-def _decode_pages_per_cell(H, rows, lanes, D, itemsize, n_pages):
+def _decode_pages_per_cell(H, rows, lanes, D, itemsize, n_pages, pools=2,
+                           cell_tokens=None):
     """G, the pages a ``decode_attn`` cell takes at a time: what fits
-    ``_DECODE_VMEM_BUDGET``, at most ``_DECODE_CELL_TOKENS`` tokens and at
+    ``_DECODE_VMEM_BUDGET``, at most ``cell_tokens`` tokens and at
     most the slot's whole table, at least one.  A function of the shapes
     the kernel sees and nothing else: 8 at GPT-2 small (H = 12, D = 64,
-    page 16, float32: a 49 KB page), 2 at H = 32, D = 128 (262 KB)."""
+    page 16, float32: a 49 KB page), 2 at H = 32, D = 128 (262 KB).
+    ``pools``: the pool operands a page is copied from (K and V; one for
+    ``mla_attn``'s latent rows, which gives its own ``cell_tokens`` in
+    ``_DECODE_CELL_TOKENS``' place)."""
     page_elems = H * rows * lanes
-    per_page = 4 * page_elems * itemsize + 4 * page_elems
+    per_page = 2 * pools * page_elems * itemsize + 4 * page_elems
     tokens = rows * (lanes // D)
     return max(1, min(_DECODE_VMEM_BUDGET // per_page,
-                      _DECODE_CELL_TOKENS // tokens, n_pages))
+                      (cell_tokens or _DECODE_CELL_TOKENS) // tokens,
+                      n_pages))
 
 
 def _decode_attn_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
@@ -1175,11 +1181,17 @@ def decode_backend_is_pallas(S, H, D, page, dtype) -> bool:
     """``MXNET_TPU_PALLAS_DECODE``: ``1`` / ``0`` / ``auto`` (the
     ops/autotune cache's measured winner for this geometry, falling back
     to pallas on TPU and XLA elsewhere)."""
-    knob = os.environ.get("MXNET_TPU_PALLAS_DECODE", "auto")
-    if knob in ("0", "1"):
-        return knob == "1"
+    knob = _pallas_decode_knob()
+    if knob is not None:
+        return knob
     from . import autotune as _autotune
     return _autotune.decode_backend(S, H, D, page, str(dtype)) == "pallas"
+
+
+def _pallas_decode_knob():
+    """``MXNET_TPU_PALLAS_DECODE`` as True / False, None for ``auto``."""
+    knob = os.environ.get("MXNET_TPU_PALLAS_DECODE", "auto")
+    return knob == "1" if knob in ("0", "1") else None
 
 
 def _default_scale(D):
@@ -1281,6 +1293,341 @@ def kv_write(kv: jax.Array, layer, k: jax.Array, v: jax.Array,
             interpret=_interpret(kv, k, v), name="kv_write",
         )(phys.astype(jnp.int32), off.astype(jnp.int32),
           _layer_operand(layer), new, kv)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA) against a pool of latent rows, and the write beside it
+# ---------------------------------------------------------------------------
+#
+# A model with multi-head latent attention caches ONE row a token a layer,
+# ``z = [c ; k_r]`` (the normed latent and the rope key shared by every
+# head), and in the absorbed form a query head is a row ``[W_UK^T q_n ; q_r]``
+# of the same width: every head of a query row scores the SAME cached rows,
+# and the value is the row's first ``latent`` lanes.  The pool is
+# ``(L, P, page, W)``: a page's tokens are its rows, W the row's width
+# (latent + rope = 576) rounded up to whole tiles of 128 lanes
+# (:func:`latent_row_lanes`: 640, the last 64 lanes zero).  The TPU tiles the
+# minor axis by 128 whatever the shape says, so a 576-wide array takes the
+# same 640 in HBM; saying so in the shape makes the device's own layout the
+# row-major one and a page an aligned (page, W) block that the kernels' copies
+# may slice (Mosaic refuses a copy out of a padded 576).
+#
+# A step's query rows come in two kinds under one fixed budget: rows
+# ``[0, n_decode)`` are one row a slot (row i belongs to slot i), the rest is
+# the prefill chunk in blocks of :func:`mla_chunk_rows` rows, the live rows of
+# a block all of one slot.  ``mla_attn`` walks ITEMS: a decode row, or a chunk
+# block.  An item stacks its rows' heads into one matrix (16 rows x 64 heads =
+# M of 1,024 x W) and walks its slot's live pages G at a time exactly as
+# ``decode_attn`` does (pool in HBM, the kernel's own double-buffered copies,
+# an item's last group starting the next item's first; G from
+# :func:`_decode_pages_per_cell`): scores ``Q Z^T`` and the weighted sum
+# ``P Z[:, :latent]`` are two MXU products a group, the softmax is the online
+# one in float32, causal inside a chunk by each row's own limit.  A decode
+# item's M is the 64 heads of its one row: its time is the copy of its pages.
+# A slot's pages are read once for each of its items: once for a decoding
+# slot, once per ``mla_chunk_rows`` rows of a chunk, whose items are bound by
+# the MXU and not by those copies.
+
+_MLA_CELL_TOKENS = 512      # tokens a group of pages holds at most
+_MLA_CHUNK_ROWS = 16        # query rows of a chunk item
+_MLA_VMEM_LIMIT = 48 << 20
+
+
+def latent_row_lanes(width: int) -> int:
+    """Lanes a latent row of ``width`` takes in the pool: whole tiles."""
+    return -(-int(width) // _LANES) * _LANES
+
+
+def _pad_lanes(x, lanes):
+    """``x`` with zeros appended to its last axis up to ``lanes``."""
+    short = lanes - x.shape[-1]
+    if short == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+
+
+def mla_chunk_rows() -> int:
+    """Rows of one chunk item: the host lays a slot's chunk rows out in
+    blocks of this many (the last padded with dead rows)."""
+    return _MLA_CHUNK_ROWS
+
+
+def _mla_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, qd_ref, qc_ref,
+                     ld_ref, lc_ref, pool_hbm, od_ref, oc_ref, z_buf, m_ref,
+                     l_ref, acc_ref, sems, buf_ref, *, n_decode, G, page,
+                     latent, scale, n_pages):
+    """One item (the block above has what an item is).  ``slot_ref`` /
+    ``ctx_ref``: the item's slot and the positions its last live row
+    attends; ``qd_ref`` (H, W) / ``qc_ref`` (rows*H, W) the decode row's and
+    the chunk block's queries, ``ld_ref`` / ``lc_ref`` each matrix row's
+    limit (it attends positions below it; 0: a dead row, whose output is
+    finite and unused); ``z_buf`` (2, G*page, W) the two landing buffers."""
+    i = pl.program_id(0)
+    N = pl.num_programs(0)
+    T = G * page
+    layer = layer_ref[0]
+
+    def live_pages(item):
+        # at least one, as in decode_attn: an idle item has a page to read
+        return jnp.maximum(jax.lax.div(ctx_ref[item] + (page - 1), page), 1)
+
+    def live_copies(item, g, b, act):
+        slot = slot_ref[item]
+        n = live_pages(item)
+        for k in range(G):
+            j = g * G + k
+            at = j if n_pages % G == 0 else jnp.minimum(j, n_pages - 1)
+            copy = pltpu.make_async_copy(
+                pool_hbm.at[layer, pt_ref[slot, at]],
+                z_buf.at[b, pl.ds(k * page, page)], sems.at[b])
+
+            @pl.when(j < n)
+            def _():
+                act(copy)
+
+    def start(item, g, b):
+        live_copies(item, g, b, lambda copy: copy.start())
+
+    @pl.when(i == 0)
+    def _first():
+        # a dead page keeps what its buffer held, and weight 0 times that
+        # must be 0: never what VMEM woke up with
+        z_buf[...] = jnp.zeros_like(z_buf)
+        buf_ref[0] = 0
+        start(0, 0, 0)
+
+    n_groups = jax.lax.div(live_pages(i) + (G - 1), G)
+    b0 = buf_ref[0]
+
+    def walk(q_ref, lim_ref, o_ref):
+        M = q_ref.shape[0]
+        m_ref[:M] = jnp.full((M, 1), _NEG_BIG, jnp.float32)
+        l_ref[:M] = jnp.zeros((M, 1), jnp.float32)
+        acc_ref[:M] = jnp.zeros((M, latent), jnp.float32)
+        q = q_ref[...]
+        limit = lim_ref[...]                                # (M, 1)
+
+        def group(g, carry):
+            b = (b0 + g) & 1
+            last = g + 1 == n_groups
+            nxt = jnp.where(last, i + 1, i)
+
+            @pl.when(nxt < N)
+            def _prefetch():
+                start(jnp.minimum(nxt, N - 1), jnp.where(last, 0, g + 1),
+                      1 - b)
+
+            live_copies(i, g, b, lambda copy: copy.wait())
+            z = z_buf[b]                                    # (T, W)
+            s = _mxu_dot(q, z, _NT) * scale                 # (M, T)
+            pos = g * T + jax.lax.broadcasted_iota(jnp.int32, (M, T), 1)
+            s = jnp.where(pos < limit, s, jnp.float32(_NEG_BIG))
+            m_old = m_ref[:M]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_old - m_new)
+            l_ref[:M] = l_ref[:M] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[:M] = acc_ref[:M] * corr + _mxu_dot(
+                p.astype(z.dtype), z[:, :latent], _NN)
+            m_ref[:M] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_groups, group, 0)
+        o_ref[...] = (acc_ref[:M] / jnp.maximum(l_ref[:M], jnp.float32(1e-37))
+                      ).astype(o_ref.dtype)
+
+    @pl.when(i < n_decode)
+    def _decode_row():
+        walk(qd_ref, ld_ref, od_ref)
+
+    @pl.when(i >= n_decode)
+    def _chunk_block():
+        walk(qc_ref, lc_ref, oc_ref)
+
+    buf_ref[0] = (b0 + n_groups) & 1
+
+
+@functools.partial(jax.jit, static_argnames=("n_decode", "latent", "scale",
+                                             "interpret"))
+def _mla_attn_call(q, pool, layer, page_table, row_slot, row_limit, *,
+                   n_decode, latent, scale, interpret):
+    """The kernel's call, under ``jax.jit`` so that the layers of a step
+    share one traced body (as ``_decode_attn_call``)."""
+    R, H, W = q.shape
+    page = pool.shape[2]
+    S, TQ = n_decode, _MLA_CHUNK_ROWS
+    NB = (R - S) // TQ
+    G = _decode_pages_per_cell(1, page, W, W, pool.dtype.itemsize,
+                               page_table.shape[1], pools=1,
+                               cell_tokens=_MLA_CELL_TOKENS)
+    row_limit = row_limit.astype(jnp.int32)
+    chunk_limit = row_limit[S:].reshape(NB, TQ)
+    item_slot = jnp.concatenate([jnp.arange(S, dtype=jnp.int32),
+                                 row_slot[S:].astype(jnp.int32)
+                                 .reshape(NB, TQ)[:, 0]])
+    item_ctx = jnp.concatenate([row_limit[:S], jnp.max(chunk_limit, axis=1)])
+    by_head = jnp.broadcast_to(row_limit[:, None, None], (R, H, 1))
+    kern = functools.partial(_mla_attn_kernel, n_decode=S, G=G, page=page,
+                             latent=latent, scale=scale,
+                             n_pages=page_table.shape[1])
+
+    def decode_block(width):
+        return pl.BlockSpec((None, H, width),
+                            lambda i, *_: (jnp.minimum(i, S - 1), 0, 0))
+
+    def chunk_block(width):
+        return pl.BlockSpec((None, TQ * H, width),
+                            lambda i, *_: (jnp.maximum(i - S, 0), 0, 0))
+
+    M = TQ * H
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(S + NB,),
+        in_specs=[decode_block(W), chunk_block(W), decode_block(1),
+                  chunk_block(1), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[decode_block(latent), chunk_block(latent)],
+        scratch_shapes=[
+            pltpu.VMEM((2, G * page, W), pool.dtype),
+            pltpu.VMEM((M, 1), jnp.float32),        # running max
+            pltpu.VMEM((M, 1), jnp.float32),        # running sum
+            pltpu.VMEM((M, latent), jnp.float32),   # accumulator
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),    # the buffer the item starts in
+        ],
+    )
+    with jax.enable_x64(False):
+        ud, uc = pl.pallas_call(
+            kern, grid_spec=grid_spec,
+            out_shape=[_out_struct((S, H, latent), q.dtype, q, pool),
+                       _out_struct((NB, M, latent), q.dtype, q, pool)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_MLA_VMEM_LIMIT),
+            interpret=interpret, name="mla_attn",
+        )(item_slot, item_ctx, _layer_operand(layer),
+          page_table.astype(jnp.int32), q[:S], q[S:].reshape(NB, M, W),
+          by_head[:S], by_head[S:].reshape(NB, M, 1), pool)
+    return jnp.concatenate([ud, uc.reshape(NB * TQ, H, latent)])
+
+
+def _mla_attn_xla(q, pool, layer, page_table, row_slot, row_limit, latent,
+                  scale):
+    """XLA formulation: gather each row's slot's pages, mask, one softmax.
+    It materialises (R, max_pages * page, W): the CPU tests' form."""
+    zs = pool[layer][page_table]                    # (S, pages, page, W)
+    zs = zs.reshape(zs.shape[0], -1, zs.shape[-1])[row_slot]
+    zs = zs.astype(jnp.float32)                     # (R, T, W)
+    s = jnp.einsum("rhw,rtw->rht", q.astype(jnp.float32), zs,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    pos = jnp.arange(zs.shape[1], dtype=jnp.int32)[None, None, :]
+    s = jnp.where(pos < row_limit[:, None, None].astype(jnp.int32), s,
+                  jnp.float32(_NEG_BIG))
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("rht,rtc->rhc", p, zs[..., :latent],
+                      precision=jax.lax.Precision.HIGHEST).astype(q.dtype)
+
+
+def pool_ops_are_pallas() -> bool:
+    """``MXNET_TPU_PALLAS_DECODE`` for the latent pool's two kernels: ``1``
+    / ``0``, else (``auto``) Pallas on a TPU and XLA elsewhere."""
+    knob = _pallas_decode_knob()
+    return jax.default_backend() == "tpu" if knob is None else knob
+
+
+def mla_attention(q: jax.Array, pool: jax.Array, layer,
+                  page_table: jax.Array, row_slot: jax.Array,
+                  row_limit: jax.Array, *, n_decode: int, latent: int,
+                  scale: float, use_pallas=None) -> jax.Array:
+    """Absorbed latent attention of a step's query rows against the latent
+    pool ``(L, P, page, W)`` of ``layer``.
+
+    ``q``: (R, H, width), a head's ``[W_UK^T q_n ; q_r]``, padded here to
+    the pool's W lanes; ``page_table``:
+    (S, max_pages) valid page ids; ``row_slot``: (R,) the slot whose pages a
+    row reads; ``row_limit``: (R,) the positions a row attends (those below
+    it; 0 for a dead row).  Rows ``[0, n_decode)`` are one a slot (row i of
+    slot i), the others lie in blocks of :func:`mla_chunk_rows` whose live
+    rows share the slot the block's first row names.  Returns ``u``
+    (R, H, latent): the softmax-weighted sum of the rows' first ``latent``
+    lanes, before the value up-projection."""
+    if use_pallas is None:
+        use_pallas = pool_ops_are_pallas()
+    q = _pad_lanes(q, pool.shape[-1])
+    if not use_pallas:
+        return _mla_attn_xla(q, pool, layer, page_table, row_slot, row_limit,
+                             latent, float(scale))
+    if (q.shape[0] - n_decode) % _MLA_CHUNK_ROWS or q.shape[0] <= n_decode:
+        raise ValueError("mla_attention: %d chunk rows are not whole blocks "
+                         "of %d" % (q.shape[0] - n_decode, _MLA_CHUNK_ROWS))
+    return _mla_attn_call(q, pool, layer, page_table, row_slot, row_limit,
+                          n_decode=int(n_decode), latent=int(latent),
+                          scale=float(scale), interpret=_interpret(q, pool))
+
+
+def _latent_write_kernel(phys_ref, off_ref, layer_ref, new_ref, pool_ref,
+                         out_ref, *, tile):
+    """One row: the ``tile`` rows of its page that hold offset ``off[r]``,
+    with that row replaced.  Rows that follow one another into the same tile
+    (a chunk's consecutive positions) find it still in ``out_ref`` (the block
+    index did not change, so nothing was written back or fetched again) and
+    add their row to it."""
+    del layer_ref
+    r = pl.program_id(0)
+    before = jnp.maximum(r - 1, 0)
+    off = off_ref[r]
+    again = ((r > 0) & (phys_ref[r] == phys_ref[before])
+             & (off // tile == off_ref[before] // tile))
+
+    @pl.when(jnp.logical_not(again))
+    def _():
+        out_ref[...] = pool_ref[...]
+
+    hit = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0) == off % tile
+    out_ref[...] = jnp.where(hit, new_ref[...], out_ref[...])
+
+
+def _latent_write_pallas(pool, layer, z, phys, off, interpret):
+    R, W = z.shape
+    page = pool.shape[2]
+    tile = min(_sublane_tile(pool.dtype), page)
+    tile_block = pl.BlockSpec(
+        (None, None, tile, W),
+        lambda r, ph, of, lyr: (lyr[0], ph[r], of[r] // tile, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(R,),
+        in_specs=[pl.BlockSpec((None, 1, W), lambda r, *_: (r, 0, 0)),
+                  tile_block],
+        out_specs=tile_block,
+    )
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            functools.partial(_latent_write_kernel, tile=tile),
+            grid_spec=grid_spec,
+            out_shape=_out_struct(pool.shape, pool.dtype, z, pool),
+            # operand 4 (after the three scalars and the rows) is the pool
+            input_output_aliases={4: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret, name="latent_write",
+        )(phys.astype(jnp.int32), off.astype(jnp.int32),
+          _layer_operand(layer), z.astype(pool.dtype).reshape(R, 1, W), pool)
+
+
+def latent_write(pool: jax.Array, layer, z: jax.Array, phys: jax.Array,
+                 off: jax.Array, use_pallas=None) -> jax.Array:
+    """The latent pool ``(L, P, page, W)`` with row ``off[r]`` of page
+    ``phys[r]`` of ``layer`` set to ``z[r]`` for every row ``r`` of ``z``
+    (R, W) — written where the pool lies (``input_output_aliases``).  Rows
+    bound for one page must follow one another; dead rows (all on the trash
+    page) may overwrite one another there in any order."""
+    if use_pallas is None:
+        use_pallas = pool_ops_are_pallas()
+    z = _pad_lanes(z, pool.shape[-1])
+    if not use_pallas:
+        return pool.at[layer, phys, off].set(z.astype(pool.dtype))
+    return _latent_write_pallas(pool, layer, z, phys, off,
+                                _interpret(pool, z))
 
 
 # ---------------------------------------------------------------------------

@@ -315,15 +315,20 @@ def held_experts_ffn(m, c, picks, w1, w3, w2, first_expert: int,
 
 def moe_ffn_held(m, wr, bias, shared, experts, *, num_experts: int,
                  first_expert: int, top_k: int, route_norm: bool = True,
-                 route_scale: float = 1.0, buckets=None):
+                 route_scale: float = 1.0, buckets=None, live=None):
     """One chip's share of the layer over tokens ``m`` (T, d): routing over
     all ``num_experts`` (``wr`` (d, E), ``bias`` (E,)), the held experts'
     part, and the shared expert beside it.  ``experts`` = (W1, W3, W2) of
     shapes (held, d, f), (held, d, f), (held, f, d); ``shared`` likewise
-    without the leading axis, or None.  Returns (out (T, d) in m's dtype,
-    load (E,) float32)."""
+    without the leading axis, or None.  ``live`` (T,) bool, optional: rows
+    that are padding of a fixed-size step (a serving step's unused rows)
+    pick no expert, so they own no sorted row and count in no load.
+    Returns (out (T, d) in m's dtype, load (E,) float32)."""
     with jax.named_scope("route"):
         picks, c = route_top_k(m, wr, bias, top_k, route_norm, route_scale)
+        if live is not None:
+            # an expert number nobody holds
+            picks = jnp.where(live[:, None], picks, num_experts)
         load = expert_load(picks, num_experts)
     out = held_experts_ffn(m, c, picks, *experts, first_expert=first_expert,
                            buckets=buckets, num_experts=num_experts)

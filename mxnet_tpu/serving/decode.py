@@ -35,6 +35,15 @@ split (PAPERS.md): a decode loop whose per-token step
   before step n's tokens are fetched, a decoding slot's next token fed
   forward on the device (``prev_tok``) — so the host's work hides
   behind the device's;
+* is built for a model **family** (``DecodeConfig.family``): GPT-2's
+  block is :class:`DecodeProgram` as described above; a latent-attention
+  model is :class:`LatentDecodeProgram`, whose block
+  ``models/sarvam_mla.py`` supplies: parameters and pool in
+  ``DecodeConfig.dtype``, a pool of latent rows ``(L, P, page, W)``
+  touched by ``latent_write`` and ``mla_attn``, routed experts in the
+  step, and a **many-token step** (the slots' own rows and a chunk of
+  prompt rows under one fixed budget, compiled once), which the engine
+  feeds in chunks under the same contract;
 * optionally serves **weight-only quantized** matmuls (int8 / packed
   int4, per-channel scales, dequantization fused in the kernel —
   :func:`~mxnet_tpu.ops.pallas_kernels.quant_matmul`), selected at
@@ -80,11 +89,18 @@ from .errors import (DeadlineExceeded, ExecFailed, Overloaded,
 from .request import Request
 from .runtime import ServingRuntime, _env_int
 
-__all__ = ["DecodeConfig", "PagePool", "DecodeProgram", "DecodeRequest",
-           "DecodeEngine", "decode_param_shapes", "init_decode_params",
-           "decode_tp_model_bytes"]
+__all__ = ["DecodeConfig", "PagePool", "DecodeProgram", "LatentDecodeProgram",
+           "DecodeRequest", "DecodeEngine", "decode_param_shapes",
+           "init_decode_params", "decode_tp_model_bytes", "program_class"]
 
 _MAGIC = "mxnet_tpu-decode-v1"
+
+# the model families a decode program exists for (``DecodeConfig.family``):
+# models/transformer.py's GPT-2 block, one token a slot a step, a K/V pool by
+# head; models/sarvam_mla.py's latent-attention block with routed experts, a
+# many-token step over a pool of latent rows
+TRANSFORMER_LM = "transformer_lm"
+SARVAM_MLA = "sarvam_mla"
 
 # weights the quantized export rewrites (per layer + the head); LN affine
 # params, biases and embeddings stay f32 — they are O(hidden), noise next
@@ -99,11 +115,22 @@ class DecodeConfig:
 
     __slots__ = ("vocab_size", "num_layers", "hidden", "heads",
                  "max_seq_len", "page_size", "max_seqs", "quantize",
-                 "eos_id", "forward_len")
+                 "eos_id", "forward_len", "family", "dtype",
+                 "prefill_tokens_per_step", "model")
 
     def __init__(self, vocab_size, num_layers, hidden, heads,
                  max_seq_len, page_size=None, max_seqs=None,
-                 quantize=None, eos_id=None, forward_len=None):
+                 quantize=None, eos_id=None, forward_len=None,
+                 family=None, dtype=None, prefill_tokens_per_step=None,
+                 model=None):
+        # which model family's step this is (the program class is looked up
+        # by it), the dtype of its parameters and pool, the prompt rows a
+        # step takes beside the slots' own (0: one token a slot a step), and
+        # the family's own settings (a published config.json's keys)
+        self.family = str(family or TRANSFORMER_LM)
+        self.dtype = str(dtype or "float32")
+        self.prefill_tokens_per_step = int(prefill_tokens_per_step or 0)
+        self.model = dict(model) if model else None
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.hidden = int(hidden)
@@ -142,16 +169,9 @@ class DecodeConfig:
         return int(n) if n > 0 else 1 + self.max_seqs * self.pages_per_seq
 
     def pool_shape(self) -> tuple:
-        """``(L, 2, P, H, rows, lanes)``: a page's ``(page_size,
-        head_dim)`` tokens lie lane-dense in its last two axes,
-        ``pallas_kernels.kv_pack`` of them to a row of 128 lanes (the
-        row-major reshape of ``(page_size, head_dim)``), so that a
-        head_dim of 64 pads nothing on the TPU and the device's own layout
-        for the array is the row-major one the kernels read."""
-        from ..ops.pallas_kernels import kv_pack
-        pack = kv_pack(self.page_size, self.head_dim)
-        return (self.num_layers, 2, self.pool_pages(), self.heads,
-                self.page_size // pack, pack * self.head_dim)
+        """The page pool of this geometry, as the family's program class
+        lays it out (:meth:`DecodeProgram.pool_shape_of`)."""
+        return program_class(self.family).pool_shape_of(self)
 
     def to_meta(self) -> dict:
         return {k: getattr(self, k) for k in self.__slots__}
@@ -165,10 +185,12 @@ class DecodeConfig:
                    for k in self.__slots__ if k != "quantize")
 
     def describe(self) -> str:
-        return ("L%d H%d heads%d V%d T%d page%d S%d%s"
-                % (self.num_layers, self.hidden, self.heads,
-                   self.vocab_size, self.max_seq_len, self.page_size,
-                   self.max_seqs,
+        return ("%s %s L%d H%d heads%d V%d T%d page%d S%d%s%s"
+                % (self.family, self.dtype, self.num_layers, self.hidden,
+                   self.heads, self.vocab_size, self.max_seq_len,
+                   self.page_size, self.max_seqs,
+                   "+%d" % self.prefill_tokens_per_step
+                   if self.prefill_tokens_per_step else "",
                    " %s" % self.quantize if self.quantize else ""))
 
 
@@ -206,31 +228,20 @@ class PagePool:
 
 
 def decode_param_shapes(config: DecodeConfig) -> Dict[str, tuple]:
-    """Name -> shape of every parameter the decode program consumes: the
-    TRAINING graph's names and layouts (models/transformer.get_symbol)."""
-    h, v, t = config.hidden, config.vocab_size, config.max_seq_len
-    shapes = {"tok_embed_weight": (v, h), "pos_embed": (t, h),
-              "ln_f_gamma": (h,), "ln_f_beta": (h,),
-              "head_weight": (v, h), "head_bias": (v,)}
-    for i in range(config.num_layers):
-        p = "l%d_" % i
-        for nm, shape in (("q", (h, h)), ("k", (h, h)), ("v", (h, h)),
-                          ("proj", (h, h)), ("ff1", (4 * h, h)),
-                          ("ff2", (h, 4 * h))):
-            shapes[p + nm + "_weight"] = shape
-            shapes[p + nm + "_bias"] = shape[:1]
-        for ln in ("ln1", "ln2"):
-            shapes[p + ln + "_gamma"] = (h,)
-            shapes[p + ln + "_beta"] = (h,)
-    return shapes
+    """Name -> shape of every parameter the decode program of
+    ``config.family`` consumes (:meth:`DecodeProgram.param_shapes_of` of the
+    family's class)."""
+    return program_class(config.family).param_shapes_of(config)
 
 
 def init_decode_params(config: DecodeConfig, seed: int = 0,
                        scale: float = 0.02) -> Dict[str, np.ndarray]:
-    """Random parameters in :func:`decode_param_shapes` — the decode
+    """Random parameters in :func:`decode_param_shapes`, of the family
+    ``config`` names — the decode
     program consumes a trained module's ``arg_params`` directly; this
     helper only exists for tests and benches that have no trained model
-    at hand."""
+    at hand.  Float32 on the host whatever ``config.dtype``: the program
+    casts what its family keeps in the serving dtype."""
     rs = np.random.RandomState(seed)
 
     def init(name, shape):
@@ -313,6 +324,8 @@ class DecodeProgram:
     the pool out as it sees fit for that.
     """
 
+    FAMILY = TRANSFORMER_LM
+
     def __init__(self, params: Dict, config: DecodeConfig, *, mesh=None,
                  quantize=None, name="decode"):
         import jax
@@ -320,6 +333,12 @@ class DecodeProgram:
         if quantize is not None:
             config = DecodeConfig(**dict(config.to_meta(),
                                          quantize=quantize))
+        if config.family != self.FAMILY:
+            raise MXNetError(
+                "%s builds the %s step; the config describes %s (%s)"
+                % (type(self).__name__, self.FAMILY, config.family,
+                   config.describe()))
+        self._check_config(config, mesh)
         self.config = config
         self.name = name
         self.spec = _build_mesh(mesh)
@@ -357,7 +376,49 @@ class DecodeProgram:
         self.input_dtypes = {"tokens": np.dtype(np.int32)}
         self.output_shapes = [(S, 1)]
 
+    # -- what the family decides ------------------------------------------
+    @staticmethod
+    def pool_shape_of(config: DecodeConfig) -> tuple:
+        """``(L, 2, P, H, rows, lanes)``: a page's ``(page_size,
+        head_dim)`` tokens lie lane-dense in its last two axes,
+        ``pallas_kernels.kv_pack`` of them to a row of 128 lanes (the
+        row-major reshape of ``(page_size, head_dim)``), so that a
+        head_dim of 64 pads nothing on the TPU and the device's own layout
+        for the array is the row-major one the kernels read."""
+        from ..ops.pallas_kernels import kv_pack
+        pack = kv_pack(config.page_size, config.head_dim)
+        return (config.num_layers, 2, config.pool_pages(), config.heads,
+                config.page_size // pack, pack * config.head_dim)
+
+    @staticmethod
+    def param_shapes_of(config: DecodeConfig) -> Dict[str, tuple]:
+        """The TRAINING graph's names and layouts
+        (models/transformer.get_symbol)."""
+        h, v, t = config.hidden, config.vocab_size, config.max_seq_len
+        shapes = {"tok_embed_weight": (v, h), "pos_embed": (t, h),
+                  "ln_f_gamma": (h,), "ln_f_beta": (h,),
+                  "head_weight": (v, h), "head_bias": (v,)}
+        for i in range(config.num_layers):
+            p = "l%d_" % i
+            for nm, shape in (("q", (h, h)), ("k", (h, h)), ("v", (h, h)),
+                              ("proj", (h, h)), ("ff1", (4 * h, h)),
+                              ("ff2", (h, 4 * h))):
+                shapes[p + nm + "_weight"] = shape
+                shapes[p + nm + "_bias"] = shape[:1]
+            for ln in ("ln1", "ln2"):
+                shapes[p + ln + "_gamma"] = (h,)
+                shapes[p + ln + "_beta"] = (h,)
+        return shapes
+
     # -- construction helpers ---------------------------------------------
+    @staticmethod
+    def _check_config(config, mesh):
+        if config.dtype != "float32" or config.prefill_tokens_per_step:
+            raise MXNetError(
+                "the %s step serves in float32, one token a slot a step; "
+                "the config asks for %s" % (TRANSFORMER_LM,
+                                            config.describe()))
+
     def _check_params(self, host):
         need = {"tok_embed_weight", "pos_embed", "ln_f_gamma",
                 "ln_f_beta", "head_weight", "head_bias"}
@@ -434,7 +495,7 @@ class DecodeProgram:
         buffer, written in place."""
         import jax
         import jax.numpy as jnp
-        z = jnp.zeros(self.config.pool_shape(), jnp.float32)
+        z = jnp.zeros(self.config.pool_shape(), jnp.dtype(self.config.dtype))
         kv = jax.device_put(z, self.kv_sharding()) \
             if self.spec is not None else jax.device_put(z)
         telemetry.memory.tag(kv, "kv_cache",
@@ -443,7 +504,9 @@ class DecodeProgram:
 
     @property
     def cache_bytes(self) -> int:
-        return int(np.prod(self.config.pool_shape())) * 4
+        import jax.numpy as jnp
+        return int(np.prod(self.config.pool_shape())) \
+            * jnp.dtype(self.config.dtype).itemsize
 
     # -- the step program --------------------------------------------------
     def _make_step_fn(self, count=True):
@@ -583,6 +646,10 @@ class DecodeProgram:
                 np.zeros(S, i32), np.zeros(S, i32),
                 np.zeros((S, c.pages_per_seq), i32))
 
+    def _warm_args(self):
+        """Everything the jitted step takes after the pool, all zeros."""
+        return self._zero_step_args() + (self._no_prev_tok,)
+
     def step(self, kv, tokens, positions, seq_lens, phys, off,
              page_table, prev_tok=None):
         """One decode step for every slot; returns ``(next_tokens,
@@ -614,9 +681,7 @@ class DecodeProgram:
             with telemetry.span("compile/decode_step", cat="compile",
                                 metric="compile.seconds", timed=True,
                                 program=self.name) as sp:
-                out = self._jit_step(self._params, kv,
-                                     *self._zero_step_args(),
-                                     self._no_prev_tok)
+                out = self._jit_step(self._params, kv, *self._warm_args())
             import jax
             jax.block_until_ready(out[0])
             telemetry.tracing.note_compile("decode_step", sp.duration,
@@ -656,10 +721,11 @@ class DecodeProgram:
         nxt = None
         for t in range(c.forward_len):
             pos = np.full(S, t, np.int32)
-            nxt, _logits, kv = self.step(
+            out = self.step(
                 kv, toks[:, t], pos, pos + 1,
                 table[np.arange(S), t // c.page_size],
                 np.full(S, t % c.page_size, np.int32), table)
+            nxt, kv = out[0], out[2]
         return [np.asarray(nxt).reshape(S, 1)]
 
     # -- export / load ------------------------------------------------------
@@ -699,6 +765,10 @@ class DecodeProgram:
             raise MXNetError("%s is not a decode artifact (magic %r)"
                              % (path, meta.get("magic")))
         config = DecodeConfig.from_meta(meta["config"])
+        # the artifact says which family's step its weights are for: the
+        # class that builds that step loads it, whichever class was asked
+        if cls.FAMILY != config.family:
+            cls = program_class(config.family)
         axes = meta.get("mesh_axes")
         if mesh == "artifact":
             mesh = axes
@@ -714,10 +784,183 @@ class DecodeProgram:
                     "this process sees %d" % (dict(mesh), need, have))
         params = {k[len("param/"):]: v for k, v in arrays.items()
                   if k.startswith("param/")}
+        stored = {v.dtype.name for v in params.values() if v.dtype.kind
+                  not in "iu"} - {"float32"}
+        if stored - {config.dtype}:
+            raise MXNetError(
+                "%s holds %s parameters but its config says %s: refusing to "
+                "cast an artifact into another precision"
+                % (path, sorted(stored), config.describe()))
         prog = cls(params, config, mesh=mesh,
                    name=name or os.path.basename(os.fspath(path)))
         telemetry.count("deploy.loads")
         return prog
+
+
+class LatentDecodeProgram(DecodeProgram):
+    """The decode program of a latent-attention family
+    (``models/sarvam_mla.py`` supplies the block; nothing of it is written
+    here): parameters and pool in ``config.dtype``, a pool of latent rows
+    ``(L, P, page, W)`` and a MANY-TOKEN step under one fixed budget of rows,
+    compiled once: ``max_seqs`` rows that are one a slot (a decoding slot's
+    token) and ``prefill_tokens_per_step`` rows of prompt, each row with its
+    slot, position and place in the pool, in blocks of
+    ``pallas_kernels.mla_chunk_rows()`` whose live rows share a slot.  The
+    step returns one next token a slot (the head runs on ``out_row``, one
+    row a slot) and, fourth, what the expert layers saw: held picks and
+    experts touched.  One device, no quantization."""
+
+    FAMILY = SARVAM_MLA
+
+    @staticmethod
+    def pool_shape_of(config: DecodeConfig) -> tuple:
+        """``(L, P, page, W)``: one row a token a layer (the normed latent
+        and the rope key, no head axis, no K/V pair), W its width in whole
+        tiles of 128 lanes."""
+        from ..ops.pallas_kernels import latent_row_lanes
+        return (config.num_layers, config.pool_pages(), config.page_size,
+                latent_row_lanes(config.model["kv_lora_rank"]
+                                 + config.model["qk_rope_head_dim"]))
+
+    @staticmethod
+    def param_shapes_of(config: DecodeConfig) -> Dict[str, tuple]:
+        from ..models import sarvam_mla
+        return sarvam_mla.param_shapes(sarvam_mla.model_of(config.model),
+                                       config.num_layers, config.vocab_size)
+
+    @staticmethod
+    def _check_config(config, mesh):
+        from ..ops.pallas_kernels import mla_chunk_rows
+        block = mla_chunk_rows()
+        if mesh is not None or config.quantize:
+            raise MXNetError("the %s step runs on one device, unquantized"
+                             % SARVAM_MLA)
+        if config.prefill_tokens_per_step < block \
+                or config.prefill_tokens_per_step % block:
+            raise MXNetError(
+                "prefill_tokens_per_step %d is not a positive multiple of "
+                "the chunk block (%d rows)"
+                % (config.prefill_tokens_per_step, block))
+
+    @property
+    def rows(self) -> int:
+        """Rows of one step: the slots' own and the chunk's."""
+        return self.config.max_seqs + self.config.prefill_tokens_per_step
+
+    @property
+    def chunk_block(self) -> int:
+        """Rows of one block of the chunk: a slot's chunk rows start a
+        block, and the live rows of a block are one slot's (the kernel's
+        ``mla_chunk_rows()``)."""
+        from ..ops.pallas_kernels import mla_chunk_rows
+        return mla_chunk_rows()
+
+    def _check_params(self, host):
+        want = decode_param_shapes(self.config)
+        missing = sorted(set(want) - set(host))
+        if missing:
+            raise MXNetError("decode params missing %s (names of "
+                             "models/sarvam_mla.param_shapes)" % missing[:6])
+        wrong = [(k, tuple(host[k].shape), want[k]) for k in want
+                 if tuple(host[k].shape) != tuple(want[k])]
+        if wrong:
+            raise MXNetError("decode params of another shape than %s "
+                             "needs: %s" % (self.config.describe(),
+                                            wrong[:3]))
+
+    def _place_param(self, key, value):
+        import jax
+        import jax.numpy as jnp
+        from ..models import sarvam_mla
+        dtype = jnp.float32 if sarvam_mla.is_float32_param(key) \
+            else jnp.dtype(self.config.dtype)
+        return jax.device_put(np.asarray(value).astype(dtype, copy=False))
+
+    def _make_step_fn(self, count=True):
+        from ..models import sarvam_mla
+        c = self.config
+        decoder = sarvam_mla.Decoder(
+            c.model, num_layers=c.num_layers, vocab_size=c.vocab_size,
+            slots=c.max_seqs, chunk_rows=c.prefill_tokens_per_step,
+            dtype=c.dtype)
+
+        def step(params, kv, tokens, positions, seq_lens, phys, off,
+                 page_table, prev_tok, row_slot, out_row):
+            # ONE trace, ever: the budget of rows is fixed by the config,
+            # which rows are live and whose they are is data (GC307)
+            if count:
+                self.trace_count += 1
+            return decoder.step(params, kv, tokens, positions, seq_lens,
+                                phys, off, page_table, prev_tok, row_slot,
+                                out_row)
+
+        return step
+
+    def _zero_step_args(self):
+        c = self.config
+        S, R = c.max_seqs, self.rows
+        i32 = np.int32
+        return (np.zeros(R, i32), np.full(R, -1, i32), np.zeros(S, i32),
+                np.zeros(R, i32), np.zeros(R, i32),
+                np.zeros((S, c.pages_per_seq), i32))
+
+    def _warm_args(self):
+        S = self.config.max_seqs
+        return self._zero_step_args() + (
+            self._no_prev_tok, np.zeros(self.rows, np.int32),
+            np.arange(S, dtype=np.int32))
+
+    def rows_of_slots(self, tokens, positions, phys, off):
+        """A step's per-row arrays for a caller with one row a slot: the
+        chunk's rows dead.  Returns ``(tokens, positions, phys, off,
+        row_slot, out_row)``."""
+        S, C = self.config.max_seqs, self.config.prefill_tokens_per_step
+
+        def rows(x, fill):
+            return np.concatenate([np.asarray(x, np.int32).reshape(S),
+                                   np.full(C, fill, np.int32)])
+
+        slots = np.arange(S, dtype=np.int32)
+        return (rows(tokens, 0), rows(positions, -1), rows(phys, 0),
+                rows(off, 0), rows(slots, 0), slots)
+
+    def step(self, kv, tokens, positions, seq_lens, phys, off, page_table,
+             prev_tok=None, row_slot=None, out_row=None):
+        """One step over the budget of rows; returns ``(next_tokens (S,),
+        logits (S, V), kv', [held picks, experts touched])``.  ``kv`` is
+        DONATED.  Per row: ``tokens`` (negative: ``prev_tok`` of the row's
+        slot), ``positions`` (-1: a dead row), ``phys`` / ``off``,
+        ``row_slot``; per slot: ``seq_lens[i]``, the cache positions of slot
+        i that the step attends, and ``out_row[i]``, the row that yields its
+        token.  Without ``row_slot`` the arrays are one row a slot (the
+        one-token step's signature) and the chunk rides dead."""
+        self.ensure_compiled()
+        if row_slot is None:
+            tokens, positions, phys, off, row_slot, out_row = \
+                self.rows_of_slots(tokens, positions, phys, off)
+        if prev_tok is None:
+            prev_tok = self._no_prev_tok
+        return self._jit_step(self._params, kv, tokens, positions, seq_lens,
+                              phys, off, page_table, prev_tok, row_slot,
+                              out_row)
+
+    def lowered_step_text(self) -> str:
+        import jax
+        lowered = jax.jit(self._make_step_fn(count=False)).lower(
+            self._params, self.fresh_cache(), *self._warm_args())
+        return lowered.compile().as_text()
+
+
+_PROGRAMS = {TRANSFORMER_LM: DecodeProgram, SARVAM_MLA: LatentDecodeProgram}
+
+
+def program_class(family: str):
+    """The class that builds the decode step of ``family``."""
+    try:
+        return _PROGRAMS[family]
+    except KeyError:
+        raise MXNetError("no decode program for model family %r (has %s)"
+                         % (family, sorted(_PROGRAMS))) from None
 
 
 class DecodeRequest(Request):
@@ -766,18 +1009,21 @@ class _InFlight:
     """One dispatched step whose tokens the host has not taken in yet."""
 
     __slots__ = ("seq", "takers", "attended", "pages", "overlapped",
-                 "next_tok", "guards", "t_dispatch")
+                 "next_tok", "guards", "t_dispatch", "expert_counts")
 
     def __init__(self, seq, takers, attended, pages, overlapped):
         self.seq = seq
-        # (slot index, request, takes a token?, its last by length?) of
-        # every slot the step ran for: the REQUEST, because by the time
-        # the tokens arrive the slot may be somebody else's
+        # (slot index, request, takes a token?, its last by length?, prompt
+        # rows counted as prefilled) of every slot the step ran for: the
+        # REQUEST, because by the time the tokens arrive the slot may be
+        # somebody else's
         self.takers = takers
         self.attended = attended        # sum of the step's seq_lens
         self.pages = pages              # and of the pages they lie on
         self.overlapped = overlapped    # dispatched behind another step
         self.next_tok = None            # the step's out[0], as handed back
+        # a many-token step's out[3] ([held picks, experts touched]), or None
+        self.expert_counts = None
         self.guards = None              # watchdog watch + OOM guard, open
         self.t_dispatch = time.perf_counter()
 
@@ -1059,29 +1305,10 @@ class DecodeEngine(ServingRuntime):
         S = c.max_seqs
         flight = self._flight
         with telemetry.span("serve/build", cat="serve", slots=len(active)):
-            tokens = np.zeros(S, np.int32)
-            positions = np.zeros(S, np.int32)
-            seq_lens = np.zeros(S, np.int32)
-            phys = np.zeros(S, np.int32)      # inactive -> trash page 0
-            off = np.zeros(S, np.int32)
-            takers = []
-            for i in active:
-                slot = self._slots[i]
-                req = slot.req
-                # past the prompt the token is the last step's, on the
-                # device: -1 takes prev_tok[i]
-                tokens[i] = (req.prompt[slot.pos]
-                             if slot.pos < req.n_prompt else -1)
-                positions[i] = slot.pos
-                seq_lens[i] = slot.pos + 1
-                phys[i] = slot.pages[slot.pos // c.page_size]
-                off[i] = slot.pos % c.page_size
-                slot.pos += 1
-                takes = slot.pos >= req.n_prompt
-                last = takes and (
-                    slot.pos - req.n_prompt + 1 >= req.max_new
-                    or slot.pos >= c.max_seq_len)
-                takers.append((i, req, takes, last))
+            build = (self._build_rows if c.prefill_tokens_per_step
+                     else self._build_one_token)
+            step_args, takers, attention = build(active, c)
+            seq_lens = step_args[2]
             # the table as this step saw it: releases and admissions
             # rewrite self._table while the step may still be reading
             table = self._table.copy()
@@ -1089,7 +1316,7 @@ class DecodeEngine(ServingRuntime):
             # and pages go to the next admission now (the device runs
             # steps in order, so a new owner's step writes a page only
             # after this one has read it); its future waits for the token
-            for i, _req, _takes, last in takers:
+            for i, _req, _takes, last, _rows in takers:
                 if last:
                     self._release_slot(i)
             n_decode = sum(t[2] for t in takers)
@@ -1099,12 +1326,15 @@ class DecodeEngine(ServingRuntime):
         try:
             # the span is the outermost, so that arming the watchdog and
             # the OOM guard are host time a trace can name; the two cover
-            # the step from here to its fetch, one iteration on
+            # the step from here to its fetch, one iteration on.
+            # n_prefill: the prompt rows the step does not answer with a
+            # token (one-token step: the slots still in their prompt)
             with telemetry.span(
                     "serve/decode_step", cat="serve", batch=seq,
-                    slots=len(active), n_prefill=len(active) - n_decode,
+                    slots=len(active), n_prefill=sum(t[4] for t in takers),
                     n_decode=n_decode, attended=new.attended,
-                    in_flight=int(new.overlapped)):
+                    in_flight=int(new.overlapped),
+                    **attention) as step_span:
                 with contextlib.ExitStack() as guards:
                     if self._exec_timeout is not None:
                         guards.enter_context(self._ensure_watchdog().watch(
@@ -1117,25 +1347,141 @@ class DecodeEngine(ServingRuntime):
                     chaos.maybe_replica_crash(seq)
                     chaos.maybe_hedge_lag(seq)
                     with telemetry.span("serve/dispatch", cat="serve"):
-                        next_tok, _logits, self._kv = prog.step(
-                            self._kv, tokens, positions, seq_lens, phys,
-                            off, table, self._prev_tok)
+                        out = prog.step(self._kv, *step_args[:5], table,
+                                        self._prev_tok, *step_args[5:])
                         # what the step handed back, and nothing kept
                         # elsewhere, is what the next step is fed
-                        self._prev_tok = new.next_tok = next_tok
-                        start_copy = getattr(next_tok,
-                                             "copy_to_host_async", None)
-                        if start_copy is not None:
-                            start_copy()
+                        self._prev_tok = new.next_tok = out[0]
+                        self._kv = out[2]
+                        if len(out) > 3:
+                            new.expert_counts = out[3]
+                        for arr in (out[0], new.expert_counts):
+                            start_copy = getattr(arr, "copy_to_host_async",
+                                                 None)
+                            if start_copy is not None:
+                                start_copy()
                     new.guards = guards.pop_all()
                 self._flight = new
                 with telemetry.span("serve/fetch", cat="serve"):
                     next_np = (None if flight is None
                                else self._fetch(flight))
+                if flight is not None and flight.expert_counts is not None:
+                    # of the step just fetched, one behind this span's own
+                    step_span.annotate(
+                        expert_rows=int(flight.expert_counts[0]),
+                        experts_touched=int(flight.expert_counts[1]))
         except Exception as e:
             self._step_failed(e, (flight, new))
             return
         self._take_in(flight, next_np)
+
+    def _build_one_token(self, active: List[int], c: DecodeConfig):
+        """The one-token step's arrays, one row a slot: ``((tokens,
+        positions, seq_lens, phys, off), takers, {})`` (a row attends its
+        slot's one context, which the span's ``attended`` has).  Positions
+        advance here, at dispatch.  A slot's last prompt token is counted as
+        the decode step it is (its taker's prefilled rows are 0)."""
+        S = c.max_seqs
+        tokens = np.zeros(S, np.int32)
+        positions = np.zeros(S, np.int32)
+        seq_lens = np.zeros(S, np.int32)
+        phys = np.zeros(S, np.int32)      # inactive -> trash page 0
+        off = np.zeros(S, np.int32)
+        takers = []
+        for i in active:
+            slot = self._slots[i]
+            req = slot.req
+            # past the prompt the token is the last step's, on the
+            # device: -1 takes prev_tok[i]
+            tokens[i] = (req.prompt[slot.pos]
+                         if slot.pos < req.n_prompt else -1)
+            positions[i] = slot.pos
+            seq_lens[i] = slot.pos + 1
+            phys[i] = slot.pages[slot.pos // c.page_size]
+            off[i] = slot.pos % c.page_size
+            slot.pos += 1
+            takes = slot.pos >= req.n_prompt
+            last = takes and (
+                slot.pos - req.n_prompt + 1 >= req.max_new
+                or slot.pos >= c.max_seq_len)
+            takers.append((i, req, takes, last, int(not takes)))
+        return (tokens, positions, seq_lens, phys, off), takers, {}
+
+    def _build_rows(self, active: List[int], c: DecodeConfig):
+        """The many-token step's arrays under its fixed budget of rows:
+        ``((tokens, positions, seq_lens, phys, off, row_slot, out_row),
+        takers, the step span's attention counts)``: ``attn_pairs``, the
+        positions attended summed over the rows (a chunk row attends up to
+        its own position), of them ``chunk_pairs`` the chunk rows', and
+        ``chunk_attended``, the contexts the chunk's slots hold after it
+        (what an expanded-form prefill would up-project).  Row i < S is slot i's own
+        (a decoding slot's token, -1: ``prev_tok[i]``); the chunk's rows go
+        to the slots that still hold prompt, oldest admission first, each
+        slot's rows starting a block of the program's ``chunk_block``; what
+        the budget does not reach waits for the next step.  Positions advance
+        here, at dispatch; a slot's last prompt row yields its first token;
+        every prompt row taken counts as prefilled."""
+        S, C, page = c.max_seqs, c.prefill_tokens_per_step, c.page_size
+        block = self._program.chunk_block
+        R = S + C
+        tokens = np.zeros(R, np.int32)
+        positions = np.full(R, -1, np.int32)     # a dead row
+        phys = np.zeros(R, np.int32)             # ... on the trash page
+        off = np.zeros(R, np.int32)
+        row_slot = np.zeros(R, np.int32)
+        row_slot[:S] = np.arange(S)
+        seq_lens = np.zeros(S, np.int32)
+        out_row = np.arange(S, dtype=np.int32)
+        takers = []
+        chunk_pairs = chunk_attended = 0
+        in_prompt = []
+        for i in active:
+            slot = self._slots[i]
+            req = slot.req
+            if slot.pos < req.n_prompt:
+                in_prompt.append(i)
+                continue
+            tokens[i] = -1
+            positions[i] = slot.pos
+            seq_lens[i] = slot.pos + 1
+            phys[i] = slot.pages[slot.pos // page]
+            off[i] = slot.pos % page
+            slot.pos += 1
+            last = (slot.pos - req.n_prompt + 1 >= req.max_new
+                    or slot.pos >= c.max_seq_len)
+            takers.append((i, req, True, last, 0))
+        decode_pairs = int(seq_lens.sum())
+        at = 0                                  # chunk rows given out
+        for i in sorted(in_prompt, key=lambda j: self._slots[j].req.seq):
+            if at >= C:
+                break
+            slot = self._slots[i]
+            req = slot.req
+            n = min(req.n_prompt - slot.pos, C - at)
+            first = S + at
+            rows = slice(first, first + n)
+            pos = slot.pos + np.arange(n, dtype=np.int32)
+            tokens[rows] = req.prompt[slot.pos:slot.pos + n]
+            positions[rows] = pos
+            phys[rows] = np.asarray(slot.pages, np.int32)[pos // page]
+            off[rows] = pos % page
+            whole = -(-n // block) * block      # its blocks, the last padded
+            row_slot[first:first + whole] = i
+            at += whole
+            slot.pos += n
+            seq_lens[i] = slot.pos
+            chunk_pairs += int(pos.sum()) + n
+            chunk_attended += slot.pos
+            takes = slot.pos >= req.n_prompt
+            if takes:
+                out_row[i] = first + n - 1
+            last = takes and (req.max_new <= 1
+                              or slot.pos >= c.max_seq_len)
+            takers.append((i, req, takes, last, n))
+        return ((tokens, positions, seq_lens, phys, off, row_slot, out_row),
+                takers, {"attn_pairs": decode_pairs + chunk_pairs,
+                         "chunk_pairs": chunk_pairs,
+                         "chunk_attended": chunk_attended})
 
     def _drain(self):
         """Take in the step in flight with none dispatched behind it."""
@@ -1150,9 +1496,12 @@ class DecodeEngine(ServingRuntime):
 
     @staticmethod
     def _fetch(flight: _InFlight) -> np.ndarray:
-        """Wait for a step's tokens; its watchdog watch and OOM guard,
-        open since its dispatch, close here (on an error, with it)."""
+        """Wait for a step's tokens (and what its expert layers counted);
+        its watchdog watch and OOM guard, open since its dispatch, close
+        here (on an error, with it)."""
         with flight.guards:
+            if flight.expert_counts is not None:
+                flight.expert_counts = np.asarray(flight.expert_counts)
             return np.asarray(flight.next_tok)
 
     def _step_failed(self, e: BaseException, records):
@@ -1211,13 +1560,13 @@ class DecodeEngine(ServingRuntime):
         n_prefill = n_decode = 0
         ended = []
         now = time.monotonic()
-        for i, req, takes, last in flight.takers:
+        for i, req, takes, last, rows in flight.takers:
             if req.done:
                 # swept, evicted, cancelled or ended on eos while this
                 # step ran for it: no token, no count, no late OK
                 continue
+            n_prefill += rows
             if not takes:
-                n_prefill += 1
                 continue
             n_decode += 1
             tok = int(next_np[i])
@@ -1333,6 +1682,8 @@ class DecodeEngine(ServingRuntime):
             # steps dispatched while another was in flight: over "steps",
             # how often the loop hid the host behind the device
             "steps_overlapped": counters.get("steps_overlapped", 0),
+            # the page pool as the program laid it out, in its dtype
+            "pool_bytes": self._program.cache_bytes,
             "compiles": self._program.trace_count,
             "quantize": c.quantize,
         }
