@@ -304,6 +304,50 @@ def _build_mesh(mesh):
     return MeshSpec.build(dict(mesh))
 
 
+class _StepOperands:
+    """What a step's call hands the runtime from the host: ONE int32
+    vector, ``tokens | positions | seq_lens | phys | off | page_table``
+    (and ``row_slot | out_row`` behind them for a many-token step) at
+    static offsets that follow from the config alone.  One, because every
+    host array a jitted call is given is a host-to-device transfer of its
+    own and costs the calling thread as much whatever its size (0.13 ms
+    on the v5e's runtime).  :meth:`pack` builds the vector on the host,
+    :meth:`unpack` cuts it apart inside the jitted step by static
+    slices."""
+
+    def __init__(self, config: DecodeConfig):
+        S = config.max_seqs
+        R = S + config.prefill_tokens_per_step      # rows of one step
+        shapes = [("tokens", (R,)), ("positions", (R,)), ("seq_lens", (S,)),
+                  ("phys", (R,)), ("off", (R,)),
+                  ("page_table", (S, config.pages_per_seq))]
+        if config.prefill_tokens_per_step:
+            shapes += [("row_slot", (R,)), ("out_row", (S,))]
+        self.fields = []            # (name, shape, start, stop)
+        at = 0
+        for name, shape in shapes:
+            n = int(np.prod(shape))
+            self.fields.append((name, shape, at, at + n))
+            at += n
+        self.size = at
+
+    def pack(self, *arrays) -> np.ndarray:
+        """The operands, in the order of ``fields``, as one fresh host
+        vector (a new one a step: the runtime may still be reading the
+        last step's)."""
+        if len(arrays) != len(self.fields):
+            raise MXNetError("a step takes %s, got %d operands"
+                             % ([f[0] for f in self.fields], len(arrays)))
+        packed = np.empty(self.size, np.int32)
+        for (_name, shape, start, stop), x in zip(self.fields, arrays):
+            packed[start:stop].reshape(shape)[...] = x
+        return packed
+
+    def unpack(self, packed):
+        return tuple(packed[start:stop].reshape(shape)
+                     for _name, shape, start, stop in self.fields)
+
+
 class DecodeProgram:
     """One compiled decode step + its weights + cache geometry.
 
@@ -353,18 +397,24 @@ class DecodeProgram:
         self._params = {k: self._place_param(k, v) for k, v in host.items()}
         telemetry.memory.tag(list(self._params.values()), "served",
                              label="DecodeProgram(%s)" % name)
+        # as a step's call hands them over: a flat tuple (a dict is sorted
+        # and walked on every call)
+        leaves, self._param_tree = jax.tree_util.tree_flatten(self._params)
+        self._param_leaves = tuple(leaves)
         self.trace_count = 0          # bumps INSIDE the traced step: the
         # compile-once oracle (a retrace is a bug, not a slow path)
-        self._jit_step = self._make_jit_step()
-        # what the step gets as prev_tok from a caller that has none;
-        # under a mesh, placed as the step's own next_tokens come back
-        # (replicated), or handing those in would be a second executable
-        self._no_prev_tok = np.zeros(config.max_seqs, np.int32)
-        if self.spec is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            self._no_prev_tok = jax.device_put(
-                self._no_prev_tok,
-                NamedSharding(self.spec.mesh, PartitionSpec()))
+        self._operands = _StepOperands(config)
+        self._jit_step = jax.jit(self._packed_step_fn(),
+                                 donate_argnums=(1,))
+        # what the step gets as prev_tok from a caller that has none: on
+        # the device, as the step's own next_tokens are (a call hands over
+        # one host array, the packed operands); under a mesh, placed as
+        # those come back (replicated), or handing them in would be a
+        # second executable
+        self._no_prev_tok = jax.device_put(
+            np.zeros(config.max_seqs, np.int32),
+            None if self.spec is None else jax.sharding.NamedSharding(
+                self.spec.mesh, jax.sharding.PartitionSpec()))
         self._compiled = False
         self._compile_lock = threading.Lock()
         # generic program surface (schema checks, canary, fleet batch
@@ -634,11 +684,31 @@ class DecodeProgram:
 
         return step
 
-    def _make_jit_step(self):
+    def _packed_step_fn(self, count=True):
+        """What is jitted: ``(param leaves, kv, packed, prev_tok)``, the
+        host's operands as :class:`_StepOperands` packs them, cut apart on
+        the device and handed to :meth:`_make_step_fn`'s function as the
+        arguments it takes.  The parameters are arguments (the flat tuple
+        ``_param_leaves``) and not closed over: a closed-over array is
+        baked into the module as a constant (docs/deploy.md "One step in
+        flight")."""
         import jax
-        return jax.jit(self._make_step_fn(), donate_argnums=(1,))
+        fn = self._make_step_fn(count)
+        unpack = self._operands.unpack
+        tree = self._param_tree
+
+        def packed_step(leaves, kv, packed, prev_tok):
+            (tokens, positions, seq_lens, phys, off, page_table,
+             *rows) = unpack(packed)
+            return fn(jax.tree_util.tree_unflatten(tree, leaves), kv, tokens,
+                      positions, seq_lens, phys, off, page_table, prev_tok,
+                      *rows)
+
+        return packed_step
 
     def _zero_step_args(self):
+        """A step's host operands in the order :class:`_StepOperands`
+        packs them, for a step in which no slot is live."""
         c = self.config
         S = c.max_seqs
         i32 = np.int32
@@ -648,7 +718,23 @@ class DecodeProgram:
 
     def _warm_args(self):
         """Everything the jitted step takes after the pool, all zeros."""
-        return self._zero_step_args() + (self._no_prev_tok,)
+        return (self._operands.pack(*self._zero_step_args()),
+                self._no_prev_tok)
+
+    def handed_over(self, prev_tok=None) -> tuple:
+        """``(host arrays, device arrays)`` that one call of :meth:`step`
+        with this ``prev_tok`` hands the runtime: the packed operands (and
+        ``prev_tok`` where a caller made it on the host); the parameters'
+        leaves, the pool and a ``prev_tok`` that stayed on the device."""
+        host_tok = isinstance(prev_tok, np.ndarray)
+        return 1 + host_tok, len(self._param_leaves) + 2 - host_tok
+
+    def _call(self, kv, operands, prev_tok):
+        self.ensure_compiled()
+        if prev_tok is None:
+            prev_tok = self._no_prev_tok
+        return self._jit_step(self._param_leaves, kv,
+                              self._operands.pack(*operands), prev_tok)
 
     def step(self, kv, tokens, positions, seq_lens, phys, off,
              page_table, prev_tok=None):
@@ -659,12 +745,10 @@ class DecodeProgram:
         ``next_tokens`` as it came back and a decoding slot's token need
         not pass through the host (:class:`DecodeEngine`).  The jitted
         step always gets the array (zeros when the caller has none), so
-        there is one trace and one executable either way."""
-        self.ensure_compiled()
-        if prev_tok is None:
-            prev_tok = self._no_prev_tok
-        return self._jit_step(self._params, kv, tokens, positions,
-                              seq_lens, phys, off, page_table, prev_tok)
+        there is one trace and one executable either way, and the six
+        host arrays reach it as one (:class:`_StepOperands`)."""
+        return self._call(kv, (tokens, positions, seq_lens, phys, off,
+                               page_table), prev_tok)
 
     def ensure_compiled(self):
         """Compile the step once, visibly: the first build rides a
@@ -681,7 +765,8 @@ class DecodeProgram:
             with telemetry.span("compile/decode_step", cat="compile",
                                 metric="compile.seconds", timed=True,
                                 program=self.name) as sp:
-                out = self._jit_step(self._params, kv, *self._warm_args())
+                out = self._jit_step(self._param_leaves, kv,
+                                     *self._warm_args())
             import jax
             jax.block_until_ready(out[0])
             telemetry.tracing.note_compile("decode_step", sp.duration,
@@ -693,8 +778,8 @@ class DecodeProgram:
         """Optimized HLO of the step program (collective audits, GC307
         companions)."""
         import jax
-        lowered = jax.jit(self._make_step_fn(count=False)).lower(
-            self._params, self.fresh_cache(), *self._zero_step_args())
+        lowered = jax.jit(self._packed_step_fn(count=False)).lower(
+            self._param_leaves, self.fresh_cache(), *self._warm_args())
         return lowered.compile().as_text()
 
     # -- generic batch surface (canary, fleet batch mode) ------------------
@@ -902,13 +987,8 @@ class LatentDecodeProgram(DecodeProgram):
         i32 = np.int32
         return (np.zeros(R, i32), np.full(R, -1, i32), np.zeros(S, i32),
                 np.zeros(R, i32), np.zeros(R, i32),
-                np.zeros((S, c.pages_per_seq), i32))
-
-    def _warm_args(self):
-        S = self.config.max_seqs
-        return self._zero_step_args() + (
-            self._no_prev_tok, np.zeros(self.rows, np.int32),
-            np.arange(S, dtype=np.int32))
+                np.zeros((S, c.pages_per_seq), i32), np.zeros(R, i32),
+                np.arange(S, dtype=i32))
 
     def rows_of_slots(self, tokens, positions, phys, off):
         """A step's per-row arrays for a caller with one row a slot: the
@@ -934,21 +1014,11 @@ class LatentDecodeProgram(DecodeProgram):
         i that the step attends, and ``out_row[i]``, the row that yields its
         token.  Without ``row_slot`` the arrays are one row a slot (the
         one-token step's signature) and the chunk rides dead."""
-        self.ensure_compiled()
         if row_slot is None:
             tokens, positions, phys, off, row_slot, out_row = \
                 self.rows_of_slots(tokens, positions, phys, off)
-        if prev_tok is None:
-            prev_tok = self._no_prev_tok
-        return self._jit_step(self._params, kv, tokens, positions, seq_lens,
-                              phys, off, page_table, prev_tok, row_slot,
-                              out_row)
-
-    def lowered_step_text(self) -> str:
-        import jax
-        lowered = jax.jit(self._make_step_fn(count=False)).lower(
-            self._params, self.fresh_cache(), *self._warm_args())
-        return lowered.compile().as_text()
+        return self._call(kv, (tokens, positions, seq_lens, phys, off,
+                               page_table, row_slot, out_row), prev_tok)
 
 
 _PROGRAMS = {TRANSFORMER_LM: DecodeProgram, SARVAM_MLA: LatentDecodeProgram}
@@ -1009,9 +1079,11 @@ class _InFlight:
     """One dispatched step whose tokens the host has not taken in yet."""
 
     __slots__ = ("seq", "takers", "attended", "pages", "overlapped",
-                 "next_tok", "guards", "t_dispatch", "expert_counts")
+                 "next_tok", "guards", "t_dispatch", "expert_counts",
+                 "host_operands")
 
-    def __init__(self, seq, takers, attended, pages, overlapped):
+    def __init__(self, seq, takers, attended, pages, overlapped,
+                 host_operands):
         self.seq = seq
         # (slot index, request, takes a token?, its last by length?, prompt
         # rows counted as prefilled) of every slot the step ran for: the
@@ -1021,6 +1093,7 @@ class _InFlight:
         self.attended = attended        # sum of the step's seq_lens
         self.pages = pages              # and of the pages they lie on
         self.overlapped = overlapped    # dispatched behind another step
+        self.host_operands = host_operands  # host arrays its call hands over
         self.next_tok = None            # the step's out[0], as handed back
         # a many-token step's out[3] ([held picks, experts touched]), or None
         self.expert_counts = None
@@ -1320,9 +1393,13 @@ class DecodeEngine(ServingRuntime):
                 if last:
                     self._release_slot(i)
             n_decode = sum(t[2] for t in takers)
+            # what the call hands the runtime: host arrays it must transfer
+            # (the packed operands: 1) and device arrays (parameters, pool,
+            # the last step's tokens)
+            host_operands, device_args = prog.handed_over(self._prev_tok)
             new = _InFlight(seq, takers, int(seq_lens.sum()),
                             int((-(-seq_lens // c.page_size)).sum()),
-                            flight is not None)
+                            flight is not None, host_operands)
         try:
             # the span is the outermost, so that arming the watchdog and
             # the OOM guard are host time a trace can name; the two cover
@@ -1346,7 +1423,9 @@ class DecodeEngine(ServingRuntime):
                     chaos.maybe_slow_exec(seq)
                     chaos.maybe_replica_crash(seq)
                     chaos.maybe_hedge_lag(seq)
-                    with telemetry.span("serve/dispatch", cat="serve"):
+                    with telemetry.span("serve/dispatch", cat="serve",
+                                        host_operands=host_operands,
+                                        device_args=device_args):
                         out = prog.step(self._kv, *step_args[:5], table,
                                         self._prev_tok, *step_args[5:])
                         # what the step handed back, and nothing kept
@@ -1587,6 +1666,7 @@ class DecodeEngine(ServingRuntime):
             self._counters["tokens_decoded"] += n_decode
             self._counters["contexts_attended"] += flight.attended
             self._counters["pages_attended"] += flight.pages
+            self._counters["host_operands"] += flight.host_operands
         # counted first, delivered second: a caller that has its answer
         # finds its tokens in the counts
         for i, req in ended:
@@ -1682,6 +1762,10 @@ class DecodeEngine(ServingRuntime):
             # steps dispatched while another was in flight: over "steps",
             # how often the loop hid the host behind the device
             "steps_overlapped": counters.get("steps_overlapped", 0),
+            # host arrays a step's call handed the runtime to transfer
+            # (the packed operands: 1), over the steps taken in
+            "host_operands_per_step": round(
+                counters.get("host_operands", 0) / steps, 3),
             # the page pool as the program laid it out, in its dtype
             "pool_bytes": self._program.cache_bytes,
             "compiles": self._program.trace_count,
@@ -1720,12 +1804,14 @@ def decode_retrace_report(prog: DecodeProgram):
         active = np.zeros(S, i32)
         active[:n_active] = 1
         positions = np.full(S, pos, i32) * active
-        return (prog._params, prog.fresh_cache(), np.zeros(S, i32),
-                positions, positions + active,
-                np.ones(S, i32) * active, positions % c.page_size,
-                np.ones((S, c.pages_per_seq), i32))
+        operands = list(prog._zero_step_args())
+        operands[1:6] = (positions, positions + active,
+                         np.ones(S, i32) * active, positions % c.page_size,
+                         np.ones((S, c.pages_per_seq), i32))
+        return (prog._param_leaves, prog.fresh_cache(),
+                prog._operands.pack(*operands), prog._no_prev_tok)
 
     return graphcheck.check_decode_retrace(
-        prog._make_step_fn(count=False), args_at(1, S),
+        prog._packed_step_fn(count=False), args_at(1, S),
         args_at(2, max(1, S - 1)),
         target="DecodeProgram(%s)" % prog.name)

@@ -606,6 +606,19 @@ def test_tp2_engine_kill_swap_drill(toy):
         assert ok == 6 and late == 0
     # one executable each, though b's first steps were fed a's tokens
     assert p_a.trace_count == 1 and p_b.trace_count == 1
+    # ... and one EXECUTABLE: with both programs warm, a pipeline started
+    # from scratch (the stand-in for prev_tok, then the step's own tokens,
+    # a fresh pool, then the step's own) compiles nothing
+    import jax.monitoring
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **_kw: compiled.append(event))
+    for p in (p_a, p_b):
+        with DecodeEngine(p, default_deadline=deadline) as eng:
+            assert eng.generate(rs.randint(0, VOCAB, 3),
+                                max_new_tokens=4).size == 4
+    assert not [e for e in compiled if "backend_compile" in e]
+    assert p_a.trace_count == 1 and p_b.trace_count == 1
     # geometry mismatch is refused with the old model still serving
     cfg2 = DecodeConfig(VOCAB, L, H, HEADS, T * 2, page_size=4,
                         max_seqs=cfg.max_seqs)

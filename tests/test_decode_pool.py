@@ -347,6 +347,155 @@ def test_pallas_step_agrees_with_xla_step(cfg_and_params, monkeypatch):
     assert np.abs(got["1"][1] - got["0"][1]).max() < 1e-5
 
 
+# -- one host operand a step ---------------------------------------------
+
+def _latent_program(chunk=32):
+    from test_mla_decode import _cfg, _program
+    return _program(_cfg(), chunk=chunk)[0]
+
+
+def _gpt2_program():
+    cfg = DecodeConfig(VOCAB, LAYERS, 32, 4, T, page_size=4, max_seqs=3)
+    return DecodeProgram(init_decode_params(cfg, seed=5), cfg, name="packed")
+
+
+def _gpt2_steps(prog):
+    """Two steps' separate operands: slots 0 and 1 live at mixed lengths
+    (slot 0 on a page's last row, then on the next page's first; its second
+    token the first step's own), slot 2 inactive."""
+    c = prog.config
+    S, page = c.max_seqs, c.page_size
+    table = np.zeros((S, c.pages_per_seq), np.int32)
+    table[0, :3] = [5, 2, 7]
+    table[1, :2] = [3, 6]
+    steps = []
+    for t in range(2):
+        pos = np.array([page - 1 + t, t, 0], np.int32)
+        live = np.array([1, 1, 0], np.int32)
+        tokens = np.array([-1 if t else 11, 7 + t, 0], np.int32)
+        steps.append((tokens, pos * live, (pos + 1) * live,
+                      table[np.arange(S), pos // page] * live,
+                      (pos % page) * live, table))
+    return steps
+
+
+def _latent_steps(prog):
+    """Two steps of the many-token program: slot 0 decodes (its second
+    token the first step's own), slot 1 takes 20 prompt rows of a budget of
+    32 (a partly dead chunk) and then 12 more over a page's edge, yielding
+    its first token; slot 2 and 3 idle."""
+    c = prog.config
+    S, C, page = c.max_seqs, c.prefill_tokens_per_step, c.page_size
+    R, block = S + C, prog.chunk_block
+    table = np.zeros((S, c.pages_per_seq), np.int32)
+    table[0, :2] = [9, 4]
+    table[1, :5] = [5, 2, 11, 3, 6]
+    steps, done = [], 0
+    for t, n in enumerate((20, 12)):
+        tokens = np.zeros(R, np.int32)
+        positions = np.full(R, -1, np.int32)
+        phys = np.zeros(R, np.int32)
+        off = np.zeros(R, np.int32)
+        row_slot = np.zeros(R, np.int32)
+        row_slot[:S] = np.arange(S)
+        seq_lens = np.zeros(S, np.int32)
+        out_row = np.arange(S, dtype=np.int32)
+        tokens[0], positions[0] = (-1 if t else 13), t
+        phys[0], off[0], seq_lens[0] = table[0, t // page], t % page, t + 1
+        pos = done + np.arange(n)
+        rows = S + np.arange(n)
+        tokens[rows], positions[rows] = (3 + 5 * pos) % c.vocab_size, pos
+        phys[rows], off[rows] = table[1, pos // page], pos % page
+        row_slot[S:S + -(-n // block) * block] = 1
+        done += n
+        seq_lens[1] = done
+        if t:
+            out_row[1] = rows[-1]
+        steps.append((tokens, positions, seq_lens, phys, off, table,
+                      row_slot, out_row))
+    return steps
+
+
+@pytest.mark.parametrize("family", ["transformer_lm", "sarvam_mla"])
+def test_packed_step_equals_the_separate_operands(family):
+    """``step()`` hands the runtime ONE host vector and cuts it apart on the
+    device; tokens, logits and pool are to the bit what the traced function
+    gives when each operand is an argument of its own."""
+    prog, steps = ((_gpt2_program(), _gpt2_steps) if family == "transformer_lm"
+                   else (_latent_program(), _latent_steps))
+    steps = steps(prog)
+    assert [f[0] for f in prog._operands.fields] == [
+        "tokens", "positions", "seq_lens", "phys", "off", "page_table",
+        "row_slot", "out_row"][:len(steps[0])]
+    assert prog._operands.size == sum(a.size for a in steps[0])
+    separate = jax.jit(prog._make_step_fn(count=False))
+    kv_a, kv_b = prog.fresh_cache(), prog.fresh_cache()
+    prev_a = prev_b = None
+    trash = 2 if family == "transformer_lm" else 1      # the pages' axis
+    for ops in steps:
+        out_a = prog.step(kv_a, *ops[:6], prev_a, *ops[6:])
+        out_b = separate(prog._params, kv_b, *ops[:6],
+                         prog._no_prev_tok if prev_b is None else prev_b,
+                         *ops[6:])
+        (prev_a, lg_a, kv_a), (prev_b, lg_b, kv_b) = out_a[:3], out_b[:3]
+        assert np.array_equal(np.asarray(prev_a), np.asarray(prev_b))
+        assert np.array_equal(np.asarray(lg_a), np.asarray(lg_b))
+        # all but the trash page, where dead rows land
+        pool_a = np.moveaxis(np.asarray(kv_a, np.float32), trash, 0)[1:]
+        pool_b = np.moveaxis(np.asarray(kv_b, np.float32), trash, 0)[1:]
+        assert np.array_equal(pool_a, pool_b) and pool_a.any()
+        for x, y in zip(out_a[3:], out_b[3:]):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+    assert np.isfinite(np.asarray(lg_a)[:2]).all()
+    assert prog.trace_count == 1
+    with pytest.raises(mxnet_tpu.base.MXNetError):
+        prog._operands.pack(*steps[0][:5])
+
+
+@pytest.mark.parametrize("family", ["transformer_lm", "sarvam_mla"])
+def test_engine_forward_and_step_share_one_executable(family):
+    """The engine (which hands ``prev_tok`` back as it came), ``forward``
+    and a direct ``step()`` without one all go through the one jitted call:
+    one trace, one executable."""
+    from mxnet_tpu.serving.decode import DecodeEngine
+    prog = _gpt2_program() if family == "transformer_lm" \
+        else _latent_program(chunk=16)
+    c = prog.config
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        out = eng.generate(np.arange(5) % c.vocab_size, max_new_tokens=4)
+        st = eng.stats()
+    assert len(out) == 4
+    assert st["decode"]["host_operands_per_step"] == 1.0
+    nxt = prog.forward(np.arange(c.max_seqs * c.forward_len).reshape(
+        c.max_seqs, c.forward_len) % c.vocab_size)[0]
+    assert nxt.shape == (c.max_seqs, 1)
+    steps = (_gpt2_steps if family == "transformer_lm"
+             else _latent_steps_of_slots)(prog)
+    kv, prev = prog.fresh_cache(), None
+    for ops in steps:               # without prev_tok, then with the device's
+        res = prog.step(kv, *ops, prev)
+        prev, kv = res[0], res[2]
+    assert prog.handed_over(None) == (1, len(prog._params) + 2)
+    assert prog.handed_over(prev) == (1, len(prog._params) + 2)
+    assert prog.handed_over(np.asarray(prev)) == (2, len(prog._params) + 1)
+    assert prog.trace_count == 1
+    assert prog._jit_step._cache_size() == 1
+
+
+def _latent_steps_of_slots(prog):
+    """One row a slot (the one-token signature): the chunk rides dead."""
+    c = prog.config
+    S, page = c.max_seqs, c.page_size
+    table = np.zeros((S, c.pages_per_seq), np.int32)
+    table[:, 0] = 1 + np.arange(S)
+    steps = []
+    for t in range(2):
+        pos = np.full(S, t, np.int32)
+        steps.append((np.full(S, -1 if t else 3, np.int32), pos, pos + 1,
+                      table[:, 0].copy(), pos % page, table))
+    return steps
+
+
 # -- the 64-slot step, compiled for a described v5e ------------------------
 
 @pytest.fixture(scope="module")

@@ -239,6 +239,32 @@ def test_engine_emits_its_six_spans_once_a_step(toy, recorder):
     assert all("queued" in a for n, a in entered if n == "serve/admit")
 
 
+def test_dispatch_span_counts_what_a_call_hands_over(toy, recorder):
+    """``serve/dispatch`` says what its call handed the runtime: ONE host
+    array (the packed operands; the last step's tokens stay on the device,
+    and the first step's stand-in lies there too) and the device arrays
+    (every parameter, the pool, those tokens).  ``stats()`` has the host
+    arrays a step, exact over a run."""
+    cfg, prog = toy
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        futures = [eng.submit(np.arange(n) % VOCAB, max_new_tokens=m)
+                   for n, m in ((4, 5), (2, 3), (6, 1))]
+        for f in futures:
+            f.result(timeout=60.0)
+        stats = eng.stats()
+    steps = stats["counters"]["steps"]
+    dispatches = [attrs for _t, what, name, attrs in _engine_log(recorder)
+                  if what == "enter" and name == "serve/dispatch"]
+    assert len(dispatches) == steps > 0
+    assert all(a == {"host_operands": 1,
+                     "device_args": len(prog._params) + 2}
+               for a in dispatches)
+    assert stats["counters"]["host_operands"] == steps
+    assert stats["decode"]["host_operands_per_step"] == 1.0
+    assert prog._operands.size == 5 * cfg.max_seqs \
+        + cfg.max_seqs * cfg.pages_per_seq
+
+
 def test_request_stamps_and_their_percentiles(toy):
     cfg, prog = toy
     with DecodeEngine(prog, default_deadline=60.0) as eng:
