@@ -94,6 +94,9 @@ __all__ = ["DecodeConfig", "PagePool", "DecodeProgram", "LatentDecodeProgram",
            "init_decode_params", "decode_tp_model_bytes", "program_class"]
 
 _MAGIC = "mxnet_tpu-decode-v1"
+# a step counts as stalled where it took over 5 x the exec EWMA and this
+# many seconds, from one fetch's return to the next
+_STALL_FLOOR_S = 0.020
 
 # the model families a decode program exists for (``DecodeConfig.family``):
 # models/transformer.py's GPT-2 block, one token a slot a step, a K/V pool by
@@ -1080,7 +1083,7 @@ class _InFlight:
 
     __slots__ = ("seq", "takers", "attended", "pages", "overlapped",
                  "next_tok", "guards", "t_dispatch", "expert_counts",
-                 "host_operands")
+                 "host_operands", "prev_ready", "ready")
 
     def __init__(self, seq, takers, attended, pages, overlapped,
                  host_operands):
@@ -1098,7 +1101,23 @@ class _InFlight:
         # a many-token step's out[3] ([held picks, experts touched]), or None
         self.expert_counts = None
         self.guards = None              # watchdog watch + OOM guard, open
+        # what the host knew (``_is_ready``): at this step's dispatch, had
+        # the step in flight ended (the device had nothing queued); at this
+        # step's fetch, had it ended itself (the host came late)
+        self.prev_ready = 0
+        self.ready = 0
         self.t_dispatch = time.perf_counter()
+
+
+def _us(seconds: float) -> int:
+    return int(round(1e6 * seconds))
+
+
+def _is_ready(arr) -> bool:
+    """Has the device produced ``arr``, as far as the host knows?  Something
+    without ``is_ready`` (a test's numpy result) counts as ready."""
+    probe = getattr(arr, "is_ready", None)
+    return probe is None or bool(probe())
 
 
 class DecodeEngine(ServingRuntime):
@@ -1256,8 +1275,25 @@ class DecodeEngine(ServingRuntime):
                 self._counters["completed"] += 1
         if delivered and req.latency is not None:
             self._lat_hist.observe(req.latency)
-        telemetry.count("serve.requests",
-                        outcome="ok" if delivered else "late")
+        outcome = "ok" if delivered else "late"
+        telemetry.count("serve.requests", outcome=outcome)
+        # the request's life on its stamps, inside the serve/retire or
+        # serve/admit that settles it (not ``serve/request``: that is the
+        # batch runtime's retrospective lane)
+        attrs = {"n_prompt": req.n_prompt, "n_generated": len(req.generated),
+                 "total_us": _us(now - req.enqueued_at),
+                 "outcome": outcome if error is None
+                 else type(error).__name__}
+        if req.t_dispatched is not None:
+            attrs["queue_wait_us"] = _us(req.t_dispatched - req.enqueued_at)
+        times = req.token_times
+        if times:
+            attrs["ttft_us"] = _us(times[0] - req.enqueued_at)
+        if len(times) > 1:
+            attrs["itl_max_us"] = _us(max(
+                b - a for a, b in zip(times, times[1:])))
+        with telemetry.span("serve/request_done", cat="serve", **attrs):
+            pass
 
     def _sweep_slots(self):
         """Pre-step pass: drop sequences that are already settled (a
@@ -1423,6 +1459,11 @@ class DecodeEngine(ServingRuntime):
                     chaos.maybe_slow_exec(seq)
                     chaos.maybe_replica_crash(seq)
                     chaos.maybe_hedge_lag(seq)
+                    if flight is not None:
+                        # 1: this step goes to a device that, as far as the
+                        # host knows, has nothing queued
+                        new.prev_ready = int(_is_ready(flight.next_tok))
+                        step_span.annotate(prev_ready=new.prev_ready)
                     with telemetry.span("serve/dispatch", cat="serve",
                                         host_operands=host_operands,
                                         device_args=device_args):
@@ -1441,9 +1482,7 @@ class DecodeEngine(ServingRuntime):
                                 start_copy()
                     new.guards = guards.pop_all()
                 self._flight = new
-                with telemetry.span("serve/fetch", cat="serve"):
-                    next_np = (None if flight is None
-                               else self._fetch(flight))
+                next_np = self._fetch(flight)
                 if flight is not None and flight.expert_counts is not None:
                     # of the step just fetched, one behind this span's own
                     step_span.annotate(
@@ -1566,22 +1605,32 @@ class DecodeEngine(ServingRuntime):
         """Take in the step in flight with none dispatched behind it."""
         flight, self._flight = self._flight, None
         try:
-            with telemetry.span("serve/fetch", cat="serve"):
-                next_np = self._fetch(flight)
+            next_np = self._fetch(flight)
         except Exception as e:
             self._step_failed(e, (flight,))
             return
         self._take_in(flight, next_np)
 
     @staticmethod
-    def _fetch(flight: _InFlight) -> np.ndarray:
-        """Wait for a step's tokens (and what its expert layers counted);
-        its watchdog watch and OOM guard, open since its dispatch, close
-        here (on an error, with it)."""
-        with flight.guards:
-            if flight.expert_counts is not None:
-                flight.expert_counts = np.asarray(flight.expert_counts)
-            return np.asarray(flight.next_tok)
+    def _fetch(flight: Optional[_InFlight]) -> Optional[np.ndarray]:
+        """``serve/fetch``: wait for a step's tokens (and what its expert
+        layers counted); its watchdog watch and OOM guard, open since its
+        dispatch, close here (on an error, with it).  The span says which
+        step (``batch``) and whether it had already ended when the host came
+        for it (``ready``: 1 the host was late, 0 the fetch is a wait and
+        the device set this step's pace).  With nothing in flight (the
+        iteration that starts a pipeline) the span is empty."""
+        attrs = {}
+        if flight is not None:
+            flight.ready = int(_is_ready(flight.next_tok))
+            attrs = {"batch": flight.seq, "ready": flight.ready}
+        with telemetry.span("serve/fetch", cat="serve", **attrs):
+            if flight is None:
+                return None
+            with flight.guards:
+                if flight.expert_counts is not None:
+                    flight.expert_counts = np.asarray(flight.expert_counts)
+                return np.asarray(flight.next_tok)
 
     def _step_failed(self, e: BaseException, records):
         """A dispatch or a fetch raised.  The pool was DONATED into a
@@ -1623,11 +1672,13 @@ class DecodeEngine(ServingRuntime):
         token is never counted before it exists.  With no step fetched
         (the iteration that starts a pipeline) the span is empty."""
         with telemetry.span("serve/retire", cat="serve") as rsp:
-            rsp.annotate(retired=0 if flight is None
-                         else self._count_and_settle(flight, next_np))
+            rsp.annotate(**({"retired": 0, "decoded": 0} if flight is None
+                            else self._count_and_settle(flight, next_np)))
 
     def _count_and_settle(self, flight: _InFlight,
-                          next_np: np.ndarray) -> int:
+                          next_np: np.ndarray) -> dict:
+        """Returns the ``serve/retire`` span's attrs: requests ``retired``,
+        tokens ``decoded`` and, where the step stalled, ``stalled_ms``."""
         c = self._program.config
         self._breaker.record_success()
         # what a step costs a caller: from the last fetch's return to
@@ -1658,10 +1709,19 @@ class DecodeEngine(ServingRuntime):
             if last or (c.eos_id is not None and tok == c.eos_id):
                 ended.append((i, req))
         with self._lock:
+            # a completion the host learned of late (or a step that hung):
+            # far outside what steps have been taking
+            stalled = self._exec_ewma > 0.0 and step_time > max(
+                5.0 * self._exec_ewma, _STALL_FLOOR_S)
             self._exec_ewma = (step_time if self._exec_ewma == 0.0 else
                                0.8 * self._exec_ewma + 0.2 * step_time)
             self._counters["steps"] += 1
             self._counters["steps_overlapped"] += flight.overlapped
+            self._counters["steps_starved"] += flight.prev_ready
+            self._counters["fetches_waited"] += 1 - flight.ready
+            if stalled:
+                self._counters["stalls"] += 1
+                self._counters["stall_seconds"] += step_time
             self._counters["tokens_prefilled"] += n_prefill
             self._counters["tokens_decoded"] += n_decode
             self._counters["contexts_attended"] += flight.attended
@@ -1684,7 +1744,10 @@ class DecodeEngine(ServingRuntime):
                             kind="prefill")
         telemetry.window_tick()
         telemetry.memory.note_step(flight.seq)
-        return len(ended)
+        attrs = {"retired": len(ended), "decoded": n_decode}
+        if stalled:
+            attrs["stalled_ms"] = round(1e3 * step_time, 3)
+        return attrs
 
     # -- swap / stats --------------------------------------------------------
     def _validate_swap(self, source, canary_inputs=None):
@@ -1762,6 +1825,16 @@ class DecodeEngine(ServingRuntime):
             # steps dispatched while another was in flight: over "steps",
             # how often the loop hid the host behind the device
             "steps_overlapped": counters.get("steps_overlapped", 0),
+            # what the host knew of the device: steps dispatched when the
+            # one in flight had already ended (over "steps": how often the
+            # device had nothing queued), fetches that found their step
+            # still running (over "steps": how often the device set the
+            # pace), and steps that took over 5 x the exec EWMA and 20 ms
+            # (a completion learned of late, a hang) with their seconds
+            "steps_starved": counters.get("steps_starved", 0),
+            "fetches_waited": counters.get("fetches_waited", 0),
+            "stalls": counters.get("stalls", 0),
+            "stall_seconds": round(counters.get("stall_seconds", 0.0), 6),
             # host arrays a step's call handed the runtime to transfer
             # (the packed operands: 1), over the steps taken in
             "host_operands_per_step": round(

@@ -4,8 +4,10 @@
 ``jax.profiler.TraceAnnotation`` of the same name and attrs, entered for
 the span's lifetime whether or not anything here is armed: any
 ``jax.profiler`` trace of the process therefore holds the program's spans
-on the host plane and the device's operations on ONE clock
-(``benchmark/lib/program_trace.py`` reads both).  While no trace is being
+on the host plane and the device's operations in ONE file, on clocks the
+profiler lines up to about a millisecond
+(``benchmark/lib/program_trace.py`` reads both, ``step_pipeline.py``
+checks the clocks).  While no trace is being
 taken the annotation is one flag check in C++ and encodes nothing.
 
 Beyond that it times the region and, depending on what is armed, feeds

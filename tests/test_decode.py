@@ -447,6 +447,122 @@ def test_lone_generate_takes_its_steps_and_no_idle_sleep(toy, monkeypatch):
     assert not waits
 
 
+@pytest.fixture
+def spans_seen(monkeypatch):
+    """[(name, attrs)] of every ``telemetry.span`` closed while the test
+    runs, on whatever thread."""
+    from mxnet_tpu import telemetry
+    seen = []
+
+    class Recording(telemetry.span):
+        def __exit__(self, *exc):
+            seen.append((self.name, dict(self.attrs)))
+            return super().__exit__(*exc)
+
+    monkeypatch.setattr(telemetry, "span", Recording)
+    return seen
+
+
+def test_engine_says_what_it_knew_of_the_device(toy, spans_seen):
+    """(f) The pipeline's own account: a step's span says whether the step
+    in flight had ended at its dispatch (``prev_ready``), a fetch names its
+    step and says whether it found it ended (``ready``), a retire counts the
+    tokens it handed out, a settled request leaves one
+    ``serve/request_done``; ``stats()`` sums the same."""
+    cfg, _params, prog = toy
+    rs = np.random.RandomState(11)
+    work = [(rs.randint(0, VOCAB, n), m)
+            for n, m in ((3, 6), (7, 5), (1, 4), (5, 1), (4, 9))]
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        reqs = [eng.submit(p, max_new_tokens=m) for p, m in work]
+        outs = [r.result(timeout=60)[0].tolist() for r in reqs]
+        doomed = eng.submit(np.array([1, 2], np.int32), max_new_tokens=13,
+                            deadline=0.001)
+        with pytest.raises(DeadlineExceeded):
+            doomed.result(timeout=30)
+    st = eng.stats()        # closed: the worker has written its last
+    d, steps = st["decode"], st["counters"]["steps"]
+    assert 0 <= d["steps_starved"] <= d["steps_overlapped"] < steps
+    assert 0 <= d["fetches_waited"] <= steps
+    assert d["steps_starved"] + d["fetches_waited"] <= 2 * steps
+    assert d["stalls"] >= 0 and d["stall_seconds"] >= 0.0
+    by_name = {}
+    for name, attrs in spans_seen:
+        by_name.setdefault(name, []).append(attrs)
+    dispatched = by_name["serve/decode_step"]
+    fetched = [a for a in by_name["serve/fetch"] if a]
+    # every step taken in was fetched once, by its number
+    assert sorted(a["batch"] for a in fetched) \
+        == sorted(a["batch"] for a in dispatched)[:len(fetched)]
+    assert len(fetched) == steps
+    assert sum(1 - a["ready"] for a in fetched) == d["fetches_waited"]
+    # a step that starts a pipeline has nothing in flight to ask after
+    for a in dispatched:
+        assert ("prev_ready" in a) == bool(a["in_flight"])
+    assert sum(a.get("prev_ready", 0) for a in dispatched
+               if a["batch"] in {f["batch"] for f in fetched}) \
+        == d["steps_starved"]
+    assert sum(a["decoded"] for a in by_name["serve/retire"]) \
+        == d["tokens_decoded"] \
+        == sum(m for _p, m in work) + len(doomed.generated)
+    done = by_name["serve/request_done"]
+    ok = [a for a in done if a["outcome"] == "ok"]
+    assert sorted((a["n_prompt"], a["n_generated"]) for a in ok) \
+        == sorted((len(p), len(o)) for (p, _m), o in zip(work, outs))
+    for a in ok:
+        assert 0 <= a["queue_wait_us"] <= a["ttft_us"] <= a["total_us"]
+        assert ("itl_max_us" in a) == (a["n_generated"] > 1)
+    # the doomed request was settled by the engine (its span says on what)
+    # or shed by the queue before it saw a slot (no span)
+    rest = [a for a in done if a["outcome"] != "ok"]
+    assert [a["outcome"] for a in rest] in ([], ["DeadlineExceeded"])
+    assert sum(a["retired"] for a in by_name["serve/retire"]) == len(ok)
+
+
+def test_a_slowed_step_counts_as_a_stall(toy, spans_seen):
+    """(g) A step that takes far longer than the steps before it (here the
+    straggler drill's sleep before step 9's dispatch, which the fetch of
+    step 8 waits out) is a stall: counted, its seconds summed, and named on
+    the ``serve/retire`` that took it in."""
+    from mxnet_tpu.resilience import chaos
+    cfg, _params, prog = toy
+    try:
+        with DecodeEngine(prog, default_deadline=60.0) as eng, \
+                chaos.inject("slow_exec", at_step=9, seconds=0.5):
+            eng.generate(np.arange(4) % VOCAB, max_new_tokens=10)
+        st = eng.stats()
+    finally:
+        chaos.reset()
+    stalled = [a["stalled_ms"] for name, a in spans_seen
+               if name == "serve/retire" and "stalled_ms" in a]
+    assert st["decode"]["stalls"] == len(stalled) >= 1
+    assert max(stalled) >= 500.0
+    assert st["decode"]["stall_seconds"] == pytest.approx(
+        sum(stalled) / 1e3, abs=1e-3)
+
+
+def test_a_result_without_is_ready_counts_as_ready(toy, monkeypatch):
+    """(h) A step that hands back host arrays (a test's wrapper) has
+    nothing to wait for: every dispatch behind another counts as starved,
+    no fetch as a wait."""
+    cfg, _params, prog = toy
+    inner = prog.step
+
+    def step(*args):
+        out = inner(*args)
+        return (np.asarray(out[0]),) + tuple(out[1:])
+
+    monkeypatch.setattr(prog, "step", step)
+    with DecodeEngine(prog, default_deadline=60.0) as eng:
+        out = eng.generate(np.arange(4) % VOCAB, max_new_tokens=6)
+    st = eng.stats()
+    assert out.tolist() == _greedy_alone(prog, np.arange(4) % VOCAB, 6)
+    assert st["counters"]["steps"] == 4 + 6 - 1
+    assert st["decode"]["steps_starved"] \
+        == st["decode"]["steps_overlapped"] == 4 + 6 - 2
+    assert st["decode"]["fetches_waited"] == 0
+
+
 def test_quantized_engine_logit_kl_probe(toy):
     """int8/int4 weight-only quantization stays within the quality
     probe: bounded max-KL between f32 and quantized next-token

@@ -168,6 +168,17 @@ def test_engine_emits_its_six_spans_once_a_step(toy, recorder):
     log = _engine_log(recorder)
     # an iteration that finds no work emits serve/admit alone: drop those
     order = [(what, name) for _t, what, name, _a in log if what != "meta"]
+    # a settled request's serve/request_done (PR 37) lies inside the
+    # serve/retire that settled it and is no span of the loop's own
+    done_at = [i for i, item in enumerate(order)
+               if item == ("enter", "serve/request_done")]
+    assert len(done_at) == len(requests)
+    for i in done_at:
+        assert order[i + 1] == ("exit", "serve/request_done")
+        inside = [name for what, name in order[:i] if what == "enter"
+                  and name != "serve/request_done"][-1]
+        assert inside == "serve/retire"
+    order = [item for item in order if item[1] != "serve/request_done"]
     kept = []
     for i, item in enumerate(order):
         lone = (item == ("exit", "serve/admit")
