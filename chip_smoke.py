@@ -377,8 +377,12 @@ def phase_decode(compiles, devices):
     text = prog.lowered_step_text()
     check_no_interpreter(text, "the decode step")
     kernels = mosaic_kernels(text)
-    check(any("decode_attn" in k for k in kernels),
-          "no Mosaic custom call for decode_attn (found %s)" % kernels)
+    budget = prog.config.prefill_tokens_per_step
+    for kernel in ("decode_attn", "chunk_attn"):
+        check(any(kernel in k for k in kernels),
+              "no Mosaic custom call for %s (found %s)" % (kernel, kernels))
+    check(budget == prog.derived_budget(cfg) > 0,
+          "the step takes %s prompt rows, not the derived budget" % budget)
     place, devs = where(list(prog._params.values()))
     check(devs == {devices[0]} and devices[0].platform == "tpu",
           "weights live on %s" % sorted(map(str, devs)))
@@ -398,14 +402,16 @@ def phase_decode(compiles, devices):
     check(not wrong, "greedy tokens differ from the XLA formulation in "
           "requests %s" % wrong)
     return ("%d requests, prompts %s tokens, %d new tokens each == the XLA "
-            "formulation's; %d engine steps (%d prefill + %d decode tokens), "
-            "step traced once, Mosaic decode_attn x %d calls, no "
+            "formulation's one token a slot a step; %d engine steps of %d "
+            "prompt rows (%d prefill + %d decode tokens), step traced once, "
+            "Mosaic decode_attn x %d, chunk_attn x %d calls, no "
             "interpreter, weights on %s, %d programs compiled"
             % (len(prompts), [len(p) for p in prompts], new_tokens,
-               stats["counters"]["steps"],
+               stats["counters"]["steps"], budget,
                stats["decode"]["tokens_prefilled"],
                stats["decode"]["tokens_decoded"],
-               sum("decode_attn" in k for k in kernels), place, programs))
+               sum("decode_attn" in k for k in kernels),
+               sum("chunk_attn" in k for k in kernels), place, programs))
 
 
 LATENT_MODEL = dict(

@@ -35,6 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["two_bit_compress", "fused_attention", "fused_attention_fwd",
            "fused_attention_bwd", "decode_attention",
            "decode_attention_pool", "kv_write", "kv_pack", "quantize_weight",
+           "chunk_attention", "chunk_attn_rows",
            "quant_matmul", "grouped_matmul", "mla_attention", "latent_write",
            "mla_chunk_rows", "latent_row_lanes"]
 
@@ -1244,42 +1245,62 @@ def decode_attention_pool(q: jax.Array, kv: jax.Array, layer,
 
 def _kv_write_kernel(phys_ref, off_ref, layer_ref, new_ref, kv_ref, out_ref,
                      *, D):
-    """One slot: its (2, H, rows, lanes) page with token ``off[s]``
-    replaced by the slot's K and V (which arrive repeated once per token
-    of a row)."""
-    del phys_ref, layer_ref         # the index maps' business
-    off = off_ref[pl.program_id(0)]
+    """One row: its (2, H, rows, lanes) page with token ``off[r]`` replaced
+    by the row's K and V (which arrive repeated once per token of a page
+    row).  Rows that follow one another onto the same page (a chunk's
+    consecutive positions) find it still in ``out_ref`` (the block index did
+    not change, so nothing was written back or fetched again) and add their
+    token to it."""
+    del layer_ref                   # the index maps' business
+    r = pl.program_id(0)
+    off = off_ref[r]
+    again = (r > 0) & (phys_ref[r] == phys_ref[jnp.maximum(r - 1, 0)])
+
+    @pl.when(jnp.logical_not(again))
+    def _():
+        out_ref[...] = kv_ref[...]
+
     pack = kv_ref.shape[3] // D
     hit = jax.lax.broadcasted_iota(jnp.int32, kv_ref.shape, 2) == off // pack
     if pack > 1:                    # several tokens a row: this one's lanes
         lane = jax.lax.broadcasted_iota(jnp.int32, kv_ref.shape, 3)
         first = off % pack * D
         hit = hit & (lane >= first) & (lane < first + D)
-    out_ref[...] = jnp.where(hit, new_ref[...], kv_ref[...])
+    out_ref[...] = jnp.where(hit, new_ref[...], out_ref[...])
 
 
 def kv_write(kv: jax.Array, layer, k: jax.Array, v: jax.Array,
              phys: jax.Array, off: jax.Array) -> jax.Array:
-    """The pool ``kv`` (L, 2, P, H, rows, lanes) with token ``off[s]`` of
-    page ``phys[s]`` of ``layer`` set to ``k[s]`` (at ``[layer, 0]``) and
-    ``v[s]`` (``[layer, 1]``) for every slot ``s`` — written where the
+    """The pool ``kv`` (L, 2, P, H, rows, lanes) with token ``off[r]`` of
+    page ``phys[r]`` of ``layer`` set to ``k[r]`` (at ``[layer, 0]``) and
+    ``v[r]`` (``[layer, 1]``) for every row ``r`` — written where the
     pool lies (``input_output_aliases``; donate ``kv`` and nothing
-    pool-sized is copied).  ``k`` / ``v``: (S, H, D).  Slots that share a
-    page (the inactive ones, all on the trash page) may overwrite one
-    another there in any order."""
-    S, H, D = k.shape
+    pool-sized is copied).  ``k`` / ``v``: (R, H, D), a row a slot's token
+    or a chunk's.  Rows bound for one live page must follow one another;
+    rows that share a page otherwise (the dead ones, all on the trash page)
+    may overwrite one another there in any order."""
+    return _kv_write_call(kv, layer, k, v, phys, off,
+                          interpret=_interpret(kv, k, v))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_write_call(kv, layer, k, v, phys, off, *, interpret):
+    """The kernel's call, under ``jax.jit`` with the layer an operand (as
+    ``_decode_attn_call``): every layer of a step is the same call, traced
+    and lowered once a step."""
+    R, H, D = k.shape
     rows, lanes = kv.shape[4:]
     new = jnp.tile(jnp.stack([k, v], axis=1).astype(kv.dtype)
-                   .reshape(S, 2, H, 1, D), (1, 1, 1, 1, lanes // D))
+                   .reshape(R, 2, H, 1, D), (1, 1, 1, 1, lanes // D))
     page_block = pl.BlockSpec(
         (None, 2, None, H, rows, lanes),
-        lambda s, ph, of, lyr: (lyr[0], 0, ph[s], 0, 0, 0))
+        lambda r, ph, of, lyr: (lyr[0], 0, ph[r], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S,),
+        grid=(R,),
         in_specs=[
             pl.BlockSpec((None, 2, H, 1, lanes),
-                         lambda s, ph, of, lyr: (s, 0, 0, 0, 0)),
+                         lambda r, ph, of, lyr: (r, 0, 0, 0, 0)),
             page_block,
         ],
         out_specs=page_block,
@@ -1290,9 +1311,266 @@ def kv_write(kv: jax.Array, layer, k: jax.Array, v: jax.Array,
             out_shape=_out_struct(kv.shape, kv.dtype, new, kv),
             # operand 4 (after the three scalars and the rows) is the pool
             input_output_aliases={4: 0},
-            interpret=_interpret(kv, k, v), name="kv_write",
+            # a row may find its page in the output the row before left
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret, name="kv_write",
         )(phys.astype(jnp.int32), off.astype(jnp.int32),
           _layer_operand(layer), new, kv)
+
+
+# ---------------------------------------------------------------------------
+# chunk attention: many prompt rows of a slot against the six-axis pool
+# ---------------------------------------------------------------------------
+#
+# A many-token step (``DecodeProgram`` with ``prefill_tokens_per_step``) has,
+# beside the slots' own rows that ``decode_attn`` serves, a chunk of prompt
+# rows in blocks of :func:`chunk_attn_rows`, the live rows of a block one
+# slot's consecutive positions (the block's first row names the slot).
+# ``chunk_attn`` walks ITEMS, one a block: the slot's live pages, G at a time
+# (:func:`_decode_pages_per_cell`), by the kernel's own double-buffered
+# copies out of the pool left in HBM, an item's last group starting the next
+# live item's first, exactly as ``decode_attn`` walks a slot.  So a block of
+# rows reads its slot's context ONCE, where one row a slot would read it once
+# a row.  A block with no live row is skipped: no copy, no product.
+#
+# Per head, scores and the weighted sum are two MXU products a group, in
+# float32 (the package's ``highest``), the softmax the online one with one
+# running max / sum a query row, causal by each row's own limit.  A page row
+# holds ``pack`` tokens side by side on the lanes (``kv_pack``: two at
+# D = 64), so the query arrives once per token of a row, each copy on that
+# token's lanes and zero elsewhere, the copies stacked down the sublanes:
+# (pack * rows, lanes) against the page rows (G * rows_of_a_page, lanes)
+# gives every token's score in the copy of its parity, and the weighted sum
+# of V's rows in that copy holds the answer on the parity's own lanes, which
+# is where the row's accumulator takes it from.  Both products are ONE
+# batched product over the heads (a group's pages lie head by head in the
+# landing buffers), so that the MXU works on one head while the vector unit
+# works on another: 1,496 bundles a group of 8 pages at GPT-2 small's shapes,
+# compiled for the v5e, against 3,331 with a loop over the heads; and the
+# kernel's body is small, which the step's set-up pays for in tracing and
+# lowering it (PERF.md, PR 39).
+
+_CHUNK_ROWS = 16        # query rows of a chunk_attn item
+
+
+def chunk_attn_rows() -> int:
+    """Rows of one ``chunk_attn`` item: the host lays a slot's chunk rows out
+    in blocks of this many (the last padded with dead rows)."""
+    return _CHUNK_ROWS
+
+
+def _chunk_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, q_ref, lim_ref,
+                       k_hbm, v_hbm, o_ref, k_buf, v_buf, m_ref, l_ref,
+                       acc_ref, sems, buf_ref, *, G, D):
+    """One item (the block above has what an item is).  ``slot_ref`` /
+    ``ctx_ref``: the item's slot and the positions its last live row attends
+    (0: a dead item); ``q_ref`` (H, pack * TQ, lanes) the block's queries,
+    scaled, one copy a parity; ``lim_ref`` (pack * TQ, 1) each copy's limit
+    less its parity (a token at ``pack * n + base`` counts where that is
+    below it); ``k_buf`` / ``v_buf`` (2, H, G, rows, lanes) the two landing
+    buffers, a head's pages of a group side by side."""
+    i = pl.program_id(0)
+    N = pl.num_programs(0)
+    _, H, _, rows, lanes = k_buf.shape
+    pack = lanes // D
+    page = rows * pack
+    T = G * rows                                    # page rows of a group
+    M = q_ref.shape[1]
+    TQ = M // pack
+    layer = layer_ref[0]
+
+    def live_pages(item):
+        # none for a dead item: it copies nothing
+        return jax.lax.div(ctx_ref[item] + (page - 1), page)
+
+    def live_copies(item, g, b, act):
+        """``act`` (start or wait) on the K and the V copy of every live
+        page of group ``g`` of ``item`` into buffer ``b``: the same
+        descriptors to start as to wait."""
+        slot = slot_ref[item]
+
+        def page_copies(k, carry):
+            phys = pt_ref[slot, g * G + k]
+            act(pltpu.make_async_copy(k_hbm.at[layer, 0, phys],
+                                      k_buf.at[b, :, k], sems.at[b, 0]))
+            act(pltpu.make_async_copy(v_hbm.at[layer, 1, phys],
+                                      v_buf.at[b, :, k], sems.at[b, 1]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(live_pages(item) - g * G, 0, G),
+                          page_copies, 0)
+
+    def start(item, g, b):
+        live_copies(item, g, b, lambda copy: copy.start())
+
+    @pl.when(i == 0)
+    def _first():
+        # a dead page of a group keeps what its buffer held: weight 0 times
+        # that must be 0, so it must never be what VMEM woke up with
+        v_buf[...] = jnp.zeros_like(v_buf)
+        buf_ref[0] = 0
+
+    n_groups = jax.lax.div(live_pages(i) + (G - 1), G)
+    # a live item's first copies were started by the item before it, where
+    # that one was live; otherwise it starts them itself
+    after_live = (i > 0) & (ctx_ref[jnp.maximum(i - 1, 0)] > 0)
+
+    @pl.when((n_groups > 0) & jnp.logical_not(after_live))
+    def _own_start():
+        start(i, 0, buf_ref[0])
+
+    b0 = buf_ref[0]
+    # a dead item walks no group and answers zeros
+    m_ref[...] = jnp.full_like(m_ref, jnp.float32(_NEG_BIG))
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    lim = lim_ref[...]                                          # (M, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (M, T), 1) * pack
+    # a query row's copies, one a parity of a page row's tokens
+    parts = [slice(p * TQ, (p + 1) * TQ) for p in range(pack)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 2) // D
+
+    def group(g, carry):
+        b = (b0 + g) & 1
+        last = g + 1 == n_groups
+        nxt = jnp.where(last, i + 1, i)
+
+        @pl.when(nxt < N)
+        def _prefetch():
+            start(jnp.minimum(nxt, N - 1), jnp.where(last, 0, g + 1),
+                  1 - b)
+
+        live_copies(i, g, b, lambda copy: copy.wait())
+        valid = col + g * (G * page) < lim                      # (M, T)
+
+        # every head's scores, one softmax step for all of them, then
+        # every head's weighted sum
+        s = jax.lax.dot_general(
+            q_ref[...], k_buf[b].reshape(H, T, lanes),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)                 # (H, M, T)
+        s = jnp.where(valid[None], s, jnp.float32(_NEG_BIG))
+        # one running max and sum a query row, over its copies: each
+        # copy's weighted sum lands on its own parity's lanes
+        row_max = jnp.max(s, axis=2, keepdims=True)             # (H, M, 1)
+        m_old = m_ref[...]                                      # (H, TQ, 1)
+        m_new = m_old
+        for part in parts:
+            m_new = jnp.maximum(m_new, row_max[:, part])
+        p = jnp.exp(s - jnp.concatenate([m_new] * pack, axis=1))
+        corr = jnp.exp(m_old - m_new)
+        sums = jnp.sum(p, axis=2, keepdims=True)
+        l_new = l_ref[...] * corr
+        for part in parts:
+            l_new = l_new + sums[:, part]
+        pv = jax.lax.dot_general(
+            p, v_buf[b].reshape(H, T, lanes),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)                 # (H, M, lanes)
+        mine = pv[:, parts[0]]
+        for k, part in enumerate(parts[1:], 1):
+            mine = jnp.where(lane == k, pv[:, part], mine)
+        acc_ref[...] = acc_ref[...] * corr + mine
+        l_ref[...] = l_new
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...],
+                                             jnp.float32(1e-37))
+                  ).astype(o_ref.dtype)
+
+    buf_ref[0] = (b0 + n_groups) & 1
+
+
+@functools.partial(jax.jit, static_argnames=("TQ", "scale", "interpret"))
+def _chunk_attn_call(q, kv, layer, page_table, row_slot, row_limit, *, TQ,
+                     scale, interpret):
+    """The kernel's call, under ``jax.jit`` with the layer an operand, so
+    that the layers of a step share one traced and lowered body (as
+    ``_decode_attn_call``)."""
+    C, H, D = q.shape
+    rows, lanes = kv.shape[4:]
+    pack = lanes // D
+    NB, M = C // TQ, pack * TQ
+    G = _decode_pages_per_cell(H, rows, lanes, D, kv.dtype.itemsize,
+                               page_table.shape[1])
+    limit = row_limit.astype(jnp.int32).reshape(NB, TQ)
+    item_slot = row_slot.astype(jnp.int32).reshape(NB, TQ)[:, 0]
+    item_ctx = jnp.max(limit, axis=1)
+    # (NB, H, pack * TQ, lanes): copy p of a row's query on the lanes of a
+    # page row's token p, zero on the others
+    qs = (q.astype(jnp.float32) * scale).reshape(NB, TQ, H, D) \
+        .transpose(0, 2, 1, 3)
+    qp = jnp.concatenate(
+        [jnp.pad(qs, ((0, 0), (0, 0), (0, 0), (p * D, lanes - (p + 1) * D)))
+         for p in range(pack)], axis=2)
+    lim = jnp.concatenate([limit - p for p in range(pack)], axis=1)[..., None]
+    kern = functools.partial(_chunk_attn_kernel, G=G, D=D)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(NB,),
+        in_specs=[pl.BlockSpec((None, H, M, lanes), lambda i, *_: (i, 0, 0, 0)),
+                  pl.BlockSpec((None, M, 1), lambda i, *_: (i, 0, 0)),
+                  in_hbm, in_hbm],
+        out_specs=pl.BlockSpec((None, H, TQ, lanes),
+                               lambda i, *_: (i, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, H, G, rows, lanes), kv.dtype),
+            pltpu.VMEM((2, H, G, rows, lanes), kv.dtype),
+            pltpu.VMEM((H, TQ, 1), jnp.float32),    # running max
+            pltpu.VMEM((H, TQ, 1), jnp.float32),    # running sum
+            pltpu.VMEM((H, TQ, lanes), jnp.float32),  # accumulator
+            pltpu.SemaphoreType.DMA((2, 2)),        # buffer, K|V
+            pltpu.SMEM((1,), jnp.int32),    # the buffer the item starts in
+        ],
+    )
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            kern, grid_spec=grid_spec,
+            out_shape=_out_struct((NB, H, TQ, lanes), jnp.float32, qp, kv),
+            # an item's first copies are started by the item before it
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret, name="chunk_attn",
+        )(item_slot, item_ctx, _layer_operand(layer),
+          page_table.astype(jnp.int32), qp, lim, kv, kv)
+    out = out.reshape(NB, H, TQ, pack, D).sum(axis=3)
+    return out.transpose(0, 2, 1, 3).reshape(C, H, D).astype(q.dtype)
+
+
+def chunk_attention(q: jax.Array, kv: jax.Array, layer,
+                    page_table: jax.Array, row_slot: jax.Array,
+                    row_limit: jax.Array, scale=None,
+                    use_pallas=None) -> jax.Array:
+    """Attention of a step's chunk rows against their slots' pages in layer
+    ``layer`` of the whole serving pool ``kv`` (L, 2, P, H, rows, lanes),
+    taken as it lies.  ``q``: (C, H, D), C whole blocks of
+    :func:`chunk_attn_rows` whose live rows are one slot's, the slot that
+    ``row_slot`` names for the block's first row; ``row_limit`` (C,): the
+    positions a row attends (those below it; 0: a dead row, whose output is
+    finite and unused).  Returns (C, H, D).  ``use_pallas``: None consults
+    :func:`decode_backend_is_pallas`; the XLA formulation gathers each row's
+    slot's pages."""
+    C, H, D = q.shape
+    if scale is None:
+        scale = _default_scale(D)
+    L, _, P, _, rows, lanes = kv.shape
+    page = rows * (lanes // D)
+    if use_pallas is None:
+        use_pallas = decode_backend_is_pallas(C, H, D, page, q.dtype)
+    if not use_pallas:
+        by_token = kv.reshape(L, 2, P, H, page, D)[layer]
+        return _decode_attn_xla(q, by_token[0], by_token[1],
+                                page_table[row_slot], row_limit, float(scale))
+    if C % _CHUNK_ROWS:
+        raise ValueError("chunk_attention: %d rows are not whole blocks of %d"
+                         % (C, _CHUNK_ROWS))
+    return _chunk_attn_call(q, kv, layer, page_table, row_slot, row_limit,
+                            TQ=_CHUNK_ROWS, scale=float(scale),
+                            interpret=_interpret(q, kv))
 
 
 # ---------------------------------------------------------------------------

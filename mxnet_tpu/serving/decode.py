@@ -14,11 +14,13 @@ split (PAPERS.md): a decode loop whose per-token step
   NEVER change — the step program compiles exactly once, whatever
   sequence lengths come and go (the recompile-per-token trap is
   graphcheck rule GC307);
-* touches the pool with two Pallas kernels and nothing else: ``kv_write``
-  puts the new token's K/V row at ``(page, offset)`` **in place** (the
-  donated pool is aliased into the kernel's result) and ``decode_attn``
+* touches the pool with Pallas kernels and nothing else: ``kv_write``
+  puts each row's K/V at ``(page, offset)`` **in place** (the donated
+  pool is aliased into the kernel's result), ``decode_attn``
   (:func:`~mxnet_tpu.ops.pallas_kernels.decode_attention_pool`) walks
-  the slot's pages via scalar-prefetched indices; both take the whole
+  a slot's pages via scalar-prefetched indices for its own row, and
+  ``chunk_attn`` (:func:`~mxnet_tpu.ops.pallas_kernels.chunk_attention`)
+  for a block of its prompt rows at once; all take the whole
   six-axis pool as it lies (lane-dense pages,
   :meth:`DecodeConfig.pool_shape`), so the compiled step holds no
   slice, scatter or copy of anything pool-sized — or the XLA scatter /
@@ -28,22 +30,25 @@ split (PAPERS.md): a decode loop whose per-token step
 * runs **continuous token-level batching** (:class:`DecodeEngine`):
   a scheduler admits and retires sequences per STEP, so requests join
   and leave the running batch mid-generation — slot allocation from the
-  page pool, prefill chunked into the running batch one token per step,
+  page pool, prefill chunked into the running batch (a chunk of prompt
+  rows a step beside the slots' own, or one token a slot a step),
   admission-queue priorities/eviction and deadlines preserved (a
   retired or evicted sequence can never late-OK: the Request future is
   one-shot); the loop keeps one step in flight — step n+1 is dispatched
   before step n's tokens are fetched, a decoding slot's next token fed
   forward on the device (``prev_tok``) — so the host's work hides
   behind the device's;
+* takes a **many-token step**: the slots' own rows and a chunk of
+  prompt rows under one fixed budget, compiled once, which the engine
+  feeds in chunks under the same contract (a GPT-2 program on one TPU
+  derives its budget from the shapes; 0 is one token a slot a step);
 * is built for a model **family** (``DecodeConfig.family``): GPT-2's
   block is :class:`DecodeProgram` as described above; a latent-attention
   model is :class:`LatentDecodeProgram`, whose block
   ``models/sarvam_mla.py`` supplies: parameters and pool in
   ``DecodeConfig.dtype``, a pool of latent rows ``(L, P, page, W)``
   touched by ``latent_write`` and ``mla_attn``, routed experts in the
-  step, and a **many-token step** (the slots' own rows and a chunk of
-  prompt rows under one fixed budget, compiled once), which the engine
-  feeds in chunks under the same contract;
+  step, and a budget the config names;
 * optionally serves **weight-only quantized** matmuls (int8 / packed
   int4, per-channel scales, dequantization fused in the kernel —
   :func:`~mxnet_tpu.ops.pallas_kernels.quant_matmul`), selected at
@@ -105,6 +110,9 @@ _STALL_FLOOR_S = 0.020
 TRANSFORMER_LM = "transformer_lm"
 SARVAM_MLA = "sarvam_mla"
 
+# rows of one tile of the v5e MXU: a derived budget fills whole tiles
+_MXU_ROWS = 128
+
 # weights the quantized export rewrites (per layer + the head); LN affine
 # params, biases and embeddings stay f32 — they are O(hidden), noise next
 # to the O(hidden²)/O(V·hidden) matmul weights the quantization targets
@@ -128,11 +136,15 @@ class DecodeConfig:
                  model=None):
         # which model family's step this is (the program class is looked up
         # by it), the dtype of its parameters and pool, the prompt rows a
-        # step takes beside the slots' own (0: one token a slot a step), and
-        # the family's own settings (a published config.json's keys)
+        # step takes beside the slots' own (0: one token a slot a step; None:
+        # the program derives them from the shapes,
+        # :meth:`DecodeProgram.derived_budget`), and the family's own
+        # settings (a published config.json's keys)
         self.family = str(family or TRANSFORMER_LM)
         self.dtype = str(dtype or "float32")
-        self.prefill_tokens_per_step = int(prefill_tokens_per_step or 0)
+        self.prefill_tokens_per_step = (
+            None if prefill_tokens_per_step is None
+            else int(prefill_tokens_per_step))
         self.model = dict(model) if model else None
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
@@ -264,13 +276,15 @@ def decode_tp_model_bytes(config: DecodeConfig, tp: int,
     step (the audit-side model a test holds the lowered HLO against):
     Megatron-style head/FFN sharding leaves TWO partial-sum reductions
     per layer — the attention projection and the FFN down-projection —
-    each of the (S, hidden) activation, and the row-sharded vocab head
+    each of the (rows, hidden) activation (the slots' rows and the
+    config's prompt rows), and the row-sharded vocab head
     gathers the (S, vocab) logits back whole (a vocab the tp degree
     does not divide keeps a replicated head per the placement degrade
     rule, and the gather disappears).  Nothing else may move: weights
     and KV pages stay resident in their shards."""
     S, h = config.max_seqs, config.hidden
-    out = {"all-reduce": 2 * config.num_layers * S * h * itemsize}
+    R = S + (config.prefill_tokens_per_step or 0)
+    out = {"all-reduce": 2 * config.num_layers * R * h * itemsize}
     if tp > 1 and config.vocab_size % tp == 0:
         out["all-gather"] = S * config.vocab_size * itemsize
     return out
@@ -365,10 +379,18 @@ class DecodeProgram:
     The page pool (:meth:`DecodeConfig.pool_shape`, float32) is the
     step's one piece of state: made by :meth:`fresh_cache`, donated to
     every step and handed back as the same buffer.  On one device with
-    the Pallas backend the step writes and reads it through ``kv_write``
-    and ``decode_attn`` alone, where it lies; the XLA backend and the tp
-    export scatter into and gather from per-layer slices, and XLA lays
-    the pool out as it sees fit for that.
+    the Pallas backend the step writes and reads it through ``kv_write``,
+    ``decode_attn`` and ``chunk_attn`` alone, where it lies; the XLA
+    backend and the tp export scatter into and gather from per-layer
+    slices, and XLA lays the pool out as it sees fit for that.
+
+    The step takes ``max_seqs`` rows, one a slot, and
+    ``prefill_tokens_per_step`` rows of prompt beside them (the MANY-TOKEN
+    step, :class:`LatentDecodeProgram`'s too): each row with its slot,
+    position and place in the pool, the chunk's rows in blocks of
+    :attr:`chunk_block` whose live rows share a slot, the head on
+    ``out_row``, one row a slot.  Where the config names no budget the
+    program derives one (:meth:`derived_budget`); 0 is the one-token step.
     """
 
     FAMILY = TRANSFORMER_LM
@@ -385,6 +407,10 @@ class DecodeProgram:
                 "%s builds the %s step; the config describes %s (%s)"
                 % (type(self).__name__, self.FAMILY, config.family,
                    config.describe()))
+        if config.prefill_tokens_per_step is None:
+            config = DecodeConfig(**dict(
+                config.to_meta(),
+                prefill_tokens_per_step=self.derived_budget(config, mesh)))
         self._check_config(config, mesh)
         self.config = config
         self.name = name
@@ -463,14 +489,48 @@ class DecodeProgram:
                 shapes[p + ln + "_beta"] = (h,)
         return shapes
 
+    @staticmethod
+    def derived_budget(config: DecodeConfig, mesh=None) -> int:
+        """The prompt rows a step takes where the config names none.  Where
+        the step runs on one TPU through the Pallas kernels: the most whole
+        chunk blocks that, with the slots' own rows, fill the fewest
+        128-row tiles (the v5e MXU's rows) that hold one block beside them
+        (96 at 32 slots, 64 at 64, 112 at 8).  Elsewhere (a mesh, the XLA
+        formulation, the CPU) 0: one token a slot a step."""
+        import jax
+        from ..ops import pallas_kernels as pk
+        S, block = config.max_seqs, pk.chunk_attn_rows()
+        if (mesh is not None or jax.default_backend() != "tpu"
+                or not pk.decode_backend_is_pallas(
+                    S, config.heads, config.head_dim, config.page_size,
+                    config.dtype)):
+            return 0
+        rows = -(-(S + block) // _MXU_ROWS) * _MXU_ROWS
+        return (rows - S) // block * block
+
+    @property
+    def rows(self) -> int:
+        """Rows of one step: the slots' own and the chunk's."""
+        return self.config.max_seqs + self.config.prefill_tokens_per_step
+
+    @property
+    def chunk_block(self) -> int:
+        """Rows of one block of the chunk: a slot's chunk rows start a
+        block, and the live rows of a block are one slot's (the kernel's
+        ``chunk_attn_rows()``)."""
+        from ..ops.pallas_kernels import chunk_attn_rows
+        return chunk_attn_rows()
+
     # -- construction helpers ---------------------------------------------
     @staticmethod
     def _check_config(config, mesh):
-        if config.dtype != "float32" or config.prefill_tokens_per_step:
+        from ..ops.pallas_kernels import chunk_attn_rows
+        block = chunk_attn_rows()
+        if config.dtype != "float32" or config.prefill_tokens_per_step % block:
             raise MXNetError(
-                "the %s step serves in float32, one token a slot a step; "
-                "the config asks for %s" % (TRANSFORMER_LM,
-                                            config.describe()))
+                "the %s step serves in float32 and takes prompt rows in "
+                "whole blocks of %d; the config asks for %s"
+                % (TRANSFORMER_LM, block, config.describe()))
 
     def _check_params(self, host):
         need = {"tok_embed_weight", "pos_embed", "ln_f_gamma",
@@ -592,15 +652,16 @@ class DecodeProgram:
             return (x32 - mean) * inv * p[name + "_gamma"] \
                 + p[name + "_beta"]
 
-        # The pool's two touches a layer, in two formulations.  Pallas:
-        # both kernels take the pool whole and address it by prefetched
-        # scalars, so the compiled step holds no slice, scatter or copy of
-        # anything pool-sized and the donated pool is updated where it
-        # lies.  XLA: a scatter and a gather over per-layer slices of the
-        # pool seen as (L, 2, P, H, page, D) — the form GSPMD can shard
-        # and the CPU runs; XLA is free to re-lay the pool for it (on the
-        # v5e it did, at two copies of the pool a step), so no chip cell
-        # runs it.
+        # The pool's touches a layer, in two formulations.  Pallas: the
+        # kernels take the pool whole and address it by prefetched scalars,
+        # so the compiled step holds no slice, scatter or copy of anything
+        # pool-sized and the donated pool is updated where it lies; the
+        # slots' own rows attend through ``decode_attn``, the chunk's
+        # through ``chunk_attn``.  XLA: a scatter and a gather over
+        # per-layer slices of the pool seen as (L, 2, P, H, page, D), every
+        # row against its slot's table — the form GSPMD can shard and the
+        # CPU runs; XLA is free to re-lay the pool for it (on the v5e it
+        # did, at two copies of the pool a step), so no chip cell runs it.
         by_token = (c.num_layers, 2, c.pool_pages(), H, c.page_size, Dh)
 
         def _write_xla(kv, i, k, v, phys, off):
@@ -609,21 +670,33 @@ class DecodeProgram:
             kv = kv.at[i, 1, phys, :, off, :].set(v.astype(kv.dtype))
             return kv.reshape(c.pool_shape())
 
-        def _attend_xla(q, kv, i, page_table, seq_lens):
+        def _attend_xla(q, kv, i, page_table, limit, row_slot):
             kv = kv.reshape(by_token)
+            if row_slot is not None:
+                page_table = page_table[row_slot]
             return pk.decode_attention(q, kv[i, 0], kv[i, 1], page_table,
-                                       seq_lens, use_pallas=False)
+                                       limit, use_pallas=False)
+
+        def _attend_pallas(q, kv, i, page_table, limit, row_slot):
+            if row_slot is None:
+                return pk.decode_attention_pool(q, kv, i, page_table, limit)
+            S = c.max_seqs
+            return jnp.concatenate([
+                pk.decode_attention_pool(q[:S], kv, i, page_table, limit[:S]),
+                pk.chunk_attention(q[S:], kv, i, page_table, row_slot[S:],
+                                   limit[S:], use_pallas=True)])
 
         _pool_ops_xla = (_write_xla, _attend_xla)
-        _pool_ops_pallas = (pk.kv_write, pk.decode_attention_pool)
+        _pool_ops_pallas = (pk.kv_write, _attend_pallas)
 
         def step(params, kv, tokens, positions, seq_lens, phys, off,
-                 page_table, prev_tok=None):
+                 page_table, prev_tok=None, row_slot=None, out_row=None):
             # ONE trace, ever: shapes are fixed by the config, token
             # positions/lengths/page indices are all data (GC307)
             if count:
                 self.trace_count += 1
             S = c.max_seqs
+            R = tokens.shape[0]                 # S, or S + the chunk's rows
             # a pool handed over by token, (L, 2, P, H, page, D), is taken
             # and given back in that shape; fresh_cache's needs no reshape
             came_as = kv.shape
@@ -633,6 +706,14 @@ class DecodeProgram:
                              and pk.decode_backend_is_pallas(
                                  S, H, Dh, c.page_size, kv.dtype)
                              else _pool_ops_xla)
+            # what a row attends: a slot's own row its slot's seq_lens; a
+            # chunk row up to its own position; a dead row (position -1: a
+            # slot in its prompt, a chunk's padding) nothing
+            limit = seq_lens
+            if row_slot is not None:
+                live = positions >= 0
+                limit = jnp.where(live, positions + 1, 0).astype(jnp.int32)
+                limit = limit.at[:S].set(jnp.where(live[:S], seq_lens, 0))
             # stable device-side names (jax.named_scope: metadata only);
             # no layer index in them, so the layers group in a trace
             scope = jax.named_scope
@@ -640,24 +721,27 @@ class DecodeProgram:
                 if prev_tok is not None:
                     # a negative token stands for "the one the last step
                     # produced for this slot", which never left the device
-                    tokens = jnp.where(tokens < 0, prev_tok, tokens)
+                    tokens = jnp.where(
+                        tokens < 0,
+                        prev_tok if row_slot is None else prev_tok[row_slot],
+                        tokens)
                 x = params["tok_embed_weight"][tokens] \
-                    + params["pos_embed"][positions]      # (S, hidden)
+                    + params["pos_embed"][jnp.maximum(positions, 0)]
             for i in range(c.num_layers):
                 pfx = "l%d_" % i
                 with scope("mx.decode.ln"):
                     a = ln(params, x, pfx + "ln1")
                 with scope("mx.decode.qkv"):
-                    q = lin(params, a, pfx + "q").reshape(S, H, Dh)
-                    k = lin(params, a, pfx + "k").reshape(S, H, Dh)
-                    v = lin(params, a, pfx + "v").reshape(S, H, Dh)
-                # the pool is touched by these two and by nothing else
+                    q = lin(params, a, pfx + "q").reshape(R, H, Dh)
+                    k = lin(params, a, pfx + "k").reshape(R, H, Dh)
+                    v = lin(params, a, pfx + "v").reshape(R, H, Dh)
+                # the pool is touched by these and by nothing else
                 with scope("mx.decode.kv_write"):
                     kv = write(kv, i, k, v, phys, off)
                 with scope("mx.decode.attn"):
-                    att = attend(q, kv, i, page_table, seq_lens)
+                    att = attend(q, kv, i, page_table, limit, row_slot)
                 with scope("mx.decode.proj"):
-                    att = lin(params, att.reshape(S, c.hidden),
+                    att = lin(params, att.reshape(R, c.hidden),
                               pfx + "proj")
                     x = x + att
                 with scope("mx.decode.ln"):
@@ -668,7 +752,8 @@ class DecodeProgram:
                     f = lin(params, f, pfx + "ff2")
                     x = x + f
             with scope("mx.decode.ln"):
-                x = ln(params, x, "ln_f")
+                # one row a slot yields its token
+                x = ln(params, x if out_row is None else x[out_row], "ln_f")
             with scope("mx.decode.head"):
                 logits = lin(params, x, "head")           # (S, vocab)
                 if sharded:
@@ -711,13 +796,32 @@ class DecodeProgram:
 
     def _zero_step_args(self):
         """A step's host operands in the order :class:`_StepOperands`
-        packs them, for a step in which no slot is live."""
+        packs them, for a step in which no slot is live (and every chunk
+        row is dead)."""
         c = self.config
-        S = c.max_seqs
+        S, R = c.max_seqs, self.rows
         i32 = np.int32
-        return (np.zeros(S, i32), np.zeros(S, i32), np.zeros(S, i32),
-                np.zeros(S, i32), np.zeros(S, i32),
-                np.zeros((S, c.pages_per_seq), i32))
+        table = np.zeros((S, c.pages_per_seq), i32)
+        if not c.prefill_tokens_per_step:
+            return (np.zeros(S, i32), np.zeros(S, i32), np.zeros(S, i32),
+                    np.zeros(S, i32), np.zeros(S, i32), table)
+        return (np.zeros(R, i32), np.full(R, -1, i32), np.zeros(S, i32),
+                np.zeros(R, i32), np.zeros(R, i32), table, np.zeros(R, i32),
+                np.arange(S, dtype=i32))
+
+    def rows_of_slots(self, tokens, positions, phys, off):
+        """A many-token step's per-row arrays for a caller with one row a
+        slot: the chunk's rows dead.  Returns ``(tokens, positions, phys,
+        off, row_slot, out_row)``."""
+        S, C = self.config.max_seqs, self.config.prefill_tokens_per_step
+
+        def rows(x, fill):
+            return np.concatenate([np.asarray(x, np.int32).reshape(S),
+                                   np.full(C, fill, np.int32)])
+
+        slots = np.arange(S, dtype=np.int32)
+        return (rows(tokens, 0), rows(positions, -1), rows(phys, 0),
+                rows(off, 0), rows(slots, 0), slots)
 
     def _warm_args(self):
         """Everything the jitted step takes after the pool, all zeros."""
@@ -740,18 +844,30 @@ class DecodeProgram:
                               self._operands.pack(*operands), prev_tok)
 
     def step(self, kv, tokens, positions, seq_lens, phys, off,
-             page_table, prev_tok=None):
-        """One decode step for every slot; returns ``(next_tokens,
-        logits, kv')``.  ``kv`` is DONATED — the caller must thread the
-        returned pool into the next call.  Where ``tokens[i]`` is
-        negative the slot is fed ``prev_tok[i]``: hand in the last step's
-        ``next_tokens`` as it came back and a decoding slot's token need
-        not pass through the host (:class:`DecodeEngine`).  The jitted
-        step always gets the array (zeros when the caller has none), so
-        there is one trace and one executable either way, and the six
-        host arrays reach it as one (:class:`_StepOperands`)."""
+             page_table, prev_tok=None, row_slot=None, out_row=None):
+        """One decode step for every slot; returns ``(next_tokens (S,),
+        logits (S, V), kv')`` (a family may return more after them).
+        ``kv`` is DONATED — the caller must thread the returned pool into
+        the next call.  Where a row's token is negative the row is fed
+        ``prev_tok`` of its slot: hand in the last step's ``next_tokens``
+        as it came back and a decoding slot's token need not pass through
+        the host (:class:`DecodeEngine`).  The jitted step always gets the
+        array (zeros when the caller has none), so there is one trace and
+        one executable either way, and the host arrays reach it as one
+        (:class:`_StepOperands`).
+
+        A many-token step takes, per row, ``tokens``, ``positions`` (-1: a
+        dead row), ``phys`` / ``off`` and ``row_slot``; per slot
+        ``seq_lens[i]``, the cache positions slot i's own row attends, and
+        ``out_row[i]``, the row that yields its token.  Without
+        ``row_slot`` the arrays are one row a slot (the one-token
+        signature) and the chunk rides dead (:meth:`rows_of_slots`)."""
+        if self.config.prefill_tokens_per_step and row_slot is None:
+            tokens, positions, phys, off, row_slot, out_row = \
+                self.rows_of_slots(tokens, positions, phys, off)
+        rows = () if row_slot is None else (row_slot, out_row)
         return self._call(kv, (tokens, positions, seq_lens, phys, off,
-                               page_table), prev_tok)
+                               page_table) + rows, prev_tok)
 
     def ensure_compiled(self):
         """Compile the step once, visibly: the first build rides a
@@ -806,6 +922,8 @@ class DecodeProgram:
             table[s, :pages_needed] = 1 + s * pages_needed \
                 + np.arange(pages_needed)
         kv = self.fresh_cache()
+        if c.prefill_tokens_per_step:
+            return [self._forward_chunked(kv, toks, table).reshape(S, 1)]
         nxt = None
         for t in range(c.forward_len):
             pos = np.full(S, t, np.int32)
@@ -815,6 +933,40 @@ class DecodeProgram:
                 np.full(S, t % c.page_size, np.int32), table)
             nxt, kv = out[0], out[2]
         return [np.asarray(nxt).reshape(S, 1)]
+
+    def _forward_chunked(self, kv, toks, table):
+        """``forward``'s prompts through a many-token step's chunk rows, a
+        slot's rows starting a block, as many steps as the budget needs;
+        each slot's token from the step that took its last row."""
+        c = self.config
+        S, C, page = c.max_seqs, c.prefill_tokens_per_step, c.page_size
+        block, n = self.chunk_block, c.forward_len
+        fed = np.zeros(S, np.int32)
+        nxt = np.zeros(S, np.int32)
+        while (fed < n).any():
+            tokens, positions, seq_lens, phys, off, _t, row_slot, out_row = \
+                self._zero_step_args()
+            at, ended = 0, []
+            for s in np.flatnonzero(fed < n):
+                if at >= C:
+                    break
+                k = min(n - fed[s], C - at)
+                rows = slice(S + at, S + at + k)
+                pos = fed[s] + np.arange(k)
+                tokens[rows], positions[rows] = toks[s, pos], pos
+                phys[rows], off[rows] = table[s, pos // page], pos % page
+                row_slot[S + at:S + at + -(-k // block) * block] = s
+                at += -(-k // block) * block
+                fed[s] += k
+                seq_lens[s] = fed[s]
+                if fed[s] == n:
+                    out_row[s] = rows.stop - 1
+                    ended.append(s)
+            out = self.step(kv, tokens, positions, seq_lens, phys, off, table,
+                            None, row_slot, out_row)
+            kv = out[2]
+            nxt[ended] = np.asarray(out[0])[ended]
+        return nxt
 
     # -- export / load ------------------------------------------------------
     def export(self, path) -> str:
@@ -930,15 +1082,15 @@ class LatentDecodeProgram(DecodeProgram):
                 "the chunk block (%d rows)"
                 % (config.prefill_tokens_per_step, block))
 
-    @property
-    def rows(self) -> int:
-        """Rows of one step: the slots' own and the chunk's."""
-        return self.config.max_seqs + self.config.prefill_tokens_per_step
+    @staticmethod
+    def derived_budget(config: DecodeConfig, mesh=None) -> int:
+        """0: the latent step's budget is the config's to name (and its
+        check refuses none)."""
+        return 0
 
     @property
     def chunk_block(self) -> int:
-        """Rows of one block of the chunk: a slot's chunk rows start a
-        block, and the live rows of a block are one slot's (the kernel's
+        """Rows of one block of the chunk (the kernel's
         ``mla_chunk_rows()``)."""
         from ..ops.pallas_kernels import mla_chunk_rows
         return mla_chunk_rows()
@@ -984,44 +1136,6 @@ class LatentDecodeProgram(DecodeProgram):
 
         return step
 
-    def _zero_step_args(self):
-        c = self.config
-        S, R = c.max_seqs, self.rows
-        i32 = np.int32
-        return (np.zeros(R, i32), np.full(R, -1, i32), np.zeros(S, i32),
-                np.zeros(R, i32), np.zeros(R, i32),
-                np.zeros((S, c.pages_per_seq), i32), np.zeros(R, i32),
-                np.arange(S, dtype=i32))
-
-    def rows_of_slots(self, tokens, positions, phys, off):
-        """A step's per-row arrays for a caller with one row a slot: the
-        chunk's rows dead.  Returns ``(tokens, positions, phys, off,
-        row_slot, out_row)``."""
-        S, C = self.config.max_seqs, self.config.prefill_tokens_per_step
-
-        def rows(x, fill):
-            return np.concatenate([np.asarray(x, np.int32).reshape(S),
-                                   np.full(C, fill, np.int32)])
-
-        slots = np.arange(S, dtype=np.int32)
-        return (rows(tokens, 0), rows(positions, -1), rows(phys, 0),
-                rows(off, 0), rows(slots, 0), slots)
-
-    def step(self, kv, tokens, positions, seq_lens, phys, off, page_table,
-             prev_tok=None, row_slot=None, out_row=None):
-        """One step over the budget of rows; returns ``(next_tokens (S,),
-        logits (S, V), kv', [held picks, experts touched])``.  ``kv`` is
-        DONATED.  Per row: ``tokens`` (negative: ``prev_tok`` of the row's
-        slot), ``positions`` (-1: a dead row), ``phys`` / ``off``,
-        ``row_slot``; per slot: ``seq_lens[i]``, the cache positions of slot
-        i that the step attends, and ``out_row[i]``, the row that yields its
-        token.  Without ``row_slot`` the arrays are one row a slot (the
-        one-token step's signature) and the chunk rides dead."""
-        if row_slot is None:
-            tokens, positions, phys, off, row_slot, out_row = \
-                self.rows_of_slots(tokens, positions, phys, off)
-        return self._call(kv, (tokens, positions, seq_lens, phys, off,
-                               page_table, row_slot, out_row), prev_tok)
 
 
 _PROGRAMS = {TRANSFORMER_LM: DecodeProgram, SARVAM_MLA: LatentDecodeProgram}
@@ -1130,8 +1244,9 @@ class DecodeEngine(ServingRuntime):
     starve mid-generation; a higher-priority arrival may EVICT the
     cheapest running sequence when the pool is exhausted), then runs ONE
     decode step for all occupied slots — prefill is chunked into the
-    running batch one token per step, so a long prompt never stalls
-    other tenants' token cadence.  Admission, breaker, watchdog-armed
+    running batch (a budget of prompt rows a step beside the slots' own,
+    :meth:`_build_rows`, or one token a slot a step), so a long prompt
+    never stalls other tenants' token cadence.  Admission, breaker, watchdog-armed
     dispatch, and the one-shot Request future (no late OKs, ever) are
     inherited from :class:`ServingRuntime`.
 
@@ -1877,10 +1992,14 @@ def decode_retrace_report(prog: DecodeProgram):
         active = np.zeros(S, i32)
         active[:n_active] = 1
         positions = np.full(S, pos, i32) * active
-        operands = list(prog._zero_step_args())
-        operands[1:6] = (positions, positions + active,
-                         np.ones(S, i32) * active, positions % c.page_size,
-                         np.ones((S, c.pages_per_seq), i32))
+        tokens, phys, off = (np.zeros(S, i32), np.ones(S, i32) * active,
+                             positions % c.page_size)
+        rows = ()
+        if c.prefill_tokens_per_step:
+            tokens, positions, phys, off, *rows = prog.rows_of_slots(
+                tokens, positions, phys, off)
+        operands = (tokens, positions, np.full(S, pos + 1, i32) * active,
+                    phys, off, np.ones((S, c.pages_per_seq), i32), *rows)
         return (prog._param_leaves, prog.fresh_cache(),
                 prog._operands.pack(*operands), prog._no_prev_tok)
 

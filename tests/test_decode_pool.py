@@ -561,3 +561,49 @@ def test_64_slot_step_compiles_for_the_v5e(v5e_chip, monkeypatch, S):
     # it data
     assert ma.alias_size_in_bytes == 4 * np.prod(pool)
     assert planned < 8e9
+
+
+def test_chunked_step_compiles_for_the_v5e(v5e_chip, monkeypatch):
+    """The serve cell's many-token step (32 slots and the 96 prompt rows
+    the rule derives for them on a v5e): the pool is still written and read
+    by Mosaic kernels alone, now three a layer (``kv_write`` over all 128
+    rows, ``decode_attn`` over the slots' own, ``chunk_attn`` over the
+    chunk), each lowered once for the twelve layers, and nothing pool-sized
+    is made but by ``kv_write`` in place."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    monkeypatch.setenv("MXNET_TPU_PALLAS_DECODE", "1")
+    monkeypatch.setattr(pk, "_interpret", lambda *a: False)   # Mosaic
+    S, C = 32, 96
+    cfg = DecodeConfig(50257, 12, 768, 12, 1024, page_size=16, max_seqs=S,
+                       prefill_tokens_per_step=C)
+    weights = {k: np.zeros(shape, np.float32)
+               for k, shape in decode_param_shapes(cfg).items()}
+    prog = DecodeProgram(weights, cfg, name="aot-chunk")
+    on = SingleDeviceSharding(v5e_chip)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=on)
+
+    R = S + C
+    lowered = jax.jit(prog._make_step_fn(count=False),
+                      donate_argnums=(1,)).lower(
+        {k: sds(v.shape, jnp.float32) for k, v in weights.items()},
+        sds(cfg.pool_shape(), jnp.float32), sds((R,)), sds((R,)), sds((S,)),
+        sds((R,)), sds((R,)), sds((S, cfg.pages_per_seq)), sds((S,)),
+        sds((R,)), sds((S,)))
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    pool_type = r"f32\[%s\]" % ",".join(map(str, cfg.pool_shape()))
+    makers = re.findall(r"= %s\S* ([\w\-]+)\(" % pool_type, text)
+    assert set(makers) == {"parameter", "custom-call"}, makers
+    assert makers.count("custom-call") == cfg.num_layers
+    mosaic = re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
+                        r'"tpu_custom_call"', text)
+    for kernel in ("kv_write", "decode_attn", "chunk_attn"):
+        assert sum(kernel in c for c in mosaic) == cfg.num_layers, mosaic
+    assert len(mosaic) == 3 * cfg.num_layers, mosaic
+    assert ma.temp_size_in_bytes < 0.1e9
+    assert ma.alias_size_in_bytes == 4 * np.prod(cfg.pool_shape())
