@@ -12,6 +12,7 @@ from . import inception_v4
 from . import transformer
 from . import afmoe
 from . import sarvam_mla      # a serving step's block, not a symbol builder
+from . import lfm2_moe        # likewise
 
 get_resnet = resnet.get_symbol
 get_lenet = lenet.get_symbol

@@ -765,10 +765,24 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
 # caller masks them.
 
 _GMM_TILING = (512, 1024, 1024)     # rows, contracted, columns
+# rows under which a tile's visit is bound by reading its weight tile and
+# not by the MXU (the v5e multiplies ~240 rows by a bf16 tile in the time
+# it reads it): a smaller row tile only re-reads the weights of every
+# group that spans two tiles
+_GMM_MIN_ROWS = 256
 
 
-def _gmm_tiling(m):
+def _gmm_tiling(m, groups):
+    """The tiling of ``m`` sorted rows over ``groups`` groups.  A row tile is
+    the MXU's whole work for every group that has a row in it, so a tile of
+    eight times the groups' mean rows or more is halved (down to
+    ``_GMM_MIN_ROWS``): 2,048 rows over 32 experts in 512-row tiles made
+    the MXU compute eight times the products it kept, more time than
+    reading the experts' weights."""
     tm, tk, tn = _GMM_TILING
+    mean = -(-m // groups)
+    while tm > _GMM_MIN_ROWS and tm >= 8 * mean:
+        tm //= 2
     while m % tm:
         tm //= 2
     return tm, tk, tn
@@ -796,7 +810,8 @@ def _mxu_operands(dtype):
 def _gmm_tpu(x, w, sizes, out_dtype):
     megablox = _megablox()
     with _mxu_operands(x.dtype), jax.enable_x64(False):
-        return megablox.gmm(x, w, sizes, out_dtype, _gmm_tiling(x.shape[0]))
+        return megablox.gmm(x, w, sizes, out_dtype,
+                            _gmm_tiling(x.shape[0], w.shape[0]))
 
 
 def _gmm_tpu_fwd(x, w, sizes, out_dtype):
@@ -806,7 +821,7 @@ def _gmm_tpu_fwd(x, w, sizes, out_dtype):
 def _gmm_tpu_bwd(out_dtype, saved, dy):
     megablox = _megablox()
     x, w, sizes = saved
-    tiling = _gmm_tiling(x.shape[0])
+    tiling = _gmm_tiling(x.shape[0], w.shape[0])
     dy = dy.astype(x.dtype)
     with _mxu_operands(x.dtype), jax.enable_x64(False):
         dx = megablox.gmm(dy, w, sizes, x.dtype, tiling, transpose_rhs=True)
@@ -1160,14 +1175,18 @@ def _decode_attn_xla(q, k_pages, v_pages, page_table, seq_lens, scale):
     """XLA formulation: gather the slots' pages, mask, one softmax.  It
     materializes (S, max_pages·page, H·D) per call — fine on CPU and the
     form GSPMD can shard over a tp axis (pallas_call is a partitioning
-    black box; the tp serving export always uses this path)."""
+    black box; the tp serving export always uses this path).  A pool of
+    fewer heads than ``q`` (H = rep x H_kv) serves query head h from its
+    head h // rep."""
     S, H, D = q.shape
-    page = k_pages.shape[2]
+    page, h_kv = k_pages.shape[2], k_pages.shape[1]
     n_pages = page_table.shape[1]
     T = n_pages * page
-    # (S, n_pages, H, page, D) -> (S, H, T, D)
-    k = k_pages[page_table].transpose(0, 2, 1, 3, 4).reshape(S, H, T, D)
-    v = v_pages[page_table].transpose(0, 2, 1, 3, 4).reshape(S, H, T, D)
+    # (S, n_pages, H_kv, page, D) -> (S, H_kv, T, D)
+    k = k_pages[page_table].transpose(0, 2, 1, 3, 4).reshape(S, h_kv, T, D)
+    v = v_pages[page_table].transpose(0, 2, 1, 3, 4).reshape(S, h_kv, T, D)
+    if h_kv != H:
+        k, v = (jnp.repeat(x, H // h_kv, axis=1) for x in (k, v))
     s_sht = jnp.einsum("shd,shtd->sht", q.astype(jnp.float32),
                        k.astype(jnp.float32)) * scale
     pos = jnp.arange(T, dtype=jnp.int32)[None, None, :]
@@ -1234,13 +1253,31 @@ def decode_attention_pool(q: jax.Array, kv: jax.Array, layer,
                           page_table: jax.Array, seq_lens: jax.Array,
                           scale=None) -> jax.Array:
     """:func:`decode_attention` for one layer of the whole serving pool
-    ``kv`` (L, 2, P, H, rows, lanes), which the kernel takes as it is: K
-    at ``kv[layer, 0]``, V at ``kv[layer, 1]``, no slice made.  Pallas
-    only (the XLA formulation takes the 4-D slices)."""
+    ``kv`` (L, 2, P, H_kv, rows, lanes), which the kernel takes as it is:
+    K at ``kv[layer, 0]``, V at ``kv[layer, 1]``, no slice made.  Pallas
+    only (the XLA formulation takes the 4-D slices).
+
+    ``q`` (S, H, D) has one query group a key/value head (H = rep x H_kv,
+    head h reading head h // rep).  At rep 1 a slot's row is scored on the
+    vector unit (the kernel above).  At rep > 1 the same name runs the
+    ``chunk_attn`` body with one item a slot: the group's query heads are
+    rows of ONE matrix product against the pages, the item two rows high
+    (the slot's and a dead one), so that a bfloat16 query fills the
+    16-row tile it packs into (PERF.md section 6)."""
     if scale is None:
         scale = _default_scale(q.shape[-1])
-    return _decode_attn_pallas(q, kv, kv, layer, 1, page_table, seq_lens,
-                               float(scale), _interpret(q, kv))
+    S, H, D = q.shape
+    if H == kv.shape[3]:
+        return _decode_attn_pallas(q, kv, kv, layer, 1, page_table,
+                                   seq_lens, float(scale), _interpret(q, kv))
+    rows = jnp.stack([q, jnp.zeros_like(q)], axis=1).reshape(2 * S, H, D)
+    limit = jnp.stack([seq_lens, jnp.zeros_like(seq_lens)], axis=1)
+    out = _chunk_attn_call(
+        rows, kv, layer, page_table,
+        jnp.repeat(jnp.arange(S, dtype=jnp.int32), 2), limit.reshape(-1),
+        TQ=2, scale=float(scale), interpret=_interpret(q, kv),
+        cell_tokens=_GQA_CELL_TOKENS, name="decode_attn")
+    return out.reshape(S, 2, H, D)[:, 0]
 
 
 def _kv_write_kernel(phys_ref, off_ref, layer_ref, new_ref, kv_ref, out_ref,
@@ -1270,15 +1307,26 @@ def _kv_write_kernel(phys_ref, off_ref, layer_ref, new_ref, kv_ref, out_ref,
 
 
 def kv_write(kv: jax.Array, layer, k: jax.Array, v: jax.Array,
-             phys: jax.Array, off: jax.Array) -> jax.Array:
+             phys: jax.Array, off: jax.Array, use_pallas=True) -> jax.Array:
     """The pool ``kv`` (L, 2, P, H, rows, lanes) with token ``off[r]`` of
     page ``phys[r]`` of ``layer`` set to ``k[r]`` (at ``[layer, 0]``) and
     ``v[r]`` (``[layer, 1]``) for every row ``r`` — written where the
     pool lies (``input_output_aliases``; donate ``kv`` and nothing
     pool-sized is copied).  ``k`` / ``v``: (R, H, D), a row a slot's token
-    or a chunk's.  Rows bound for one live page must follow one another;
-    rows that share a page otherwise (the dead ones, all on the trash page)
-    may overwrite one another there in any order."""
+    or a chunk's; H is the pool's (key/value) heads, D its head width, the
+    values cast to the pool's dtype.  Rows bound for one live page must
+    follow one another; rows that share a page otherwise (the dead ones,
+    all on the trash page) may overwrite one another there in any order.
+    ``use_pallas=False``: the XLA scatter over the pool seen by token."""
+    if not use_pallas:
+        L, _, P, H, rows, lanes = kv.shape
+        D = k.shape[-1]
+        by_token = kv.reshape(L, 2, P, H, rows * lanes // D, D)
+        by_token = by_token.at[layer, 0, phys, :, off, :].set(
+            k.astype(kv.dtype))
+        by_token = by_token.at[layer, 1, phys, :, off, :].set(
+            v.astype(kv.dtype))
+        return by_token.reshape(kv.shape)
     return _kv_write_call(kv, layer, k, v, phys, off,
                           interpret=_interpret(kv, k, v))
 
@@ -1350,8 +1398,19 @@ def _kv_write_call(kv, layer, k, v, phys, off, *, interpret):
 # compiled for the v5e, against 3,331 with a loop over the heads; and the
 # kernel's body is small, which the step's set-up pays for in tracing and
 # lowering it (PERF.md, PR 39).
+#
+# A pool of H_kv heads serving H = rep x H_kv query heads (grouped-query
+# attention) is the same body: the rep query heads of a group are more rows
+# of the item's matrix (a row and a head of its group to a matrix row), so
+# one product a group scores them all against the pages they share, and the
+# pool's dtype (bfloat16) is the operands'.  ``decode_attn`` of such a pool
+# is this body with one item a slot (:func:`decode_attention_pool`).
 
 _CHUNK_ROWS = 16        # query rows of a chunk_attn item
+# tokens a group of pages holds at most where the pool serves query groups:
+# a group's query heads are rows of the item's products, so its pages are
+# read once for them all and a larger group amortises the walk
+_GQA_CELL_TOKENS = 512
 
 
 def chunk_attn_rows() -> int:
@@ -1369,7 +1428,9 @@ def _chunk_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, q_ref, lim_ref,
     scaled, one copy a parity; ``lim_ref`` (pack * TQ, 1) each copy's limit
     less its parity (a token at ``pack * n + base`` counts where that is
     below it); ``k_buf`` / ``v_buf`` (2, H, G, rows, lanes) the two landing
-    buffers, a head's pages of a group side by side."""
+    buffers, a head's pages of a group side by side.  With a query group a
+    key/value head, H counts the pool's heads and a "query row" here is
+    one (row, query head of the group) pair: the caller folds them."""
     i = pl.program_id(0)
     N = pl.num_programs(0)
     _, H, _, rows, lanes = k_buf.shape
@@ -1379,6 +1440,9 @@ def _chunk_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, q_ref, lim_ref,
     M = q_ref.shape[1]
     TQ = M // pack
     layer = layer_ref[0]
+    # bfloat16 operands go to the MXU as they are (_mxu_dot has why)
+    precision = None if k_buf.dtype == jnp.float32 \
+        else jax.lax.Precision.DEFAULT
 
     def live_pages(item):
         # none for a dead item: it copies nothing
@@ -1448,7 +1512,7 @@ def _chunk_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, q_ref, lim_ref,
         # every head's weighted sum
         s = jax.lax.dot_general(
             q_ref[...], k_buf[b].reshape(H, T, lanes),
-            (((2,), (2,)), ((0,), (0,))),
+            (((2,), (2,)), ((0,), (0,))), precision=precision,
             preferred_element_type=jnp.float32)                 # (H, M, T)
         s = jnp.where(valid[None], s, jnp.float32(_NEG_BIG))
         # one running max and sum a query row, over its copies: each
@@ -1465,8 +1529,8 @@ def _chunk_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, q_ref, lim_ref,
         for part in parts:
             l_new = l_new + sums[:, part]
         pv = jax.lax.dot_general(
-            p, v_buf[b].reshape(H, T, lanes),
-            (((2,), (1,)), ((0,), (0,))),
+            p.astype(v_buf.dtype), v_buf[b].reshape(H, T, lanes),
+            (((2,), (1,)), ((0,), (0,))), precision=precision,
             preferred_element_type=jnp.float32)                 # (H, M, lanes)
         mine = pv[:, parts[0]]
         for k, part in enumerate(parts[1:], 1):
@@ -1484,45 +1548,54 @@ def _chunk_attn_kernel(slot_ref, ctx_ref, layer_ref, pt_ref, q_ref, lim_ref,
     buf_ref[0] = (b0 + n_groups) & 1
 
 
-@functools.partial(jax.jit, static_argnames=("TQ", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("TQ", "scale", "interpret",
+                                             "cell_tokens", "name"))
 def _chunk_attn_call(q, kv, layer, page_table, row_slot, row_limit, *, TQ,
-                     scale, interpret):
+                     scale, interpret, cell_tokens=None, name="chunk_attn"):
     """The kernel's call, under ``jax.jit`` with the layer an operand, so
     that the layers of a step share one traced and lowered body (as
-    ``_decode_attn_call``)."""
+    ``_decode_attn_call``).  ``q`` (C, H, D) over a pool of H_kv heads: the
+    ``rep = H / H_kv`` query heads of a group become rows of the item's
+    matrix, ``(row, head of the group)`` in that order, queries in the
+    pool's dtype.  ``cell_tokens`` / ``name``: the group size's cap
+    (:func:`_decode_pages_per_cell`) and the kernel's name."""
     C, H, D = q.shape
-    rows, lanes = kv.shape[4:]
+    h_kv, rows, lanes = kv.shape[3:]
+    rep = H // h_kv
     pack = lanes // D
-    NB, M = C // TQ, pack * TQ
-    G = _decode_pages_per_cell(H, rows, lanes, D, kv.dtype.itemsize,
-                               page_table.shape[1])
+    NB, M = C // TQ, pack * TQ * rep
+    G = _decode_pages_per_cell(h_kv, rows, lanes, D, kv.dtype.itemsize,
+                               page_table.shape[1], cell_tokens=cell_tokens)
     limit = row_limit.astype(jnp.int32).reshape(NB, TQ)
     item_slot = row_slot.astype(jnp.int32).reshape(NB, TQ)[:, 0]
     item_ctx = jnp.max(limit, axis=1)
-    # (NB, H, pack * TQ, lanes): copy p of a row's query on the lanes of a
-    # page row's token p, zero on the others
-    qs = (q.astype(jnp.float32) * scale).reshape(NB, TQ, H, D) \
-        .transpose(0, 2, 1, 3)
+    # (NB, H_kv, pack * TQ * rep, lanes): copy p of a row's query on the
+    # lanes of a page row's token p, zero on the others
+    qs = (q.astype(jnp.float32) * scale).reshape(NB, TQ, h_kv, rep * D) \
+        .transpose(0, 2, 1, 3).reshape(NB, h_kv, TQ * rep, D)
     qp = jnp.concatenate(
         [jnp.pad(qs, ((0, 0), (0, 0), (0, 0), (p * D, lanes - (p + 1) * D)))
-         for p in range(pack)], axis=2)
+         for p in range(pack)], axis=2).astype(kv.dtype)
+    if rep > 1:
+        limit = jnp.repeat(limit, rep, axis=1)
     lim = jnp.concatenate([limit - p for p in range(pack)], axis=1)[..., None]
     kern = functools.partial(_chunk_attn_kernel, G=G, D=D)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(NB,),
-        in_specs=[pl.BlockSpec((None, H, M, lanes), lambda i, *_: (i, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((None, h_kv, M, lanes),
+                               lambda i, *_: (i, 0, 0, 0)),
                   pl.BlockSpec((None, M, 1), lambda i, *_: (i, 0, 0)),
                   in_hbm, in_hbm],
-        out_specs=pl.BlockSpec((None, H, TQ, lanes),
+        out_specs=pl.BlockSpec((None, h_kv, TQ * rep, lanes),
                                lambda i, *_: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, H, G, rows, lanes), kv.dtype),
-            pltpu.VMEM((2, H, G, rows, lanes), kv.dtype),
-            pltpu.VMEM((H, TQ, 1), jnp.float32),    # running max
-            pltpu.VMEM((H, TQ, 1), jnp.float32),    # running sum
-            pltpu.VMEM((H, TQ, lanes), jnp.float32),  # accumulator
+            pltpu.VMEM((2, h_kv, G, rows, lanes), kv.dtype),
+            pltpu.VMEM((2, h_kv, G, rows, lanes), kv.dtype),
+            pltpu.VMEM((h_kv, TQ * rep, 1), jnp.float32),   # running max
+            pltpu.VMEM((h_kv, TQ * rep, 1), jnp.float32),   # running sum
+            pltpu.VMEM((h_kv, TQ * rep, lanes), jnp.float32),  # accumulator
             pltpu.SemaphoreType.DMA((2, 2)),        # buffer, K|V
             pltpu.SMEM((1,), jnp.int32),    # the buffer the item starts in
         ],
@@ -1530,14 +1603,16 @@ def _chunk_attn_call(q, kv, layer, page_table, row_slot, row_limit, *, TQ,
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kern, grid_spec=grid_spec,
-            out_shape=_out_struct((NB, H, TQ, lanes), jnp.float32, qp, kv),
+            out_shape=_out_struct((NB, h_kv, TQ * rep, lanes), jnp.float32,
+                                  qp, kv),
             # an item's first copies are started by the item before it
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
-            interpret=interpret, name="chunk_attn",
+            interpret=interpret, name=name,
         )(item_slot, item_ctx, _layer_operand(layer),
           page_table.astype(jnp.int32), qp, lim, kv, kv)
-    out = out.reshape(NB, H, TQ, pack, D).sum(axis=3)
+    out = out.reshape(NB, h_kv, TQ * rep, pack, D).sum(axis=3) \
+        .reshape(NB, h_kv, TQ, rep * D)
     return out.transpose(0, 2, 1, 3).reshape(C, H, D).astype(q.dtype)
 
 
@@ -1546,8 +1621,9 @@ def chunk_attention(q: jax.Array, kv: jax.Array, layer,
                     row_limit: jax.Array, scale=None,
                     use_pallas=None) -> jax.Array:
     """Attention of a step's chunk rows against their slots' pages in layer
-    ``layer`` of the whole serving pool ``kv`` (L, 2, P, H, rows, lanes),
-    taken as it lies.  ``q``: (C, H, D), C whole blocks of
+    ``layer`` of the whole serving pool ``kv`` (L, 2, P, H_kv, rows, lanes),
+    taken as it lies.  ``q``: (C, H, D), H = rep x H_kv query heads, head h
+    reading the pool's head h // rep; C whole blocks of
     :func:`chunk_attn_rows` whose live rows are one slot's, the slot that
     ``row_slot`` names for the block's first row; ``row_limit`` (C,): the
     positions a row attends (those below it; 0: a dead row, whose output is
@@ -1557,12 +1633,12 @@ def chunk_attention(q: jax.Array, kv: jax.Array, layer,
     C, H, D = q.shape
     if scale is None:
         scale = _default_scale(D)
-    L, _, P, _, rows, lanes = kv.shape
+    L, _, P, h_kv, rows, lanes = kv.shape
     page = rows * (lanes // D)
     if use_pallas is None:
         use_pallas = decode_backend_is_pallas(C, H, D, page, q.dtype)
     if not use_pallas:
-        by_token = kv.reshape(L, 2, P, H, page, D)[layer]
+        by_token = kv.reshape(L, 2, P, h_kv, page, D)[layer]
         return _decode_attn_xla(q, by_token[0], by_token[1],
                                 page_table[row_slot], row_limit, float(scale))
     if C % _CHUNK_ROWS:
@@ -1570,7 +1646,9 @@ def chunk_attention(q: jax.Array, kv: jax.Array, layer,
                          % (C, _CHUNK_ROWS))
     return _chunk_attn_call(q, kv, layer, page_table, row_slot, row_limit,
                             TQ=_CHUNK_ROWS, scale=float(scale),
-                            interpret=_interpret(q, kv))
+                            interpret=_interpret(q, kv),
+                            cell_tokens=None if H == h_kv
+                            else _GQA_CELL_TOKENS)
 
 
 # ---------------------------------------------------------------------------

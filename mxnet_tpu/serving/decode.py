@@ -48,7 +48,13 @@ split (PAPERS.md): a decode loop whose per-token step
   ``models/sarvam_mla.py`` supplies: parameters and pool in
   ``DecodeConfig.dtype``, a pool of latent rows ``(L, P, page, W)``
   touched by ``latent_write`` and ``mla_attn``, routed experts in the
-  step, and a budget the config names;
+  step, and a budget the config names; a model of gated short
+  convolutions beside grouped-query attention is
+  :class:`HybridDecodeProgram`, whose block ``models/lfm2_moe.py``
+  supplies: a step state that is the K/V pool of its attention layers
+  alone (``kv_heads`` heads, a query group each, through ``kv_write``,
+  ``decode_attn`` and ``chunk_attn``) beside a per-slot convolution
+  state, donated together;
 * optionally serves **weight-only quantized** matmuls (int8 / packed
   int4, per-channel scales, dequantization fused in the kernel —
   :func:`~mxnet_tpu.ops.pallas_kernels.quant_matmul`), selected at
@@ -95,7 +101,8 @@ from .request import Request
 from .runtime import ServingRuntime, _env_int
 
 __all__ = ["DecodeConfig", "PagePool", "DecodeProgram", "LatentDecodeProgram",
-           "DecodeRequest", "DecodeEngine", "decode_param_shapes",
+           "HybridDecodeProgram", "DecodeRequest", "DecodeEngine",
+           "decode_param_shapes",
            "init_decode_params", "decode_tp_model_bytes", "program_class"]
 
 _MAGIC = "mxnet_tpu-decode-v1"
@@ -106,9 +113,13 @@ _STALL_FLOOR_S = 0.020
 # the model families a decode program exists for (``DecodeConfig.family``):
 # models/transformer.py's GPT-2 block, one token a slot a step, a K/V pool by
 # head; models/sarvam_mla.py's latent-attention block with routed experts, a
-# many-token step over a pool of latent rows
+# many-token step over a pool of latent rows; models/lfm2_moe.py's short
+# convolutions beside grouped-query attention with routed experts, a
+# many-token step over a K/V pool of the attention layers and a per-slot
+# convolution state
 TRANSFORMER_LM = "transformer_lm"
 SARVAM_MLA = "sarvam_mla"
+LFM2_MOE = "lfm2_moe"
 
 # rows of one tile of the v5e MXU: a derived budget fills whole tiles
 _MXU_ROWS = 128
@@ -127,13 +138,13 @@ class DecodeConfig:
     __slots__ = ("vocab_size", "num_layers", "hidden", "heads",
                  "max_seq_len", "page_size", "max_seqs", "quantize",
                  "eos_id", "forward_len", "family", "dtype",
-                 "prefill_tokens_per_step", "model")
+                 "prefill_tokens_per_step", "model", "kv_heads")
 
     def __init__(self, vocab_size, num_layers, hidden, heads,
                  max_seq_len, page_size=None, max_seqs=None,
                  quantize=None, eos_id=None, forward_len=None,
                  family=None, dtype=None, prefill_tokens_per_step=None,
-                 model=None):
+                 model=None, kv_heads=None):
         # which model family's step this is (the program class is looked up
         # by it), the dtype of its parameters and pool, the prompt rows a
         # step takes beside the slots' own (0: one token a slot a step; None:
@@ -153,6 +164,11 @@ class DecodeConfig:
         if self.hidden % self.heads:
             raise MXNetError("hidden %d not divisible by heads %d"
                              % (self.hidden, self.heads))
+        # the K/V pool's heads: one query group of heads / kv_heads each
+        self.kv_heads = int(kv_heads or heads)
+        if self.heads % self.kv_heads:
+            raise MXNetError("heads %d not divisible by kv_heads %d"
+                             % (self.heads, self.kv_heads))
         self.max_seq_len = int(max_seq_len)
         self.page_size = int(page_size if page_size is not None
                              else _env_int("MXNET_TPU_DECODE_PAGE", 64))
@@ -200,9 +216,11 @@ class DecodeConfig:
                    for k in self.__slots__ if k != "quantize")
 
     def describe(self) -> str:
-        return ("%s %s L%d H%d heads%d V%d T%d page%d S%d%s%s"
+        return ("%s %s L%d H%d heads%d%s V%d T%d page%d S%d%s%s"
                 % (self.family, self.dtype, self.num_layers, self.hidden,
-                   self.heads, self.vocab_size, self.max_seq_len,
+                   self.heads, "/%d" % self.kv_heads
+                   if self.kv_heads != self.heads else "",
+                   self.vocab_size, self.max_seq_len,
                    self.page_size, self.max_seqs,
                    "+%d" % self.prefill_tokens_per_step
                    if self.prefill_tokens_per_step else "",
@@ -376,8 +394,9 @@ class DecodeProgram:
     ``quantize`` (or ``config.quantize``): int8/int4 weight-only
     quantized matmuls, fixed at construction = "selected at export".
 
-    The page pool (:meth:`DecodeConfig.pool_shape`, float32) is the
-    step's one piece of state: made by :meth:`fresh_cache`, donated to
+    The page pool (:meth:`DecodeConfig.pool_shape`, float32) is this
+    family's state (another family's may hold more beside its pool,
+    :class:`HybridDecodeProgram`): made by :meth:`fresh_cache`, donated to
     every step and handed back as the same buffer.  On one device with
     the Pallas backend the step writes and reads it through ``kv_write``,
     ``decode_attn`` and ``chunk_attn`` alone, where it lies; the XLA
@@ -617,9 +636,20 @@ class DecodeProgram:
 
     @property
     def cache_bytes(self) -> int:
+        """Bytes of the step's whole state (:meth:`fresh_cache`)."""
         import jax.numpy as jnp
         return int(np.prod(self.config.pool_shape())) \
-            * jnp.dtype(self.config.dtype).itemsize
+            * jnp.dtype(self.config.dtype).itemsize + self.state_bytes
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the state kept beside the page pool: none here."""
+        return 0
+
+    # the positions before its own that a row's state reads (0: none; the
+    # step span's ``state_rows`` counts the rows that read them from a slot's
+    # carried state)
+    carried_taps = 0
 
     # -- the step program --------------------------------------------------
     def _make_step_fn(self, count=True):
@@ -665,10 +695,7 @@ class DecodeProgram:
         by_token = (c.num_layers, 2, c.pool_pages(), H, c.page_size, Dh)
 
         def _write_xla(kv, i, k, v, phys, off):
-            kv = kv.reshape(by_token)
-            kv = kv.at[i, 0, phys, :, off, :].set(k.astype(kv.dtype))
-            kv = kv.at[i, 1, phys, :, off, :].set(v.astype(kv.dtype))
-            return kv.reshape(c.pool_shape())
+            return pk.kv_write(kv, i, k, v, phys, off, use_pallas=False)
 
         def _attend_xla(q, kv, i, page_table, limit, row_slot):
             kv = kv.reshape(by_token)
@@ -1053,6 +1080,13 @@ class LatentDecodeProgram(DecodeProgram):
     FAMILY = SARVAM_MLA
 
     @staticmethod
+    def _block():
+        """The module that supplies the family's block: ``param_shapes``,
+        ``model_of``, ``is_float32_param`` and ``Decoder``."""
+        from ..models import sarvam_mla
+        return sarvam_mla
+
+    @staticmethod
     def pool_shape_of(config: DecodeConfig) -> tuple:
         """``(L, P, page, W)``: one row a token a layer (the normed latent
         and the rope key, no head axis, no K/V pair), W its width in whole
@@ -1062,19 +1096,18 @@ class LatentDecodeProgram(DecodeProgram):
                 latent_row_lanes(config.model["kv_lora_rank"]
                                  + config.model["qk_rope_head_dim"]))
 
-    @staticmethod
-    def param_shapes_of(config: DecodeConfig) -> Dict[str, tuple]:
-        from ..models import sarvam_mla
-        return sarvam_mla.param_shapes(sarvam_mla.model_of(config.model),
-                                       config.num_layers, config.vocab_size)
+    @classmethod
+    def param_shapes_of(cls, config: DecodeConfig) -> Dict[str, tuple]:
+        block = cls._block()
+        return block.param_shapes(block.model_of(config.model),
+                                  config.num_layers, config.vocab_size)
 
-    @staticmethod
-    def _check_config(config, mesh):
-        from ..ops.pallas_kernels import mla_chunk_rows
-        block = mla_chunk_rows()
+    @classmethod
+    def _check_config(cls, config, mesh):
+        block = cls._chunk_rows()
         if mesh is not None or config.quantize:
             raise MXNetError("the %s step runs on one device, unquantized"
-                             % SARVAM_MLA)
+                             % cls.FAMILY)
         if config.prefill_tokens_per_step < block \
                 or config.prefill_tokens_per_step % block:
             raise MXNetError(
@@ -1088,19 +1121,24 @@ class LatentDecodeProgram(DecodeProgram):
         check refuses none)."""
         return 0
 
-    @property
-    def chunk_block(self) -> int:
-        """Rows of one block of the chunk (the kernel's
-        ``mla_chunk_rows()``)."""
+    @staticmethod
+    def _chunk_rows() -> int:
         from ..ops.pallas_kernels import mla_chunk_rows
         return mla_chunk_rows()
+
+    @property
+    def chunk_block(self) -> int:
+        """Rows of one block of the chunk (the attention kernel's: here
+        ``mla_chunk_rows()``)."""
+        return self._chunk_rows()
 
     def _check_params(self, host):
         want = decode_param_shapes(self.config)
         missing = sorted(set(want) - set(host))
         if missing:
             raise MXNetError("decode params missing %s (names of "
-                             "models/sarvam_mla.param_shapes)" % missing[:6])
+                             "models/%s.param_shapes)"
+                             % (missing[:6], self.FAMILY))
         wrong = [(k, tuple(host[k].shape), want[k]) for k in want
                  if tuple(host[k].shape) != tuple(want[k])]
         if wrong:
@@ -1111,15 +1149,13 @@ class LatentDecodeProgram(DecodeProgram):
     def _place_param(self, key, value):
         import jax
         import jax.numpy as jnp
-        from ..models import sarvam_mla
-        dtype = jnp.float32 if sarvam_mla.is_float32_param(key) \
+        dtype = jnp.float32 if self._block().is_float32_param(key) \
             else jnp.dtype(self.config.dtype)
         return jax.device_put(np.asarray(value).astype(dtype, copy=False))
 
     def _make_step_fn(self, count=True):
-        from ..models import sarvam_mla
         c = self.config
-        decoder = sarvam_mla.Decoder(
+        decoder = self._block().Decoder(
             c.model, num_layers=c.num_layers, vocab_size=c.vocab_size,
             slots=c.max_seqs, chunk_rows=c.prefill_tokens_per_step,
             dtype=c.dtype)
@@ -1137,8 +1173,86 @@ class LatentDecodeProgram(DecodeProgram):
         return step
 
 
+class HybridDecodeProgram(LatentDecodeProgram):
+    """The decode program of a family with two kinds of layer, gated short
+    convolutions and grouped-query attention (``models/lfm2_moe.py``
+    supplies the block), under :class:`LatentDecodeProgram`'s contract: one
+    device, parameters in ``config.dtype``, the many-token step with its
+    chunk in blocks of ``chunk_attn_rows()``, expert counts as the step's
+    fourth result.  Its state is a dict donated whole to every step:
 
-_PROGRAMS = {TRANSFORMER_LM: DecodeProgram, SARVAM_MLA: LatentDecodeProgram}
+    * ``kv``: the K/V pool of the ATTENTION layers only, ``(n_attn, 2, P,
+      kv_heads, rows, lanes)`` in ``config.dtype``, touched by
+      ``kv_write``, ``decode_attn`` and ``chunk_attn`` with a query group
+      of ``heads / kv_heads`` a key/value head;
+    * ``conv``: every slot's last ``conv_L_cache`` convolution inputs a
+      convolution layer, ``(n_conv, S, L, hidden)``, which the step reads
+      and writes by position alone (a new request's rows read zeros where
+      its positions are negative), so the engine does nothing for it."""
+
+    FAMILY = LFM2_MOE
+
+    @staticmethod
+    def _block():
+        from ..models import lfm2_moe
+        return lfm2_moe
+
+    @staticmethod
+    def _chunk_rows() -> int:
+        from ..ops.pallas_kernels import chunk_attn_rows
+        return chunk_attn_rows()
+
+    @staticmethod
+    def pool_shape_of(config: DecodeConfig) -> tuple:
+        """``(n_attn, 2, P, kv_heads, rows, lanes)``: the lane-dense pages of
+        :meth:`DecodeProgram.pool_shape_of`, for the attention layers."""
+        from ..models import lfm2_moe
+        from ..ops.pallas_kernels import kv_pack
+        hd = config.hidden // config.heads
+        pack = kv_pack(config.page_size, hd)
+        return (lfm2_moe.attention_layers(config.model, config.num_layers),
+                2, config.pool_pages(), config.kv_heads,
+                config.page_size // pack, pack * hd)
+
+    @classmethod
+    def _check_config(cls, config, mesh):
+        super()._check_config(config, mesh)
+        want = config.model["num_key_value_heads"]
+        if config.kv_heads != want:
+            raise MXNetError("%s has %d key/value heads; the config says %d"
+                             % (config.describe(), want, config.kv_heads))
+
+    def _state_shape(self) -> tuple:
+        c = self.config
+        return self._block().state_shape(c.model, c.num_layers, c.max_seqs)
+
+    @property
+    def carried_taps(self) -> int:
+        return self.config.model["conv_L_cache"] - 1
+
+    def fresh_cache(self):
+        """The zeroed state: ``{"kv": pool, "conv": convolution state}``."""
+        import jax
+        import jax.numpy as jnp
+        dtype = jnp.dtype(self.config.dtype)
+        kv = jax.device_put(jnp.zeros(self.config.pool_shape(), dtype))
+        conv = jax.device_put(jnp.zeros(self._state_shape(), dtype))
+        telemetry.memory.tag(kv, "kv_cache",
+                             label="HybridDecodeProgram(%s).kv" % self.name)
+        telemetry.memory.tag(conv, "kv_cache",
+                             label="HybridDecodeProgram(%s).conv" % self.name)
+        return {"kv": kv, "conv": conv}
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the convolution state alone."""
+        import jax.numpy as jnp
+        return int(np.prod(self._state_shape())) \
+            * jnp.dtype(self.config.dtype).itemsize
+
+
+_PROGRAMS = {TRANSFORMER_LM: DecodeProgram, SARVAM_MLA: LatentDecodeProgram,
+             LFM2_MOE: HybridDecodeProgram}
 
 
 def program_class(family: str):
@@ -1647,7 +1761,10 @@ class DecodeEngine(ServingRuntime):
         positions attended summed over the rows (a chunk row attends up to
         its own position), of them ``chunk_pairs`` the chunk rows', and
         ``chunk_attended``, the contexts the chunk's slots hold after it
-        (what an expanded-form prefill would up-project).  Row i < S is slot i's own
+        (what an expanded-form prefill would up-project), and where the
+        program carries state a slot (``carried_taps``), ``state_rows``:
+        the rows that read positions before their own from that state and
+        not from a row of the same step.  Row i < S is slot i's own
         (a decoding slot's token, -1: ``prev_tok[i]``); the chunk's rows go
         to the slots that still hold prompt, oldest admission first, each
         slot's rows starting a block of the program's ``chunk_block``; what
@@ -1666,7 +1783,8 @@ class DecodeEngine(ServingRuntime):
         seq_lens = np.zeros(S, np.int32)
         out_row = np.arange(S, dtype=np.int32)
         takers = []
-        chunk_pairs = chunk_attended = 0
+        chunk_pairs = chunk_attended = state_rows = 0
+        taps = getattr(self._program, "carried_taps", 0)
         in_prompt = []
         for i in active:
             slot = self._slots[i]
@@ -1676,6 +1794,7 @@ class DecodeEngine(ServingRuntime):
                 continue
             tokens[i] = -1
             positions[i] = slot.pos
+            state_rows += slot.pos > 0
             seq_lens[i] = slot.pos + 1
             phys[i] = slot.pages[slot.pos // page]
             off[i] = slot.pos % page
@@ -1700,6 +1819,8 @@ class DecodeEngine(ServingRuntime):
             off[rows] = pos % page
             whole = -(-n // block) * block      # its blocks, the last padded
             row_slot[first:first + whole] = i
+            if slot.pos > 0:
+                state_rows += min(n, taps)
             at += whole
             slot.pos += n
             seq_lens[i] = slot.pos
@@ -1711,10 +1832,13 @@ class DecodeEngine(ServingRuntime):
             last = takes and (req.max_new <= 1
                               or slot.pos >= c.max_seq_len)
             takers.append((i, req, takes, last, n))
+        counts = {"attn_pairs": decode_pairs + chunk_pairs,
+                  "chunk_pairs": chunk_pairs,
+                  "chunk_attended": chunk_attended}
+        if taps:
+            counts["state_rows"] = state_rows
         return ((tokens, positions, seq_lens, phys, off, row_slot, out_row),
-                takers, {"attn_pairs": decode_pairs + chunk_pairs,
-                         "chunk_pairs": chunk_pairs,
-                         "chunk_attended": chunk_attended})
+                takers, counts)
 
     def _drain(self):
         """Take in the step in flight with none dispatched behind it."""
@@ -1954,8 +2078,11 @@ class DecodeEngine(ServingRuntime):
             # (the packed operands: 1), over the steps taken in
             "host_operands_per_step": round(
                 counters.get("host_operands", 0) / steps, 3),
-            # the page pool as the program laid it out, in its dtype
+            # the step's state as the program laid it out, in its dtype:
+            # the page pool and, of it, what lies beside the pages (a
+            # convolution state a slot)
             "pool_bytes": self._program.cache_bytes,
+            "state_bytes": self._program.state_bytes,
             "compiles": self._program.trace_count,
             "quantize": c.quantize,
         }
