@@ -767,9 +767,24 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
 _GMM_TILING = (512, 1024, 1024)     # rows, contracted, columns
 # rows under which a tile's visit is bound by reading its weight tile and
 # not by the MXU (the v5e multiplies ~240 rows by a bf16 tile in the time
-# it reads it): a smaller row tile only re-reads the weights of every
-# group that spans two tiles
+# it reads it).  Where a group's mean rows fall under it, the forward
+# product takes the whole contracted axis (:func:`_gmm_fwd_tiling`): the
+# weight block then stays put across the visits to one group, and each
+# group's matrix is read once a column tile, whatever row tiles it spans
 _GMM_MIN_ROWS = 256
+# such a product's VMEM: its double-buffered (k, tn) weight tile, and with
+# it the lhs tile, the float32 accumulator and the output tile, under the
+# scoped VMEM Mosaic allows a kernel by default on the v5e (megablox asks
+# for no more)
+_GMM_WEIGHT_VMEM = 8 << 20
+_GMM_SCOPED_VMEM = 16 << 20
+# and its row tile: a visit pushes its group's whole weight tile through the
+# MXU whatever its rows, so fewer rows save nothing (64 read 5% slower than
+# 128 on the v5e at 64 rows a group, 256 0.5% slower; PERF.md section 5)
+_GMM_RESIDENT_ROWS = 128
+
+# what each grouped product traced in this process took on the TPU
+_GMM_TILINGS = {}
 
 
 def _gmm_tiling(m, groups):
@@ -786,6 +801,56 @@ def _gmm_tiling(m, groups):
     while m % tm:
         tm //= 2
     return tm, tk, tn
+
+
+def _gmm_vmem(tm, k, tn, itemsize, out_itemsize):
+    """VMEM bytes of a (tm, k, tn) tile of megablox's forward product:
+    lhs and weight tiles and the output tile double-buffered, the float32
+    accumulator once."""
+    return (2 * (tm * k + k * tn) * itemsize + tm * tn * 4
+            + 2 * tm * tn * out_itemsize)
+
+
+def _gmm_fwd_tiling(m, groups, k, n, dtype, out_dtype):
+    """The forward product's tiling of ``m`` sorted rows over ``groups``
+    groups of (k, n) matrices.  Where the groups' mean rows are under
+    ``_GMM_MIN_ROWS`` the product is bound by reading its weights, and it
+    takes the whole contracted axis, ``_GMM_RESIDENT_ROWS`` rows and the
+    widest multiple of 128 columns that divides ``n`` inside the VMEM
+    budgets; anywhere else, or where no such column tile fits, it takes
+    :func:`_gmm_tiling`'s.  Returns (tiling, weight_resident)."""
+    if -(-m // groups) < _GMM_MIN_ROWS:
+        itemsize = jnp.dtype(dtype).itemsize
+        out_itemsize = jnp.dtype(out_dtype).itemsize
+        tm = _GMM_RESIDENT_ROWS
+        while m % tm:
+            tm //= 2
+        for tn in range(n - n % 128, 0, -128):
+            if (n % tn == 0 and 2 * k * tn * itemsize <= _GMM_WEIGHT_VMEM
+                    and _gmm_vmem(tm, k, tn, itemsize, out_itemsize)
+                    <= _GMM_SCOPED_VMEM):
+                return (tm, k, tn), True
+    return _gmm_tiling(m, groups), False
+
+
+def grouped_matmul_tilings():
+    """Every grouped product traced in this process for the TPU, in the
+    order first traced: dicts of ``pass`` (``forward`` or ``backward``),
+    ``m``, ``groups``, ``k``, ``n``, ``dtype``, ``tiling`` (rows,
+    contracted, columns) and ``weight_resident`` (the whole contracted axis
+    in one tile, :func:`_gmm_fwd_tiling`).  Filled at trace time; the CPU's
+    ``ragged_dot`` path takes no tiling and records nothing."""
+    return [dict(v) for v in _GMM_TILINGS.values()]
+
+
+def _note_tiling(pass_, x, w, tiling, resident):
+    m, k = x.shape
+    groups, n = w.shape[0], w.shape[-1]
+    key = (pass_, m, groups, k, n, str(x.dtype))
+    _GMM_TILINGS.setdefault(key, {
+        "pass": pass_, "m": m, "groups": groups, "k": k, "n": n,
+        "dtype": str(x.dtype), "tiling": tuple(tiling),
+        "weight_resident": resident})
 
 
 def _megablox():
@@ -809,9 +874,11 @@ def _mxu_operands(dtype):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _gmm_tpu(x, w, sizes, out_dtype):
     megablox = _megablox()
+    tiling, resident = _gmm_fwd_tiling(x.shape[0], w.shape[0], x.shape[1],
+                                       w.shape[2], x.dtype, out_dtype)
+    _note_tiling("forward", x, w, tiling, resident)
     with _mxu_operands(x.dtype), jax.enable_x64(False):
-        return megablox.gmm(x, w, sizes, out_dtype,
-                            _gmm_tiling(x.shape[0], w.shape[0]))
+        return megablox.gmm(x, w, sizes, out_dtype, tiling)
 
 
 def _gmm_tpu_fwd(x, w, sizes, out_dtype):
@@ -822,6 +889,7 @@ def _gmm_tpu_bwd(out_dtype, saved, dy):
     megablox = _megablox()
     x, w, sizes = saved
     tiling = _gmm_tiling(x.shape[0], w.shape[0])
+    _note_tiling("backward", x, w, tiling, False)
     dy = dy.astype(x.dtype)
     with _mxu_operands(x.dtype), jax.enable_x64(False):
         dx = megablox.gmm(dy, w, sizes, x.dtype, tiling, transpose_rhs=True)

@@ -110,11 +110,11 @@ def test_grouped_query_pages_are_walked_in_larger_groups():
 
 
 def test_grouped_product_row_tile_follows_the_rows_a_group_holds():
-    """2,048 sorted rows over 32 experts (the cell's step) take 256-row
-    tiles, not 512; the other cells' budgets keep theirs (a training
-    step's 10,240 rows and more over 16 experts 512; the latent serving
-    step's 768-3,584 over 16: 256, 512, 512, 512); a tile divides the
-    rows."""
+    """``_gmm_tiling``, the backward products' tiling and the forward's
+    where they are not weight-bound (``tests/test_grouped_matmul_tiling.py``
+    has the forward rule): 2,048 sorted rows over 32 experts take 256-row
+    tiles, not 512; a training step's 10,240 rows and more over 16 experts
+    512; 768-3,584 over 16: 256, 512, 512, 512; a tile divides the rows."""
     from mxnet_tpu.ops import pallas_kernels as pk
     assert pk._gmm_tiling(2048, 32) == (256, 1024, 1024)
     for m in (10240, 20480, 65536):
