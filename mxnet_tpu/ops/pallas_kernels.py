@@ -753,7 +753,10 @@ def fused_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
 # On the TPU this is jax's megablox kernel pair (``gmm`` for the product
 # and for the rows' gradient, ``tgmm`` for the matrices'): its grid runs
 # over the row tiles the groups really own (a dynamic bound), so time
-# follows ``sum(sizes)`` and not m.  ``jax.lax.ragged_dot`` lowers to a
+# follows ``sum(sizes)`` and not m.  A forward product bound by reading
+# its weights takes a kernel of this module's own on megablox's grid,
+# :func:`_gmm_group_ahead`, that copies the next group's weights while
+# every visit of the current group runs.  ``jax.lax.ragged_dot`` lowers to a
 # kernel of XLA's own that is as fast (17 ms a step of
 # trinity-mini.train-b1x8k against a least time of 6.3; PERF.md section 6,
 # PR 33) but drops the ``jax.named_scope`` path of the call, so no trace
@@ -772,10 +775,10 @@ _GMM_TILING = (512, 1024, 1024)     # rows, contracted, columns
 # weight block then stays put across the visits to one group, and each
 # group's matrix is read once a column tile, whatever row tiles it spans
 _GMM_MIN_ROWS = 256
-# such a product's VMEM: its double-buffered (k, tn) weight tile, and with
-# it the lhs tile, the float32 accumulator and the output tile, under the
-# scoped VMEM Mosaic allows a kernel by default on the v5e (megablox asks
-# for no more)
+# such a product's VMEM: its two (k, tn) weight slots (the group being
+# visited and the next one), and with them the lhs tile, megablox's float32
+# accumulator and the output tile, under the scoped VMEM Mosaic allows a
+# kernel by default on the v5e (neither kernel asks for more)
 _GMM_WEIGHT_VMEM = 8 << 20
 _GMM_SCOPED_VMEM = 16 << 20
 # and its row tile: a visit pushes its group's whole weight tile through the
@@ -806,7 +809,8 @@ def _gmm_tiling(m, groups):
 def _gmm_vmem(tm, k, tn, itemsize, out_itemsize):
     """VMEM bytes of a (tm, k, tn) tile of megablox's forward product:
     lhs and weight tiles and the output tile double-buffered, the float32
-    accumulator once."""
+    accumulator once.  :func:`_gmm_group_ahead` holds the same but the
+    accumulator."""
     return (2 * (tm * k + k * tn) * itemsize + tm * tn * 4
             + 2 * tm * tn * out_itemsize)
 
@@ -837,9 +841,12 @@ def grouped_matmul_tilings():
     """Every grouped product traced in this process for the TPU, in the
     order first traced: dicts of ``pass`` (``forward`` or ``backward``),
     ``m``, ``groups``, ``k``, ``n``, ``dtype``, ``tiling`` (rows,
-    contracted, columns) and ``weight_resident`` (the whole contracted axis
-    in one tile, :func:`_gmm_fwd_tiling`).  Filled at trace time; the CPU's
-    ``ragged_dot`` path takes no tiling and records nothing."""
+    contracted, columns), ``weight_resident`` (the whole contracted axis
+    in one tile, :func:`_gmm_fwd_tiling`) and ``prefetch``, the pipeline
+    that brings the weights: ``group`` (:func:`_gmm_group_ahead`, the next
+    group's weights a whole group ahead) or ``megablox`` (one grid step
+    ahead).  Filled at trace time; the CPU's ``ragged_dot`` path takes no
+    tiling and records nothing."""
     return [dict(v) for v in _GMM_TILINGS.values()]
 
 
@@ -850,7 +857,8 @@ def _note_tiling(pass_, x, w, tiling, resident):
     _GMM_TILINGS.setdefault(key, {
         "pass": pass_, "m": m, "groups": groups, "k": k, "n": n,
         "dtype": str(x.dtype), "tiling": tuple(tiling),
-        "weight_resident": resident})
+        "weight_resident": resident,
+        "prefetch": "group" if resident else "megablox"})
 
 
 def _megablox():
@@ -871,14 +879,122 @@ def _mxu_operands(dtype):
     return jax.default_matmul_precision("default")
 
 
+def _gmm_group_ahead_kernel(offsets, group_ids, m_tile_ids, following,
+                            x_ref, w_hbm, o_ref, w_buf, sems, slot_ref, *,
+                            dtype):
+    """One visit of megablox's grid: row tile ``m_tile_ids[t]`` of group
+    ``group_ids[t]`` against column tile ``n``, the whole contracted axis
+    in one dot.
+
+    ``w_hbm`` is every group's (k, n) matrix, left in HBM; ``w_buf``
+    (2, k, tn) the two slots a group's column tile lands in, ``sems``
+    their copies' semaphores, ``slot_ref`` the slot of the group being
+    visited.  At a group's first visit the kernel waits for its copy and
+    starts the next non-empty group's (``following[g + 1]``; past the last
+    group, the first one, ``following[0]``, of the next column tile) into
+    the other slot, so that copy runs through all of this group's visits:
+    megablox's pipeline would start it one grid step ahead, during this
+    group's last visit only.  Rows of the tile outside the group keep what
+    the output block holds (megablox's masked store)."""
+    n, t = pl.program_id(0), pl.program_id(1)
+    tm, tn = o_ref.shape
+    groups = w_hbm.shape[0]
+    g = group_ids[t]
+
+    def copy(group, col, slot):
+        cols = pl.ds(pl.multiple_of(col * tn, tn), tn)
+        return pltpu.make_async_copy(w_hbm.at[group, :, cols],
+                                     w_buf.at[slot], sems.at[slot])
+
+    @pl.when((n == 0) & (t == 0))
+    def _first():
+        slot_ref[0] = 1                 # the first group's slot is then 0
+        copy(g, n, 0).start()
+
+    @pl.when((t == 0) | (group_ids[jnp.maximum(t - 1, 0)] != g))
+    def _new_group():
+        slot = 1 - slot_ref[0]
+        slot_ref[0] = slot
+        copy(g, n, slot).wait()
+        after = following[g + 1]
+        here = after < groups
+
+        @pl.when(here | (n + 1 < pl.num_programs(0)))
+        def _prefetch():
+            copy(jnp.where(here, after, following[0]),
+                 jnp.where(here, n, n + 1), 1 - slot).start()
+
+    y = jnp.dot(x_ref[...].astype(dtype), w_buf[slot_ref[0]].astype(dtype),
+                preferred_element_type=jnp.float32)
+    rows = (m_tile_ids[t] * tm
+            + jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0))
+    mine = (rows >= offsets[g]) & (rows < offsets[g + 1])
+    o_ref[...] = jnp.where(mine, y, o_ref[...].astype(jnp.float32)
+                           ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("out_dtype", "tiling", "interpret"))
+def _gmm_group_ahead(x, w, sizes, out_dtype, tiling, interpret=False):
+    """``megablox.gmm(x, w, sizes, out_dtype, tiling)`` for a tiling whose
+    contracted tile is the whole of k (``_gmm_fwd_tiling``'s weight-resident
+    one), with the weights copied a group ahead
+    (:func:`_gmm_group_ahead_kernel`).  The grid is megablox's, the column
+    tiles outer and the visits (row tile, group) of
+    ``make_group_metadata`` inner; both axes are sequential, since a copy
+    started in one column tile is waited on in the next.  Under
+    ``jax.jit`` so that each shape is traced once."""
+    m, k = x.shape
+    groups, _, n = w.shape
+    tm, tk, tn = tiling
+    assert tk == k and m % tm == 0 and n % tn == 0, (x.shape, w.shape,
+                                                     tiling)
+    megablox = _megablox()
+    (offsets, group_ids, m_tile_ids), visits = megablox.make_group_metadata(
+        group_sizes=sizes, m=m, tm=tm, start_group=0,
+        num_nonzero_groups=groups, visit_empty_groups=False)
+    # following[j]: the first non-empty group from j on (``groups``: none)
+    nonempty = jnp.where(sizes > 0, jnp.arange(groups, dtype=jnp.int32),
+                         groups)
+    following = jnp.append(jax.lax.cummin(nonempty, reverse=True),
+                           jnp.int32(groups))
+    dtype = (jnp.bfloat16 if x.dtype == w.dtype == jnp.bfloat16
+             else jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n // tn, visits),
+        in_specs=[pl.BlockSpec((tm, k), lambda c, t, o, g, r, f: (r[t], 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tm, tn), lambda c, t, o, g, r, f: (r[t], c)),
+        scratch_shapes=[pltpu.VMEM((2, k, tn), w.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)])
+    return pl.pallas_call(
+        functools.partial(_gmm_group_ahead_kernel, dtype=dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        # megablox's estimate, so that XLA schedules around the call as
+        # it did around megablox's
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(x.size * x.itemsize * (n // tn)
+                            + k * n * w.itemsize * group_ids.size
+                            + m * n * jnp.dtype(out_dtype).itemsize)),
+        interpret=interpret, name="gmm",
+    )(offsets, group_ids, m_tile_ids, following, x, w)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _gmm_tpu(x, w, sizes, out_dtype):
-    megablox = _megablox()
     tiling, resident = _gmm_fwd_tiling(x.shape[0], w.shape[0], x.shape[1],
                                        w.shape[2], x.dtype, out_dtype)
     _note_tiling("forward", x, w, tiling, resident)
     with _mxu_operands(x.dtype), jax.enable_x64(False):
-        return megablox.gmm(x, w, sizes, out_dtype, tiling)
+        if resident:
+            return _gmm_group_ahead(x, w, sizes, out_dtype, tiling)
+        return _megablox().gmm(x, w, sizes, out_dtype, tiling)
 
 
 def _gmm_tpu_fwd(x, w, sizes, out_dtype):

@@ -1,12 +1,15 @@
 """The grouped product's tiling rule (``ops/pallas_kernels._gmm_fwd_tiling``)
 at every configuration's real shapes, the record of what each traced product
-took (``grouped_matmul_tilings``), and megablox's kernel in the Pallas
-interpreter under the weight-resident tiling against ``jax.lax.ragged_dot``.
+took (``grouped_matmul_tilings``), and the two kernels that run the
+weight-resident tiling, megablox's and ``_gmm_group_ahead``, in the Pallas
+interpreter against ``jax.lax.ragged_dot``.
 
 A product whose groups own fewer rows than ``_GMM_MIN_ROWS`` on average is
 bound by reading its weights: it takes the whole contracted axis in one tile,
-so that consecutive visits to a group keep its weight block.  Anything else
-keeps ``_gmm_tiling``'s, and so does every backward product."""
+so that consecutive visits to a group keep its weight block, and the
+group-ahead kernel, which copies the next group's weights while the current
+group's visits run.  Anything else keeps ``_gmm_tiling``'s and megablox, and
+so does every backward product."""
 import json
 import os
 
@@ -96,12 +99,15 @@ def _tilings(case):
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_rule_at_each_configurations_shapes(case):
+def test_rule_at_each_configurations_shapes(case, monkeypatch):
     """The serving steps' products are weight-bound and take the whole
     contracted axis (gate/up 896 columns at k 2,048, down 1,024 at 1,792;
-    sarvam's 512 at k 4,096); trinity's training products (640 rows an
-    expert and more) keep exactly ``_gmm_tiling``'s MXU-bound (512, 1024,
-    1024), forward and backward."""
+    sarvam's 512 at k 4,096) and the group-ahead kernel; trinity's training
+    products (640 rows an expert and more) keep exactly ``_gmm_tiling``'s
+    MXU-bound (512, 1024, 1024) and megablox, forward and backward; every
+    backward product, at any shape, keeps megablox."""
+    import jax
+    import jax.numpy as jnp
     from mxnet_tpu.ops import pallas_kernels as pk
     _products, want, resident = CASES[case]
     got = _tilings(case)
@@ -111,6 +117,22 @@ def test_rule_at_each_configurations_shapes(case):
         assert tiling == want[which], (m, k, n, tiling)
         if not resident:
             assert tiling == pk._gmm_tiling(m, groups)
+
+    monkeypatch.setattr(pk, "_GMM_TILINGS", {})
+    S = jax.ShapeDtypeStruct
+    for m, groups, k, n, out_dtype, *_ in got:
+        def loss(x, w, out_dtype=out_dtype, groups=groups):
+            return pk._gmm_tpu(x, w, jnp.zeros((groups,), jnp.int32),
+                               out_dtype).astype(jnp.float32).sum()
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1)),
+                       S((m, k), jnp.bfloat16),
+                       S((groups, k, n), jnp.bfloat16))
+    records = pk.grouped_matmul_tilings()
+    assert {r["pass"] for r in records} == {"forward", "backward"}
+    assert len(records) == 2 * len({(m, k, n) for m, _g, k, n, *_ in got})
+    for r in records:
+        ahead = r["pass"] == "forward" and resident
+        assert r["prefetch"] == ("group" if ahead else "megablox"), r
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -152,7 +174,8 @@ def test_the_rule_reads_shapes_alone():
 
 def test_traced_products_are_recorded(monkeypatch):
     """Tracing the TPU path records each product once by shape, forward and
-    backward, with the tiling it took and whether it was weight-resident."""
+    backward, with the tiling it took, whether it was weight-resident and
+    the pipeline that brought its weights."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import pallas_kernels as pk
@@ -176,37 +199,47 @@ def test_traced_products_are_recorded(monkeypatch):
     assert got == [
         {"pass": "forward", "m": 2048, "groups": 32, "k": 2048, "n": 1792,
          "dtype": "bfloat16", "tiling": (128, 2048, 896),
-         "weight_resident": True},
+         "weight_resident": True, "prefetch": "group"},
         {"pass": "forward", "m": 10240, "groups": 16, "k": 2048, "n": 1024,
          "dtype": "bfloat16", "tiling": (512, 1024, 1024),
-         "weight_resident": False},
+         "weight_resident": False, "prefetch": "megablox"},
         {"pass": "backward", "m": 10240, "groups": 16, "k": 2048, "n": 1024,
          "dtype": "bfloat16", "tiling": (512, 1024, 1024),
-         "weight_resident": False}]
+         "weight_resident": False, "prefetch": "megablox"}]
 
 
-# sizes of 8 groups over 256 rows: skewed with empty groups and a group
-# across the 128-row boundary, rows past the last group; even; one group
-SIZES = {"skewed": [100, 3, 0, 60, 1, 0, 40, 20],
-         "even": [32] * 8,
-         "one_group": [0, 0, 0, 256, 0, 0, 0, 0]}
+# (rows, sizes of 8 groups): skewed with empty groups and a group across the
+# 128-row boundary, rows past the last group; even; one group; a group across
+# three row tiles with the last group empty; empty groups at the end of one
+# column tile and at the start of the next; the last group empty
+SIZES = {"skewed": (256, [100, 3, 0, 60, 1, 0, 40, 20]),
+         "even": (256, [32] * 8),
+         "one_group": (256, [0, 0, 0, 256, 0, 0, 0, 0]),
+         "three_tiles": (512, [10, 300, 20, 0, 40, 0, 30, 0]),
+         "empty_edges": (256, [0, 0, 50, 100, 60, 30, 0, 0]),
+         "last_empty": (256, [40, 30, 50, 20, 60, 30, 26, 0])}
 
 
+@pytest.mark.parametrize("kernel", ["megablox", "group_ahead"])
 @pytest.mark.parametrize("k", [256, 8192])
 @pytest.mark.parametrize("pattern", sorted(SIZES))
-def test_weight_resident_kernel_equals_ragged_dot(pattern, k):
-    """megablox's ``gmm`` in the interpreter, one contracted tile (and, at
-    k 8,192, three 128-column tiles of n 384), equals ``ragged_dot`` on every
-    row once the rows past the last group are masked as ``_held_rows``
-    masks them."""
+def test_weight_resident_kernel_equals_ragged_dot(pattern, k, kernel):
+    """Each kernel in the interpreter, one contracted tile (and, at k 8,192,
+    three 128-column tiles of n 384), equals ``ragged_dot`` on every row
+    once the rows past the last group are masked as ``_held_rows`` masks
+    them.  The group-ahead kernel runs in the TPU interpreter, where a copy
+    lands only when it is waited on and memory no copy reached reads NaN,
+    and equals megablox's to the bit."""
     import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
     from mxnet_tpu.ops import pallas_kernels as pk
-    m, groups, n = 256, 8, 384
+    m, sizes = SIZES[pattern]
+    groups, n = 8, 384
     rs = np.random.default_rng(k + len(pattern))
     x = jnp.asarray(rs.normal(size=(m, k)), jnp.bfloat16)
     w = jnp.asarray(rs.normal(size=(groups, k, n)) / np.sqrt(k), jnp.bfloat16)
-    sizes = jnp.asarray(SIZES[pattern], jnp.int32)
+    sizes = jnp.asarray(sizes, jnp.int32)
     tiling, resident = pk._gmm_fwd_tiling(m, groups, k, n, jnp.bfloat16,
                                           jnp.float32)
     assert resident and tiling[1] == k and n % tiling[2] == 0
@@ -214,7 +247,14 @@ def test_weight_resident_kernel_equals_ragged_dot(pattern, k):
     with jax.enable_x64(False):
         got = pk._megablox().gmm(x, w, sizes, jnp.float32, tiling,
                                  interpret=True)
+        if kernel == "group_ahead":
+            megablox, got = got, pk._gmm_group_ahead(
+                x, w, sizes, jnp.float32, tiling,
+                interpret=pltpu.InterpretParams())
     want = jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
     live = (jnp.arange(m) < jnp.sum(sizes))[:, None]
     got, want = (np.asarray(jnp.where(live, a, 0)) for a in (got, want))
     assert np.abs(got - want).max() <= 1e-4 * max(1.0, np.abs(want).max())
+    if kernel == "group_ahead":
+        np.testing.assert_array_equal(
+            got, np.asarray(jnp.where(live, megablox, 0)))
